@@ -167,7 +167,7 @@ def _streams_per_rhs_table() -> dict:
             for base in ("fused_v2", "sstep_v3")}
 
 
-def _solver_service_section(quick: bool) -> dict | None:
+def _solver_service_section(quick: bool) -> dict:
     """Latency/throughput rows from the solver-service bench (schema v7).
 
     Measured (wall-clock) — gated like the us/iter table: presence is
@@ -176,15 +176,10 @@ def _solver_service_section(quick: bool) -> dict | None:
     """
     from repro.launch.solver_service import bench_service
 
-    try:
-        if quick:
-            return bench_service(nelt=64, n=4, requests=4, max_b=2,
-                                 niter=3, repeats=1)
-        return bench_service(nelt=64, requests=16, max_b=8, niter=25)
-    except Exception as e:  # noqa: BLE001 — bench must not sink the run
-        print(f"# WARNING: solver-service bench skipped: {e}",
-              file=sys.stderr)
-        return None
+    if quick:
+        return bench_service(nelt=64, n=4, requests=4, max_b=2,
+                             niter=3, repeats=1)
+    return bench_service(nelt=64, requests=16, max_b=8, niter=25)
 
 
 def _us_per_iter_table(sections: list) -> dict:
@@ -214,7 +209,7 @@ def _reference_backend() -> str:
     return jax.default_backend()
 
 
-def _telemetry_section() -> dict | None:
+def _telemetry_section() -> dict:
     """Observability summary travelling with the bench (schema v9).
 
     The cost-model drift check (obs/drift.py) re-measured at bench time:
@@ -222,29 +217,27 @@ def _telemetry_section() -> dict | None:
     Summary-only (ok flag + per-row ratios) — the full report lives in
     the obs-smoke CI leg; here it stamps the bench JSON so a drifting
     model is visible next to the numbers it prices.  Never value-gated
-    by check_regression.py, and never allowed to sink the bench run.
+    by check_regression.py; a failure of the check itself fails the run.
     """
-    try:
-        from repro.obs import drift
+    from repro.obs import drift
 
-        report = drift.check()
-        return {
-            "drift": {
-                "ok": report.ok,
-                "rows": [{"pipeline": r.pipeline, "check": r.check,
-                          "ok": r.ok, "ratio": r.ratio}
-                         for r in report.rows],
-            },
-        }
-    except Exception as e:  # noqa: BLE001 — telemetry must not sink the run
-        print(f"# WARNING: telemetry section skipped: {e}", file=sys.stderr)
-        return None
+    report = drift.check()
+    return {
+        "drift": {
+            "ok": report.ok,
+            "rows": [{"pipeline": r.pipeline, "check": r.check,
+                      "ok": r.ok, "ratio": r.ratio}
+                     for r in report.rows],
+        },
+    }
 
 
 def main() -> None:
     from benchmarks import bench_ax_versions, bench_cost_model, bench_roofline
+    from repro.compile_cache import configure_caches
     from repro.obs import trace
 
+    configure_caches()
     sections = []
     print("name,us_per_call,derived")
     # one env var away from a named profiler timeline (DESIGN.md §14):

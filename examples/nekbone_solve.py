@@ -41,6 +41,9 @@ def run_case(nelt: int, niter: int = 100):
 
 
 def main():
+    from repro.compile_cache import configure_caches
+
+    configure_caches()
     ap = argparse.ArgumentParser()
     ap.add_argument("--elements", type=int, default=128,
                     choices=sorted(PAPER_CASES))

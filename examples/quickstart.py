@@ -15,6 +15,9 @@ from repro.core.nekbone import NekboneCase
 
 
 def main():
+    from repro.compile_cache import configure_caches
+
+    configure_caches()
     # Paper setup: polynomial degree 9 -> n = 10 GLL points, 64 elements.
     case = NekboneCase(n=10, grid=(4, 4, 4), dtype=jnp.float32)
     print(f"case: {case.mesh.nelt} elements, {case.mesh.ndof} local DOFs, "

@@ -13,6 +13,9 @@ from repro.launch.serve import serve
 
 
 def main():
+    from repro.compile_cache import configure_caches
+
+    configure_caches()
     for name in ("qwen2.5-14b", "rwkv6-1.6b", "hymba-1.5b"):
         cfg = get(name).reduced()
         tokens, stats = serve(cfg, batch=4, prompt_len=24, gen=12)

@@ -19,6 +19,9 @@ from repro.launch.train import train
 
 
 def main():
+    from repro.compile_cache import configure_caches
+
+    configure_caches()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch", type=int, default=8)

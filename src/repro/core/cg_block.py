@@ -29,8 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cg import CGResult, SolveResult
-from repro.core.cg_fused import _check_box_fields
-from repro.core.geom import box_outer
+from repro.core.cg_fused import _check_box_fields, initial_rtz
 from repro.core.precision import resolve_policy
 from repro.kernels import autotune as _autotune
 from repro.kernels import nekbone_ax as _ax
@@ -39,26 +38,25 @@ __all__ = ["cg_block_fixed_iters", "cg_block_tol"]
 
 
 def _block_iter(x3, r3, p3, rtz, beta, *, D, Dt, g3, mx, my, mz, cx, cy, cz,
-                zero_plane, n: int, grid: tuple[int, int, int], sz: int,
-                interpret: bool, acc_name: str, layout: str = "fold",
+                n: int, grid: tuple[int, int, int], sz: int,
+                interpret: bool, acc_name: str,
                 grid_order: str = "parallel"):
     """One full batched v2 CG iteration (both block kernels + stitch).
 
     The multi-RHS sibling of :func:`repro.core.cg_fused._v2_iter`:
-    identical structure with a leading RHS axis on the fields and planes
-    and per-lane scalar recurrences (``rtz``/``beta``: (b,)).  Returns
-    ``(x3, r3, p3, rtz_new, beta_new)``.
+    identical structure with a leading RHS axis on the kernel-layout
+    fields ``(b, n, n^2, E)`` and planes, and per-lane scalar recurrences
+    (``rtz``/``beta``: (b,)).  Returns ``(x3, r3, p3, rtz_new, beta_new)``.
     """
     nrhs = p3.shape[0]
     p3, w3, bot, top, pap_b = _ax.nekbone_ax_slab_block_pallas(
         p3, r3, D, Dt, g3, mx, my, mz, beta.reshape(1, nrhs),
         n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name,
-        layout=layout, grid_order=grid_order)
+        grid_order=grid_order)
     pap = jnp.sum(pap_b, axis=0)
     alpha = rtz / pap
     # cross-block stitch operands, shifted along the block axis per RHS.
-    addb = jnp.concatenate([zero_plane, top[:, :-1]], axis=1)
-    addt = jnp.concatenate([bot[:, 1:], zero_plane], axis=1)
+    addb, addt = _ax.shift_planes(bot, top)
     x3, r3, rcr_b = _ax.nekbone_cg_update_block_pallas(
         x3, p3, r3, w3, addb, addt, alpha.reshape(1, nrhs), cx, cy, cz,
         n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name)
@@ -67,46 +65,39 @@ def _block_iter(x3, r3, p3, rtz, beta, *, D, Dt, g3, mx, my, mz, cx, cy, cz,
     return x3, r3, p3, rtz_new, beta
 
 
-def _block_init(B, cx, cy, cz, *, n, grid, acc, x_name):
-    """Shared state setup: per-RHS rtz0 (one single-RHS-shaped reduction
-    per lane, so the b=1 arithmetic is exactly ``_cg_fused_v2``'s) and the
-    zero stitch plane."""
-    ex, ey, _ = grid
+def _block_init(B, g3, cx, cy, cz, *, n, acc, x_name):
+    """Shared set-up: the kernel-layout RHS batch ``(b, n, n^2, E)`` and
+    metric (converted once per solve), and per-RHS rtz0 (one
+    single-RHS-shaped reduction per lane, so the b=1 arithmetic is exactly
+    ``_cg_fused_v2``'s).  Returns ``(state, g3)``."""
     nrhs, E = B.shape[0], B.shape[1]
-    n3 = n ** 3
-    pln = ey * ex * n * n
-    B2 = B.reshape(nrhs, E, n3)
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
-    rtz0 = jnp.stack([jnp.sum(B2[j].astype(acc) * c2 * B2[j].astype(acc))
+    B2 = _ax.to_lanes(B.reshape(nrhs, E, n ** 3), n)
+    rtz0 = jnp.stack([initial_rtz(B[j].reshape(E, n, n, n), cz, cy, cx, acc)
                       for j in range(nrhs)])
-    zero_plane = jnp.zeros((nrhs, 1, pln), B.dtype)
     state = (jnp.zeros(B2.shape, jnp.dtype(x_name)), B2,
              jnp.zeros_like(B2), rtz0, jnp.zeros((nrhs,), acc))
-    return state, zero_plane
+    return state, _ax.metric_lanes(g3, n)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "niter", "sz",
                                              "interpret", "acc_name",
-                                             "x_name", "layout",
-                                             "grid_order"))
+                                             "x_name", "grid_order"))
 def _cg_block(B, D, Dt, g3, mx, my, mz, cx, cy, cz, *, n: int,
               grid: tuple[int, int, int], niter: int, sz: int,
               interpret: bool, acc_name: str, x_name: str,
-              layout: str = "fold",
               grid_order: str = "parallel") -> CGResult:
     nrhs = B.shape[0]
     acc = jnp.dtype(acc_name)
-    (x3, r3, p3, rtz0, beta0), zero_plane = _block_init(
-        B, cx, cy, cz, n=n, grid=grid, acc=acc, x_name=x_name)
+    (x3, r3, p3, rtz0, beta0), g3 = _block_init(
+        B, g3, cx, cy, cz, n=n, acc=acc, x_name=x_name)
 
     def body(k, state):
         x3, r3, p3, rtz, beta, hist = state
         hist = hist.at[:, k].set(jnp.sqrt(jnp.abs(rtz)))
         x3, r3, p3, rtz_new, beta = _block_iter(
             x3, r3, p3, rtz, beta, D=D, Dt=Dt, g3=g3, mx=mx, my=my, mz=mz,
-            cx=cx, cy=cy, cz=cz, zero_plane=zero_plane, n=n, grid=grid,
-            sz=sz, interpret=interpret, acc_name=acc_name, layout=layout,
-            grid_order=grid_order)
+            cx=cx, cy=cy, cz=cz, n=n, grid=grid, sz=sz, interpret=interpret,
+            acc_name=acc_name, grid_order=grid_order)
         return x3, r3, p3, rtz_new, beta, hist
 
     hist0 = jnp.full((nrhs, niter + 1), jnp.nan, dtype=acc)
@@ -114,23 +105,21 @@ def _cg_block(B, D, Dt, g3, mx, my, mz, cx, cy, cz, *, n: int,
     x3, r3, p3, rtz_last, beta, hist = jax.lax.fori_loop(0, niter, body,
                                                          state)
     hist = hist.at[:, niter].set(jnp.sqrt(jnp.abs(rtz_last)))
-    return CGResult(x=x3, iters=jnp.asarray(niter), rnorm=hist[:, niter],
-                    rnorm_history=hist)
+    return CGResult(x=_ax.from_lanes(x3, n), iters=jnp.asarray(niter),
+                    rnorm=hist[:, niter], rnorm_history=hist)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "max_iter", "sz",
                                              "interpret", "acc_name",
-                                             "x_name", "layout",
-                                             "grid_order"))
+                                             "x_name", "grid_order"))
 def _cg_block_tol(B, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
                   grid: tuple[int, int, int], max_iter: int, sz: int,
                   interpret: bool, acc_name: str, x_name: str,
-                  layout: str = "fold",
                   grid_order: str = "parallel") -> CGResult:
     nrhs = B.shape[0]
     acc = jnp.dtype(acc_name)
-    (x3, r3, p3, rtz0, beta0), zero_plane = _block_init(
-        B, cx, cy, cz, n=n, grid=grid, acc=acc, x_name=x_name)
+    (x3, r3, p3, rtz0, beta0), g3 = _block_init(
+        B, g3, cx, cy, cz, n=n, acc=acc, x_name=x_name)
     tol2 = jnp.asarray(tol2, acc)
 
     # cg()'s stopping rule per RHS, jointly: iterate while any RHS is
@@ -147,22 +136,22 @@ def _cg_block_tol(B, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
         hist = hist.at[:, kk].set(jnp.sqrt(jnp.abs(rtz)))
         x3, r3, p3, rtz_new, beta = _block_iter(
             x3, r3, p3, rtz, beta, D=D, Dt=Dt, g3=g3, mx=mx, my=my, mz=mz,
-            cx=cx, cy=cy, cz=cz, zero_plane=zero_plane, n=n, grid=grid,
-            sz=sz, interpret=interpret, acc_name=acc_name, layout=layout,
-            grid_order=grid_order)
+            cx=cx, cy=cy, cz=cz, n=n, grid=grid, sz=sz, interpret=interpret,
+            acc_name=acc_name, grid_order=grid_order)
         return x3, r3, p3, rtz_new, beta, hist, kk + 1
 
     hist0 = jnp.full((nrhs, max_iter + 1), jnp.nan, dtype=acc)
     state = (x3, r3, p3, rtz0, beta0, hist0, jnp.asarray(0))
     x3, r3, p3, rtz, beta, hist, kk = jax.lax.while_loop(cond, body, state)
     hist = hist.at[:, kk].set(jnp.sqrt(jnp.abs(rtz)))
-    return CGResult(x=x3, iters=kk, rnorm=hist[:, kk], rnorm_history=hist)
+    return CGResult(x=_ax.from_lanes(x3, n), iters=kk, rnorm=hist[:, kk],
+                    rnorm_history=hist)
 
 
-def _prepare_block(B, D, g, grid, mask, c, sz, layout, grid_order,
-                   interpret, precision):
+def _prepare_block(B, D, g, grid, mask, c, sz, grid_order, interpret,
+                   precision):
     """Shared public-driver setup: batch-axis lift, precision policy,
-    autotuned (sz, layout, grid_order) at this RHS count, box-field
+    autotuned (sz, grid_order) at this RHS count, box-field
     validation, factor/operator preparation."""
     from repro.kernels import ops as kernel_ops
 
@@ -180,13 +169,12 @@ def _prepare_block(B, D, g, grid, mask, c, sz, layout, grid_order,
     grid = tuple(grid)
     if interpret is None:
         interpret = kernel_ops.default_interpret()
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_slab_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_slab_config(
             grid, n, B.dtype, acc_dtype=policy.accum, nrhs=nrhs)
     elif sz is None:
         sz = _autotune.pick_slab_sz(grid, n, B.dtype,
                                     acc_dtype=policy.accum, nrhs=nrhs)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
 
     _check_box_fields(grid, n, mask, c)
@@ -195,7 +183,7 @@ def _prepare_block(B, D, g, grid, mask, c, sz, layout, grid_order,
     D_op = jnp.asarray(D, policy.op_storage_dtype)
     g3 = kernel_ops.diag_metric(
         jnp.asarray(g, policy.op_storage_dtype), E, n)
-    return (policy, B, n, grid, sz, layout, grid_order, interpret,
+    return (policy, B, n, grid, sz, grid_order, interpret,
             (mx, my, mz), (cx, cy, cz), D_op, g3)
 
 
@@ -204,7 +192,6 @@ def cg_block_fixed_iters(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                          mask: jnp.ndarray | None = None,
                          c: jnp.ndarray | None = None,
                          sz: int | None = None,
-                         layout: str | None = None,
                          grid_order: str | None = None,
                          interpret: bool | None = None,
                          precision=None) -> SolveResult:
@@ -214,7 +201,7 @@ def cg_block_fixed_iters(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
       B:     (b, E, n, n, n) assembled, masked right-hand sides — or a
              single (E, n, n, n) RHS, solved as ``b = 1``; elements
              z-major over ``grid``.
-      D, g, grid, niter, mask, c, sz, layout, grid_order, interpret,
+      D, g, grid, niter, mask, c, sz, grid_order, interpret,
       precision: exactly :func:`repro.core.cg_fused.cg_fused_v2_fixed_iters`
              (the autotuned slab config additionally keys on b — the RHS
              batch scales the VMEM footprint).
@@ -223,10 +210,9 @@ def cg_block_fixed_iters(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     ``rnorm`` and ``achieved_rtol`` (b,).  At ``b = 1`` the trajectory is
     fp64-bitwise identical to the single-RHS v2 driver.
     """
-    (policy, B, n, grid, sz, layout, grid_order, interpret,
+    (policy, B, n, grid, sz, grid_order, interpret,
      (mx, my, mz), (cx, cy, cz), D_op, g3) = _prepare_block(
-        B, D, g, grid, mask, c, sz, layout, grid_order, interpret,
-        precision)
+        B, D, g, grid, mask, c, sz, grid_order, interpret, precision)
     nrhs = B.shape[0]
     # tracing: the batched solve is one jitted program; the host
     # boundary is this dispatch, recorded as a single span when on.
@@ -239,7 +225,7 @@ def cg_block_fixed_iters(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                         D_op.T, g3, mx, my, mz, cx, cy, cz, n=n,
                         grid=grid, niter=niter, sz=sz,
                         interpret=interpret, acc_name=policy.accum,
-                        x_name=policy.x_storage_dtype.name, layout=layout,
+                        x_name=policy.x_storage_dtype.name,
                         grid_order=grid_order)
     return SolveResult.from_cg(
         res._replace(x=res.x.reshape(B.shape)),
@@ -252,7 +238,6 @@ def cg_block_tol(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                  mask: jnp.ndarray | None = None,
                  c: jnp.ndarray | None = None,
                  sz: int | None = None,
-                 layout: str | None = None,
                  grid_order: str | None = None,
                  interpret: bool | None = None,
                  precision=None) -> SolveResult:
@@ -264,10 +249,9 @@ def cg_block_tol(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     per-RHS histories are prefixes of the fixed-iteration trajectories,
     NaN-padded to ``max_iter + 1``; ``iters`` is the joint count run.
     """
-    (policy, B, n, grid, sz, layout, grid_order, interpret,
+    (policy, B, n, grid, sz, grid_order, interpret,
      (mx, my, mz), (cx, cy, cz), D_op, g3) = _prepare_block(
-        B, D, g, grid, mask, c, sz, layout, grid_order, interpret,
-        precision)
+        B, D, g, grid, mask, c, sz, grid_order, interpret, precision)
     nrhs = B.shape[0]
     from repro.obs import trace as _trace
 
@@ -280,7 +264,7 @@ def cg_block_tol(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                             max_iter=max_iter, sz=sz, interpret=interpret,
                             acc_name=policy.accum,
                             x_name=policy.x_storage_dtype.name,
-                            layout=layout, grid_order=grid_order)
+                            grid_order=grid_order)
     return SolveResult.from_cg(
         res._replace(x=res.x.reshape(B.shape)),
         pipeline=f"fused_v2_rhs{nrhs}")

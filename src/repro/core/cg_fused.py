@@ -89,22 +89,27 @@ def _cg_fused(b, D, Dt, g2, mask2, c, *, n: int,
     # dtype, so the HBM residency is exactly what Eq. 2 bills.
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    c_acc = c.astype(acc)
+    # the loop runs in the kernels' (n, n^2, E) layout: operands are
+    # converted once here, the solution once at the end.
+    bl = _ax.to_lanes(b.reshape(E, n3), n)
+    g = _ax.metric_lanes(g2, n)
+    mask = _ax.to_lanes(mask2, n)
+    c_acc = _ax.to_lanes(c.reshape(E, n3), n).astype(acc)
     # r·c·r is carried through the loop: each iteration's post-update
     # reduction (fused by XLA with the axpys that produce r) is next
     # iteration's rtz, so the kernel needs no r/c operands (DESIGN.md §3.3).
-    rtz0 = jnp.sum(b.astype(acc) * c_acc * b.astype(acc))
+    rtz0 = jnp.sum(bl.astype(acc) * c_acc * bl.astype(acc))
 
     def body(k, state):
         x, r, p, rtz, hist = state
         hist = hist.at[k].set(jnp.sqrt(jnp.abs(rtz)))
-        w2, pap_b = _ax.nekbone_ax_pap_pallas(
-            p.reshape(E, n3), D, Dt, g2, mask2,
-            n=n, block_e=block_e, interpret=interpret, acc_dtype=acc_name)
+        w, pap_b = _ax.nekbone_ax_pap_pallas(
+            p, D, Dt, g, mask, n=n, block_e=block_e, interpret=interpret,
+            acc_dtype=acc_name)
         pap = jnp.sum(pap_b)            # tree-reduce the per-block partials
         # mask commutes with gs (coincident copies share their mask value),
         # so the kernel's masked output assembles directly.
-        w = gs_mod.ds_sum_local(w2.reshape(b.shape), grid)
+        w = gs_mod.ds_sum_local(w, grid, lanes=True)
         alpha = rtz / pap
         # axpys evaluated in acc, stored (the loop carry) in storage dtype;
         # for the f32/f64 policies this is bit-identical to pre-policy code.
@@ -118,12 +123,13 @@ def _cg_fused(b, D, Dt, g2, mask2, c, *, n: int,
         p = (r.astype(acc) + beta * p.astype(acc)).astype(b.dtype)
         return x, r, p, rtz_new, hist
 
-    x = jnp.zeros(b.shape, x_dtype)
+    x = jnp.zeros(bl.shape, x_dtype)
     hist0 = jnp.full((niter + 1,), jnp.nan, dtype=acc)
-    state = (x, b, b, rtz0, hist0)
+    state = (x, bl, bl, rtz0, hist0)
     x, r, p, rtz_last, hist = jax.lax.fori_loop(0, niter, body, state)
     hist = hist.at[niter].set(jnp.sqrt(jnp.abs(rtz_last)))
-    return CGResult(x=x, iters=jnp.asarray(niter), rnorm=hist[niter],
+    return CGResult(x=_ax.from_lanes(x, n).reshape(b.shape),
+                    iters=jnp.asarray(niter), rnorm=hist[niter],
                     rnorm_history=hist)
 
 
@@ -213,10 +219,11 @@ def _check_box_fields(grid, n, mask, c) -> None:
 
 
 def _v2_iter(x2, r2, p2, rtz, beta, *, D, Dt, g3, mx, my, mz, cx, cy, cz,
-             zero_plane, n: int, grid: tuple[int, int, int], sz: int,
-             interpret: bool, acc_name: str, layout: str = "fold",
-             grid_order: str = "parallel"):
-    """One full v2 CG iteration (both slab kernels + the plane stitch).
+             n: int, grid: tuple[int, int, int], sz: int, interpret: bool,
+             acc_name: str, grid_order: str = "parallel"):
+    """One full v2 CG iteration (both slab kernels + the plane stitch) on
+    kernel-layout state (``(n, n^2, E)`` vectors, ``(3, n, n^2, E)``
+    metric).
 
     Shared by the fixed-iteration driver below and the tolerance-driven
     driver (:func:`repro.core.precond.cg_fused_tol`), so the tol-driven
@@ -225,17 +232,16 @@ def _v2_iter(x2, r2, p2, rtz, beta, *, D, Dt, g3, mx, my, mz, cx, cy, cz,
     ``(x2, r2, p2, rtz_new, beta_new)``.
     """
     # front half: p = r + beta p, masked Ax, pap partial, in-block
-    # assembly; boundary planes leave as (nblk, pln) side outputs.
+    # assembly; boundary planes leave as (nblk, n^2, EX*EY) side outputs.
     p2, w2, bot, top, pap_b = _ax.nekbone_ax_slab_pallas(
         p2, r2, D, Dt, g3, mx, my, mz, beta.reshape(1, 1),
         n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name,
-        layout=layout, grid_order=grid_order)
+        grid_order=grid_order)
     pap = jnp.sum(pap_b)
     alpha = rtz / pap
     # cross-block stitch operands: each block receives its neighbours'
     # boundary planes (zeros at the global ends) — O(E n^2) traffic.
-    addb = jnp.concatenate([zero_plane, top[:-1]], axis=0)
-    addt = jnp.concatenate([bot[1:], zero_plane], axis=0)
+    addb, addt = _ax.shift_planes(bot, top)
     # back half: stitch w in VMEM, both axpys, post-update r·c·r.
     x2, r2, rcr_b = _ax.nekbone_cg_update_pallas(
         x2, p2, r2, w2, addb, addt, alpha.reshape(1, 1), cx, cy, cz,
@@ -245,46 +251,61 @@ def _v2_iter(x2, r2, p2, rtz, beta, *, D, Dt, g3, mx, my, mz, cx, cy, cz,
     return x2, r2, p2, rtz_new, beta
 
 
+def v2_operands(b, g3, cz, cy, cx, acc):
+    """Kernel-layout operands of a v2-family solve, built once per solve:
+    ``(b, g3, c)`` — the right-hand side ``(n, n^2, E)``, the metric
+    diagonal ``(3, n, n^2, E)`` and the inner-product weight in ``acc``
+    (rebuilt from the per-axis factors, an XLA constant)."""
+    E, n = b.shape[0], b.shape[-1]
+    n3 = n ** 3
+    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
+    return (_ax.to_lanes(b.reshape(E, n3), n), _ax.metric_lanes(g3, n),
+            _ax.to_lanes(c2, n))
+
+
+def initial_rtz(b, cz, cy, cx, acc):
+    """``b·c·b`` of one natural ``(E, n, n, n)`` right-hand side, summed in
+    the element-major order every single- and multi-RHS v2 driver shares
+    (so a batch lane starts bitwise where its single solve does)."""
+    E, n = b.shape[0], b.shape[-1]
+    b2 = b.reshape(E, n ** 3).astype(acc)
+    c2 = box_outer(cz, cy, cx).reshape(E, n ** 3).astype(acc)
+    return jnp.sum(b2 * c2 * b2)
+
+
 @functools.partial(jax.jit, static_argnames=("n", "grid", "niter", "sz",
                                              "interpret", "acc_name",
-                                             "x_name", "layout",
-                                             "grid_order"))
+                                             "x_name", "grid_order"))
 def _cg_fused_v2(b, D, Dt, g3, mx, my, mz, cx, cy, cz, *, n: int,
                  grid: tuple[int, int, int], niter: int, sz: int,
                  interpret: bool, acc_name: str, x_name: str,
-                 layout: str = "fold",
                  grid_order: str = "parallel") -> CGResult:
-    ex, ey, ez = grid
-    E = b.shape[0]
-    n3 = n ** 3
-    pln = ey * ex * n * n
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    b2 = b.reshape(E, n3)
-    # one-time initial reduction; c rebuilt from the factors in-jit (an XLA
-    # constant) so no full-field weight operand enters the pipeline.
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
-    rtz0 = jnp.sum(b2.astype(acc) * c2 * b2.astype(acc))
-    zero_plane = jnp.zeros((1, pln), b.dtype)
+    b2, g3, _ = v2_operands(b, g3, cz, cy, cx, acc)
+    rtz0 = initial_rtz(b, cz, cy, cx, acc)
 
-    def body(k, state):
-        x2, r2, p2, rtz, beta, hist = state
+    def body(state):
+        x2, r2, p2, rtz, beta, hist, k = state
         hist = hist.at[k].set(jnp.sqrt(jnp.abs(rtz)))
         x2, r2, p2, rtz_new, beta = _v2_iter(
             x2, r2, p2, rtz, beta, D=D, Dt=Dt, g3=g3, mx=mx, my=my, mz=mz,
-            cx=cx, cy=cy, cz=cz, zero_plane=zero_plane, n=n, grid=grid,
-            sz=sz, interpret=interpret, acc_name=acc_name, layout=layout,
-            grid_order=grid_order)
-        return x2, r2, p2, rtz_new, beta, hist
+            cx=cx, cy=cy, cz=cz, n=n, grid=grid, sz=sz, interpret=interpret,
+            acc_name=acc_name, grid_order=grid_order)
+        return x2, r2, p2, rtz_new, beta, hist, k + 1
 
     hist0 = jnp.full((niter + 1,), jnp.nan, dtype=acc)
     state = (jnp.zeros(b2.shape, x_dtype), b2, jnp.zeros_like(b2), rtz0,
              jnp.zeros((), acc), hist0)
-    x2, r2, p2, rtz_last, beta, hist = jax.lax.fori_loop(0, niter, body,
-                                                         state)
+    # a while loop with the tolerance driver's body (core/precond.
+    # cg_fused_tol), compiled alike, so both trajectories stay bitwise
+    # identical.
+    x2, r2, p2, rtz_last, beta, hist, _ = jax.lax.while_loop(
+        lambda st: st[-1] < niter, body, state + (jnp.asarray(0),))
     hist = hist.at[niter].set(jnp.sqrt(jnp.abs(rtz_last)))
-    return CGResult(x=x2.reshape(b.shape), iters=jnp.asarray(niter),
-                    rnorm=hist[niter], rnorm_history=hist)
+    return CGResult(x=_ax.from_lanes(x2, n).reshape(b.shape),
+                    iters=jnp.asarray(niter), rnorm=hist[niter],
+                    rnorm_history=hist)
 
 
 def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
@@ -292,7 +313,6 @@ def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
                             niter: int, mask: jnp.ndarray | None = None,
                             c: jnp.ndarray | None = None,
                             sz: int | None = None,
-                            layout: str | None = None,
                             grid_order: str | None = None,
                             interpret: bool | None = None,
                             precision=None) -> CGResult:
@@ -311,9 +331,9 @@ def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
              structural fields and otherwise unused.
       sz:    slabs per block; default: autotuned divisor of EZ
              (kernels/autotune.pick_slab_sz).
-      layout, grid_order: contraction layout / grid iteration order for
-             the slab kernel (defaults: jointly autotuned with sz when
-             all three are None, kernels/autotune.pick_slab_config).
+      grid_order: grid iteration order for the slab kernel (default:
+             autotuned jointly with sz when both are None,
+             kernels/autotune.pick_slab_config).
       interpret: force Pallas interpret mode (default: off-TPU detection).
       precision: policy name / policy / ``None`` (infer from ``b.dtype``):
              b and the metric are cast to the storage dtype, both kernels
@@ -331,13 +351,12 @@ def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
     grid = tuple(grid)
     if interpret is None:
         interpret = kernel_ops.default_interpret()
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_slab_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_slab_config(
             grid, n, b.dtype, acc_dtype=policy.accum)
     elif sz is None:
         sz = _autotune.pick_slab_sz(grid, n, b.dtype,
                                     acc_dtype=policy.accum)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
 
     _check_box_fields(grid, n, mask, c)
@@ -353,7 +372,7 @@ def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
                      grid=grid, niter=niter, sz=sz, interpret=interpret,
                      acc_name=policy.accum,
                      x_name=policy.x_storage_dtype.name,
-                     layout=layout, grid_order=grid_order),
+                     grid_order=grid_order),
         pipeline="fused_v2")
 
 
@@ -405,27 +424,29 @@ def cg_fused_sharded_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
     n3 = n ** 3
     D = jnp.asarray(D, policy.op_storage_dtype)
     Dt = D.T
-    g2 = jnp.asarray(g, policy.op_storage_dtype).reshape(E, 6, n3)
-    mask2 = jnp.asarray(mask, b.dtype).reshape(E, n3)
+    # the loop runs in the kernels' (n, n^2, E_local) layout
+    g6 = _ax.metric_lanes(
+        jnp.asarray(g, policy.op_storage_dtype).reshape(E, 6, n3), n)
+    mask_l = _ax.to_lanes(jnp.asarray(mask, b.dtype).reshape(E, n3), n)
     acc = policy.accum_dtype
     x_dtype = policy.x_storage_dtype
-    c_acc = jnp.asarray(c, b.dtype).astype(acc)
+    c_acc = _ax.to_lanes(jnp.asarray(c, b.dtype).reshape(E, n3),
+                         n).astype(acc)
+    bl = _ax.to_lanes(b.reshape(E, n3), n)
 
     def gsum(v):
         return jax.lax.psum(v, axis_names)
 
-    rtz0 = gsum(jnp.sum(b.astype(acc) * c_acc * b.astype(acc)))
+    rtz0 = gsum(jnp.sum(bl.astype(acc) * c_acc * bl.astype(acc)))
 
     def body(k, state):
         x, r, p, rtz, hist = state
         hist = hist.at[k].set(jnp.sqrt(jnp.abs(rtz)))
-        w2, pap_b = _ax.nekbone_ax_pap_pallas(
-            p.reshape(E, n3), D, Dt, g2, mask2,
-            n=n, block_e=block_e, interpret=interpret,
+        w, pap_b = _ax.nekbone_ax_pap_pallas(
+            p, D, Dt, g6, mask_l, n=n, block_e=block_e, interpret=interpret,
             acc_dtype=policy.accum)
         pap = gsum(jnp.sum(pap_b))
-        w = gs_mod.ds_sum_sharded(w2.reshape(b.shape), grid_local,
-                                  axis_names)
+        w = gs_mod.ds_sum_sharded(w, grid_local, axis_names, lanes=True)
         alpha = rtz / pap
         x = (x.astype(acc) + alpha * p.astype(acc)).astype(x_dtype)
         r = (r.astype(acc) - alpha * w.astype(acc)).astype(b.dtype)
@@ -434,13 +455,14 @@ def cg_fused_sharded_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
         p = (r.astype(acc) + beta * p.astype(acc)).astype(b.dtype)
         return x, r, p, rtz_new, hist
 
-    x = jnp.zeros(b.shape, x_dtype)
+    x = jnp.zeros(bl.shape, x_dtype)
     hist0 = jnp.full((niter + 1,), jnp.nan, dtype=acc)
-    state = (x, b, b, rtz0, hist0)
+    state = (x, bl, bl, rtz0, hist0)
     x, r, p, rtz_last, hist = jax.lax.fori_loop(0, niter, body, state)
     hist = hist.at[niter].set(jnp.sqrt(jnp.abs(rtz_last)))
     return SolveResult.from_cg(
-        CGResult(x=x, iters=jnp.asarray(niter), rnorm=hist[niter],
+        CGResult(x=_ax.from_lanes(x, n).reshape(b.shape),
+                 iters=jnp.asarray(niter), rnorm=hist[niter],
                  rnorm_history=hist),
         pipeline="fused_v1_sharded")
 

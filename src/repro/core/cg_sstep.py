@@ -204,18 +204,19 @@ def estimate_theta(D: jnp.ndarray, g: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "s",
                                              "interpret", "acc_name",
-                                             "layout", "grid_order"))
+                                             "grid_order"))
 def _powers_call(p2, r2, D, Dt, gext, mx, my, mzext, cx, cy, cz, inv_theta,
                  *, n: int, grid: tuple[int, int, int], sz: int, s: int,
-                 interpret: bool, acc_name: str, layout: str = "fold",
+                 interpret: bool, acc_name: str,
                  grid_order: str = "parallel"):
-    """Halo-window gather + the matrix-powers pallas_call, one cycle."""
+    """Halo-window gather + the matrix-powers pallas_call, one cycle, on
+    kernel-layout vectors ``(n, n^2, E)``."""
     pext = _ax.sstep_extend_field(p2, grid, sz, s)
     rext = _ax.sstep_extend_field(r2, grid, sz, s)
     return _ax.nekbone_ax_powers_pallas(
         pext, rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, inv_theta,
         n=n, grid=grid, sz=sz, s=s, interpret=interpret, acc_dtype=acc_name,
-        layout=layout, grid_order=grid_order)
+        grid_order=grid_order)
 
 
 def sstep_cycle_traceables(D: jnp.ndarray, g: jnp.ndarray,
@@ -236,14 +237,13 @@ def sstep_cycle_traceables(D: jnp.ndarray, g: jnp.ndarray,
 
     grid = tuple(grid)
     n = int(jnp.asarray(D).shape[0])
-    n3 = n ** 3
     E = int(np.prod(grid))
     policy = resolve_policy(precision, jnp.asarray(D).dtype)
     (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(
         grid, n, policy.storage_dtype)
     D_op = jnp.asarray(D, policy.op_storage_dtype)
-    g3 = kernel_ops.diag_metric(jnp.asarray(g, policy.op_storage_dtype),
-                                E, n)
+    g3 = _ax.metric_lanes(kernel_ops.diag_metric(
+        jnp.asarray(g, policy.op_storage_dtype), E, n), n)
     gext = _ax.sstep_extend_field(g3, grid, sz, s)
     mzext = _ax.sstep_extend_zfactor(mz, sz, s)
     inv_theta = jnp.full((1, 1), 1.0, policy.accum_dtype)
@@ -258,9 +258,10 @@ def sstep_cycle_traceables(D: jnp.ndarray, g: jnp.ndarray,
             x2, p2, r2, basis, coef, cx, cy, cz, n=n, grid=grid, sz=sz,
             s=s, interpret=True, acc_dtype=policy.accum)
 
-    field = jax.ShapeDtypeStruct((E, n3), policy.storage_dtype)
-    xf = jax.ShapeDtypeStruct((E, n3), policy.x_storage_dtype)
-    basis = jax.ShapeDtypeStruct((E, 2 * s - 1, n3), policy.storage_dtype)
+    lanes = (n, n * n, E)
+    field = jax.ShapeDtypeStruct(lanes, policy.storage_dtype)
+    xf = jax.ShapeDtypeStruct(lanes, policy.x_storage_dtype)
+    basis = jax.ShapeDtypeStruct((2 * s - 1,) + lanes, policy.storage_dtype)
     coef = jax.ShapeDtypeStruct((3, 2 * s + 1), policy.accum_dtype)
     return ((powers_fn, (field, field)),
             (update_fn, (xf, field, field, basis, coef)))
@@ -271,7 +272,6 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                          mask: jnp.ndarray | None = None,
                          c: jnp.ndarray | None = None,
                          sz: int | None = None, theta: float | None = None,
-                         layout: str | None = None,
                          grid_order: str | None = None,
                          tol: float | None = None,
                          interpret: bool | None = None,
@@ -292,9 +292,9 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
       mask/c: optional structural fields, validated like the v2 path.
       sz:    slabs per block (default: joint (sz, s) autotune,
              `kernels/autotune.pick_slab_sz_sstep`).
-      layout, grid_order: powers-kernel contraction layout / grid
-             iteration order (defaults: jointly autotuned with sz when
-             all three are None, `kernels/autotune.pick_sstep_config`).
+      grid_order: powers-kernel grid iteration order (default:
+             autotuned jointly with sz when both are None,
+             `kernels/autotune.pick_sstep_config`).
       theta: basis scale override (default: power-iteration ||A|| estimate).
       tol:   optional tolerance for early exit (DESIGN.md §9.4): stop, as
              :func:`repro.core.cg.cg` does, *before* the first iteration
@@ -329,13 +329,12 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     ex, ey, ez = grid
     if interpret is None:
         interpret = kernel_ops.default_interpret()
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_sstep_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_sstep_config(
             grid, n, s, b.dtype, acc_dtype=policy.accum)
     elif sz is None:
         sz = _autotune.pick_slab_sz_sstep(grid, n, s, b.dtype,
                                           acc_dtype=policy.accum)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
 
     _check_box_fields(grid, n, mask, c)
@@ -347,9 +346,11 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     # operator data in the policy's op-storage dtype (refined policies keep
     # it wide, DESIGN.md §7); the halo'd metric windows are built once per
     # solve — the per-cycle kernel reads are what the cost model charges.
+    # The cycles run in the kernels' (n, n^2, E) layout: b and the metric
+    # are converted once here, x once at the end.
     D_op = jnp.asarray(D, policy.op_storage_dtype)
-    g3 = kernel_ops.diag_metric(jnp.asarray(g, policy.op_storage_dtype),
-                                E, n)
+    g3 = _ax.metric_lanes(kernel_ops.diag_metric(
+        jnp.asarray(g, policy.op_storage_dtype), E, n), n)
     gext = _ax.sstep_extend_field(g3, grid, sz, s)
     mzext = _ax.sstep_extend_zfactor(mz, sz, s)
     if theta is None:
@@ -362,8 +363,8 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     inv_theta = jnp.full((1, 1), 1.0 / theta, acc)
 
     tol2 = None if tol is None else float(tol) ** 2
-    x2 = jnp.zeros((E, n3), x_dtype)
-    r2 = p2 = b.reshape(E, n3)
+    r2 = p2 = _ax.to_lanes(b.reshape(E, n3), n)
+    x2 = jnp.zeros(r2.shape, x_dtype)
     hist: list[float] = []
     rcr_last = None
     it = 0
@@ -388,7 +389,7 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                     p2, r2, D_op, D_op.T, gext, mx, my, mzext, cx, cy,
                     cz, inv_theta, n=n, grid=grid, sz=sz, s=s,
                     interpret=interpret, acc_name=policy.accum,
-                    layout=layout, grid_order=grid_order)
+                    grid_order=grid_order)
             # the policy's gram dtype is always float64
             # (PrecisionPolicy.gram); cycle_coefficients resolves the
             # in-cycle stop (run only the iterations whose start rtz is
@@ -409,11 +410,13 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
         if tol2 is not None and m < s:
             break
     if rcr_last is None:                  # niter == 0 (or tol met at start)
-        c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
+        c2 = _ax.to_lanes(box_outer(cz, cy, cx).reshape(E, n3),
+                          n).astype(acc)
         rcr_last = jnp.sum(r2.astype(acc) * c2 * r2.astype(acc))
     hist.append(float(np.sqrt(abs(float(rcr_last)))))
     hist_arr = jnp.asarray(np.asarray(hist, np.float64), acc)
     return SolveResult.from_cg(
-        CGResult(x=x2.reshape(b.shape), iters=jnp.asarray(it),
+        CGResult(x=_ax.from_lanes(x2, n).reshape(b.shape),
+                 iters=jnp.asarray(it),
                  rnorm=hist_arr[-1], rnorm_history=hist_arr),
         pipeline="sstep_v3")

@@ -18,37 +18,48 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 __all__ = ["ds_sum_local", "ds_sum_sharded", "halo_exchange_z"]
 
 
-def ds_sum_local(u: jnp.ndarray, grid: tuple[int, int, int]) -> jnp.ndarray:
+def _box_view(u: jnp.ndarray, grid: tuple[int, int, int], lanes: bool):
+    """6-D view of a field plus its (element, node) axis pairs per
+    direction x, y, z.  Natural fields are ``(E, n, n, n)`` viewed as
+    ``(ez, ey, ex, k, j, i)``; kernel-layout fields ``(n, n^2, E)``
+    (kernels/nekbone_ax.py) as ``(k, j, i, ez, ey, ex)``."""
+    ex, ey, ez = grid
+    if lanes:
+        n = u.shape[0]
+        return (u.reshape(n, n, n, ez, ey, ex),
+                ((5, 2), (4, 1), (3, 0)))
+    n = u.shape[-1]
+    return u.reshape(ez, ey, ex, n, n, n), ((2, 5), (1, 4), (0, 3))
+
+
+def _at(ndim: int, axes: dict) -> tuple:
+    """Index tuple selecting ``axes[a]`` on axis ``a``, all of the rest."""
+    return tuple(axes.get(a, slice(None)) for a in range(ndim))
+
+
+def ds_sum_local(u: jnp.ndarray, grid: tuple[int, int, int], *,
+                 lanes: bool = False) -> jnp.ndarray:
     """Direct-stiffness sum over a local (un-sharded) element grid.
 
     Args:
       u:    ``(E, n, n, n)`` with ``E = EX*EY*EZ`` and e z-major
-            (``e = (ez*EY + ey)*EX + ex``), local layout ``(k, j, i)``.
+            (``e = (ez*EY + ey)*EX + ex``), local layout ``(k, j, i)``;
+            or, with ``lanes``, the kernel layout ``(n, n^2, E)``.
       grid: ``(EX, EY, EZ)``.
 
     Returns the assembled field, same shape; coincident nodes carry the sum.
     """
-    ex, ey, ez = grid
-    n = u.shape[-1]
-    v = u.reshape(ez, ey, ex, n, n, n)
-
-    if ex > 1:  # x-direction: face i = n-1 of (.., ex) meets i = 0 of (.., ex+1)
-        s = v[:, :, :-1, :, :, -1] + v[:, :, 1:, :, :, 0]
-        v = v.at[:, :, :-1, :, :, -1].set(s)
-        v = v.at[:, :, 1:, :, :, 0].set(s)
-    if ey > 1:  # y-direction
-        s = v[:, :-1, :, :, -1, :] + v[:, 1:, :, :, 0, :]
-        v = v.at[:, :-1, :, :, -1, :].set(s)
-        v = v.at[:, 1:, :, :, 0, :].set(s)
-    if ez > 1:  # z-direction
-        s = v[:-1, :, :, -1, :, :] + v[1:, :, :, 0, :, :]
-        v = v.at[:-1, :, :, -1, :, :].set(s)
-        v = v.at[1:, :, :, 0, :, :].set(s)
+    v, dirs = _box_view(u, grid, lanes)
+    for (ea, na), ne in zip(dirs, grid):
+        if ne > 1:  # face node n-1 of element e meets node 0 of e+1
+            lo = _at(v.ndim, {ea: slice(None, -1), na: -1})
+            hi = _at(v.ndim, {ea: slice(1, None), na: 0})
+            s = v[lo] + v[hi]
+            v = v.at[lo].set(s).at[hi].set(s)
     return v.reshape(u.shape)
 
 
@@ -63,7 +74,7 @@ def _flat_shift(v: jnp.ndarray, axis_names: tuple, up: bool) -> jnp.ndarray:
     """
     axis_names = tuple(axis_names)
     inner = axis_names[-1]
-    n = compat.axis_size(inner)
+    n = jax.lax.axis_size(inner)
     idx = jax.lax.axis_index(inner)
     if up:
         perm = [(i, (i + 1) % n) for i in range(n)]
@@ -94,25 +105,25 @@ def halo_exchange_z(top: jnp.ndarray, bottom: jnp.ndarray, axis_names):
 
 
 def ds_sum_sharded(u: jnp.ndarray, grid_local: tuple[int, int, int],
-                   axis_names) -> jnp.ndarray:
+                   axis_names, *, lanes: bool = False) -> jnp.ndarray:
     """Direct-stiffness sum where the z element axis is sharded.
 
     To be called *inside* ``shard_map``.  ``u`` is the shard-local block
-    ``(E_local, n, n, n)``; ``grid_local`` its local element grid
-    ``(EX, EY, EZ_local)``.  The z interface planes between shards are
-    exchanged with :func:`halo_exchange_z` and summed.
+    ``(E_local, n, n, n)`` (or ``(n, n^2, E_local)`` with ``lanes``);
+    ``grid_local`` its local element grid ``(EX, EY, EZ_local)``.  The z
+    interface planes between shards are exchanged with
+    :func:`halo_exchange_z` and summed.
 
     The local pass runs first; because the cross-shard interface is a z-plane
     and the x/y summations act within that plane on each side independently,
     local-then-exchange produces the fully assembled result.
     """
-    ex, ey, ez_l = grid_local
-    n = u.shape[-1]
-    v = ds_sum_local(u, grid_local).reshape(ez_l, ey, ex, n, n, n)
-
-    top = v[-1, :, :, -1, :, :]     # (ey, ex, n, n) plane at local k = n-1
-    bottom = v[0, :, :, 0, :, :]
-    from_below, from_above = halo_exchange_z(top, bottom, axis_names)
-    v = v.at[0, :, :, 0, :, :].add(from_below)
-    v = v.at[-1, :, :, -1, :, :].add(from_above)
+    v, dirs = _box_view(ds_sum_local(u, grid_local, lanes=lanes),
+                        grid_local, lanes)
+    ea, na = dirs[2]
+    top = _at(v.ndim, {ea: -1, na: -1})
+    bottom = _at(v.ndim, {ea: 0, na: 0})
+    from_below, from_above = halo_exchange_z(v[top], v[bottom], axis_names)
+    v = v.at[bottom].add(from_below)
+    v = v.at[top].add(from_above)
     return v.reshape(u.shape)

@@ -100,7 +100,7 @@ def gll_interp_matrix(n_to: int, n_from: int) -> np.ndarray:
 def _interp_axis(u: jnp.ndarray, mt: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Contract ``u``'s ``axis`` with ``mt``'s rows (output dim appended
     last) — the exact ``dot_general`` the Pallas interp kernel issues, so
-    an XLA reference built from this is fp64-bitwise against the kernel."""
+    an independent XLA reference for the Pallas interp kernel."""
     acc = jnp.float64 if u.dtype == jnp.float64 else jnp.float32
     return jax.lax.dot_general(u, mt, (((axis,), (0,)), ((), ())),
                                preferred_element_type=acc)
@@ -110,7 +110,7 @@ def interp3(u: jnp.ndarray, M: jnp.ndarray) -> jnp.ndarray:
     """Apply ``M`` (n_out, n_in) along each local axis of ``(E, n_in^3)``
     fields in natural ``(E, k, j, i)`` shape; returns ``(E, n_out^3)``
     natural.  The dense XLA reference for the Pallas interpolation kernel
-    (same contraction pattern and order, bitwise at fp64)."""
+    (which contracts layer by layer; the two agree to fp64 round-off)."""
     mt = jnp.asarray(M).T.astype(u.dtype)
     v = _interp_axis(u, mt, 3)                           # (E, k, j, io)
     v = _interp_axis(v, mt, 2).transpose(0, 1, 3, 2)     # (E, k, jo, io)

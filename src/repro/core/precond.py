@@ -63,7 +63,8 @@ import numpy as np
 import repro.core.gs as gs_mod
 from repro.core import pmg as _pmg
 from repro.core.cg import CGResult, SolveResult
-from repro.core.cg_fused import _check_box_fields, _v2_iter
+from repro.core.cg_fused import (_check_box_fields, _v2_iter, initial_rtz,
+                                 v2_operands)
 from repro.core.cost import CHEB_DEFAULT_K, PMG_DEFAULT_K
 from repro.core.geom import box_axis_factors, box_outer
 from repro.core.precision import resolve_policy
@@ -381,23 +382,15 @@ def make_preconditioner(name: str, *, D: jnp.ndarray, g: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "max_iter", "sz",
                                              "interpret", "acc_name",
-                                             "x_name", "layout",
-                                             "grid_order"))
+                                             "x_name", "grid_order"))
 def _cg_v2_tol(b, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
                grid: tuple[int, int, int], max_iter: int, sz: int,
                interpret: bool, acc_name: str, x_name: str,
-               layout: str = "fold",
                grid_order: str = "parallel") -> CGResult:
-    ex, ey, ez = grid
-    E = b.shape[0]
-    n3 = n ** 3
-    pln = ey * ex * n * n
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    b2 = b.reshape(E, n3)
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
-    rtz0 = jnp.sum(b2.astype(acc) * c2 * b2.astype(acc))
-    zero_plane = jnp.zeros((1, pln), b.dtype)
+    b2, g3, _ = v2_operands(b, g3, cz, cy, cx, acc)
+    rtz0 = initial_rtz(b, cz, cy, cx, acc)
     hist0 = jnp.full((max_iter + 1,), jnp.nan, dtype=acc)
     tol2 = jnp.asarray(tol2, acc)
 
@@ -410,27 +403,24 @@ def _cg_v2_tol(b, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
         hist = hist.at[kk].set(jnp.sqrt(jnp.abs(rtz)))
         x2, r2, p2, rtz_new, beta = _v2_iter(
             x2, r2, p2, rtz, beta, D=D, Dt=Dt, g3=g3, mx=mx, my=my, mz=mz,
-            cx=cx, cy=cy, cz=cz, zero_plane=zero_plane, n=n, grid=grid,
-            sz=sz, interpret=interpret, acc_name=acc_name, layout=layout,
-            grid_order=grid_order)
+            cx=cx, cy=cy, cz=cz, n=n, grid=grid, sz=sz, interpret=interpret,
+            acc_name=acc_name, grid_order=grid_order)
         return x2, r2, p2, rtz_new, beta, hist, kk + 1
 
     state = (jnp.zeros(b2.shape, x_dtype), b2, jnp.zeros_like(b2), rtz0,
              jnp.zeros((), acc), hist0, jnp.asarray(0))
     x2, r2, p2, rtz, beta, hist, kk = jax.lax.while_loop(cond, body, state)
     hist = hist.at[kk].set(jnp.sqrt(jnp.abs(rtz)))
-    return CGResult(x=x2.reshape(b.shape), iters=kk, rnorm=hist[kk],
-                    rnorm_history=hist)
+    return CGResult(x=_ax.from_lanes(x2, n).reshape(b.shape), iters=kk,
+                    rnorm=hist[kk], rnorm_history=hist)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "max_iter", "sz",
                                              "interpret", "acc_name",
-                                             "x_name", "layout",
-                                             "grid_order"))
+                                             "x_name", "grid_order"))
 def _pcg_jacobi(b, invd, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
                 grid: tuple[int, int, int], max_iter: int, sz: int,
                 interpret: bool, acc_name: str, x_name: str,
-                layout: str = "fold",
                 grid_order: str = "parallel") -> CGResult:
     """Fused Jacobi-PCG core: v2 slab front-half + PCG update back-half.
 
@@ -443,22 +433,16 @@ def _pcg_jacobi(b, invd, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
     reconstructed ``sqrt(r·c·r)``, directly comparable to
     unpreconditioned CG's.
     """
-    ex, ey, ez = grid
-    E = b.shape[0]
-    n3 = n ** 3
-    pln = ey * ex * n * n
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    b2 = b.reshape(E, n3)
-    invd2 = invd.reshape(E, n3)
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
+    b2, g3, c2 = v2_operands(b, g3, cz, cy, cx, acc)
+    invd2 = _ax.to_lanes(invd, n)
     b_acc = b2.astype(acc)
     # z0 rounded through storage — the slab kernel reads the stored z
     # (§7 rule 1's analog for the carried vector).
     z0 = (invd2.astype(acc) * b_acc).astype(b.dtype)
     rtz0 = jnp.sum(b_acc * c2 * z0.astype(acc))
     rcr0 = jnp.sum(b_acc * c2 * b_acc)
-    zero_plane = jnp.zeros((1, pln), b.dtype)
     hist0 = jnp.full((max_iter + 1,), jnp.nan, dtype=acc) \
         .at[0].set(jnp.sqrt(jnp.abs(rcr0)))
     tol2 = jnp.asarray(tol2, acc)
@@ -472,10 +456,9 @@ def _pcg_jacobi(b, invd, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
         p2, w2, bot, top, pap_b = _ax.nekbone_ax_slab_pallas(
             p2, z2, D, Dt, g3, mx, my, mz, beta.reshape(1, 1),
             n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name,
-            layout=layout, grid_order=grid_order)
+            grid_order=grid_order)
         alpha = rtz / jnp.sum(pap_b)
-        addb = jnp.concatenate([zero_plane, top[:-1]], axis=0)
-        addt = jnp.concatenate([bot[1:], zero_plane], axis=0)
+        addb, addt = _ax.shift_planes(bot, top)
         x2, z2, rtz_b, rcr_b = _ax.nekbone_pcg_update_pallas(
             x2, p2, z2, w2, addb, addt, alpha.reshape(1, 1), invd2,
             cx, cy, cz, n=n, grid=grid, sz=sz, interpret=interpret,
@@ -488,18 +471,17 @@ def _pcg_jacobi(b, invd, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *, n: int,
     state = (jnp.zeros(b2.shape, x_dtype), z0, jnp.zeros_like(z0), rtz0,
              jnp.zeros((), acc), hist0, jnp.asarray(0))
     x2, z2, p2, rtz, beta, hist, kk = jax.lax.while_loop(cond, body, state)
-    return CGResult(x=x2.reshape(b.shape), iters=kk, rnorm=hist[kk],
-                    rnorm_history=hist)
+    return CGResult(x=_ax.from_lanes(x2, n).reshape(b.shape), iters=kk,
+                    rnorm=hist[kk], rnorm_history=hist)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "max_iter", "sz",
                                              "sz_c", "k", "interpret",
                                              "acc_name", "x_name",
-                                             "layout", "grid_order"))
+                                             "grid_order"))
 def _pcg_cheb(b, D, Dt, g3, mx, my, mz, cx, cy, cz, coef, tol2, *, n: int,
               grid: tuple[int, int, int], max_iter: int, sz: int, sz_c: int,
               k: int, interpret: bool, acc_name: str, x_name: str,
-              layout: str = "fold",
               grid_order: str = "parallel") -> CGResult:
     """Fused Chebyshev-PCG core: cheb apply + v2 slab + v2 update.
 
@@ -511,16 +493,10 @@ def _pcg_cheb(b, D, Dt, g3, mx, my, mz, cx, cy, cz, coef, tol2, *, n: int,
     kernels then run the direction update / operator / axpys — 13 + 5 =
     18 streams/iter (DESIGN.md §9.3), the win being the iteration count.
     """
-    ex, ey, ez = grid
-    E = b.shape[0]
-    n3 = n ** 3
-    pln = ey * ex * n * n
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    b2 = b.reshape(E, n3)
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
+    b2, g3, c2 = v2_operands(b, g3, cz, cy, cx, acc)
     rcr0 = jnp.sum(b2.astype(acc) * c2 * b2.astype(acc))
-    zero_plane = jnp.zeros((1, pln), b.dtype)
     # halo'd operator windows for the cheb kernel, built once per solve
     # (loop-invariant); the per-iteration residual window gather below is
     # part of the halo side channel (§8.2's honesty note).
@@ -532,7 +508,7 @@ def _pcg_cheb(b, D, Dt, g3, mx, my, mz, cx, cy, cz, coef, tol2, *, n: int,
         z2, rtz_b = _ax.nekbone_cheb_apply_pallas(
             rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, coef,
             n=n, grid=grid, sz=sz_c, k=k, interpret=interpret,
-            acc_dtype=acc_name, layout=layout, grid_order=grid_order)
+            acc_dtype=acc_name, grid_order=grid_order)
         return z2, jnp.sum(rtz_b)
 
     z0, rtz0 = cheb(b2)
@@ -550,10 +526,9 @@ def _pcg_cheb(b, D, Dt, g3, mx, my, mz, cx, cy, cz, coef, tol2, *, n: int,
         p2, w2, bot, top, pap_b = _ax.nekbone_ax_slab_pallas(
             p2, z2, D, Dt, g3, mx, my, mz, beta.reshape(1, 1),
             n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name,
-            layout=layout, grid_order=grid_order)
+            grid_order=grid_order)
         alpha = rtz / jnp.sum(pap_b)
-        addb = jnp.concatenate([zero_plane, top[:-1]], axis=0)
-        addt = jnp.concatenate([bot[1:], zero_plane], axis=0)
+        addb, addt = _ax.shift_planes(bot, top)
         x2, r2, rcr_b = _ax.nekbone_cg_update_pallas(
             x2, p2, r2, w2, addb, addt, alpha.reshape(1, 1), cx, cy, cz,
             n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name)
@@ -565,21 +540,20 @@ def _pcg_cheb(b, D, Dt, g3, mx, my, mz, cx, cy, cz, coef, tol2, *, n: int,
              rtz0, jnp.ones((), acc), hist0, jnp.asarray(0))
     x2, r2, z2, p2, rtz, rtz_prev, hist, kk = jax.lax.while_loop(cond, body,
                                                                  state)
-    return CGResult(x=x2.reshape(b.shape), iters=kk, rnorm=hist[kk],
-                    rnorm_history=hist)
+    return CGResult(x=_ax.from_lanes(x2, n).reshape(b.shape), iters=kk,
+                    rnorm=hist[kk], rnorm_history=hist)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "max_iter", "sz",
                                              "ns", "szs", "cheb_szs", "k",
                                              "coarse_iters", "interpret",
                                              "acc_name", "x_name",
-                                             "layout", "grid_order"))
+                                             "grid_order"))
 def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
              grid: tuple[int, int, int], max_iter: int, sz: int,
              ns: tuple[int, ...], szs: tuple[int, ...],
              cheb_szs: tuple[int, ...], k: int, coarse_iters: int,
              interpret: bool, acc_name: str, x_name: str,
-             layout: str = "fold",
              grid_order: str = "parallel") -> CGResult:
     """Fused p-multigrid PCG core (DESIGN.md §13).
 
@@ -603,34 +577,35 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
     (:func:`repro.core.pmg.coarse_solve_fixed` — shared with the XLA
     reference cycle so interpret-mode parity isolates the kernels).
     """
-    ex, ey, ez = grid
     E = b.shape[0]
-    n3 = n ** 3
-    pln = ey * ex * n * n
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    b2 = b.reshape(E, n3)
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
+    # every level runs in the kernels' (n_l, n_l^2, E) layout; operands
+    # are converted once here (the base solve converts its small vectors)
+    b2, g3, c2 = v2_operands(b, g3, cz, cy, cx, acc)
     rcr0 = jnp.sum(b2.astype(acc) * c2 * b2.astype(acc))
-    zero_plane = jnp.zeros((1, pln), b.dtype)
     coefs, transfers, midops, coarse = levels
     L = len(ns)
     # per-smoothed-level kernel operands, fine -> coarsest smoothed
     lops = [(D, Dt, g3, mx, my, mz, cx, cy, cz)]
-    for (Dl, g3l, mxl, myl, mzl, cxl, cyl, czl) in midops:
-        lops.append((Dl, Dl.T, g3l, mxl, myl, mzl, cxl, cyl, czl))
+    for lev, (Dl, g3l, mxl, myl, mzl, cxl, cyl, czl) in enumerate(midops,
+                                                                   1):
+        lops.append((Dl, Dl.T, _ax.metric_lanes(g3l, ns[lev]), mxl, myl,
+                     mzl, cxl, cyl, czl))
     # loop-invariant per-level windows and full structural fields
     gexts, mzexts, mask2s, c2s = [], [], [], []
     for lev in range(L - 1):
         _, _, g3l, mxl, myl, mzl, cxl, cyl, czl = lops[lev]
-        nl3 = ns[lev] ** 3
+        nl = ns[lev]
         gexts.append(_ax.sstep_extend_field(g3l, grid, cheb_szs[lev], k))
         mzexts.append(_ax.sstep_extend_zfactor(mzl, cheb_szs[lev], k))
-        mask2s.append(box_outer(mzl, myl, mxl).reshape(E, nl3))
-        c2s.append(box_outer(czl, cyl, cxl).reshape(E, nl3).astype(acc))
+        mask2s.append(_ax.to_lanes(
+            box_outer(mzl, myl, mxl).reshape(E, nl ** 3), nl))
+        c2s.append(_ax.to_lanes(
+            box_outer(czl, cyl, cxl).reshape(E, nl ** 3), nl).astype(acc))
     Dc, gc, maskc, cc = coarse
     nc = ns[-1]
-    mask2s.append(maskc.reshape(E, nc ** 3))
+    mask2s.append(_ax.to_lanes(maskc.reshape(E, nc ** 3), nc))
 
     def smooth(r2l, lev):
         Dl, Dtl, _, mxl, myl, _, cxl, cyl, czl = lops[lev]
@@ -639,7 +614,7 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
             rext, Dl, Dtl, gexts[lev], mxl, myl, mzexts[lev],
             cxl, cyl, czl, coefs[lev], n=ns[lev], grid=grid,
             sz=cheb_szs[lev], k=k, interpret=interpret, acc_dtype=acc_name,
-            layout=layout, grid_order=grid_order)
+            grid_order=grid_order)
         return z2l
 
     def apply_a(z2l, lev):
@@ -648,16 +623,8 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
         _, w2, bot, top, _ = _ax.nekbone_ax_slab_pallas(
             jnp.zeros_like(z2l), z2l, Dl, Dtl, g3l, mxl, myl, mzl,
             jnp.zeros((1, 1), acc), n=nl, grid=grid, sz=szl,
-            interpret=interpret, acc_dtype=acc_name, layout=layout,
-            grid_order=grid_order)
-        nblk = ez // szl
-        if nblk > 1:
-            vb = w2.reshape(nblk, szl, ey, ex, nl, nl, nl)
-            plshape = (nblk - 1, ey, ex, nl, nl)
-            vb = vb.at[1:, 0, :, :, 0, :, :].add(top[:-1].reshape(plshape))
-            vb = vb.at[:-1, -1, :, :, -1, :, :].add(bot[1:].reshape(plshape))
-            w2 = vb.reshape(E, nl ** 3)
-        return w2
+            interpret=interpret, acc_dtype=acc_name, grid_order=grid_order)
+        return _ax.stitch_planes(w2, bot, top, grid, szl)
 
     def restrict(res2, lev):
         ncl = ns[lev + 1]
@@ -665,8 +632,7 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
         rc2 = _ax.nekbone_interp_pallas(
             t2, transfers[lev], nin=ns[lev], nout=ncl, grid=grid,
             sz=szs[lev], interpret=interpret, acc_dtype=acc_name)
-        rc2 = gs_mod.ds_sum_local(
-            rc2.reshape(E, ncl, ncl, ncl), grid).reshape(E, ncl ** 3)
+        rc2 = gs_mod.ds_sum_local(rc2, grid, lanes=True)
         return rc2 * mask2s[lev + 1].astype(rc2.dtype)
 
     def prolong(ec2, lev):
@@ -678,9 +644,9 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
     def vcycle_level(r2l, lev):
         if lev == L - 1:
             e4 = _pmg.coarse_solve_fixed(
-                r2l.reshape(E, nc, nc, nc).astype(acc), Dc, gc, grid,
-                maskc, cc, iters=coarse_iters)
-            return e4.reshape(E, nc ** 3).astype(b.dtype)
+                _ax.from_lanes(r2l, nc).reshape(E, nc, nc, nc).astype(acc),
+                Dc, gc, grid, maskc, cc, iters=coarse_iters)
+            return _ax.to_lanes(e4.reshape(E, nc ** 3), nc).astype(b.dtype)
         z2l = smooth(r2l, lev)
         res = (r2l.astype(acc) - apply_a(z2l, lev).astype(acc)) \
             .astype(r2l.dtype)
@@ -711,10 +677,9 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
         p2, w2, bot, top, pap_b = _ax.nekbone_ax_slab_pallas(
             p2, z2, D, Dt, g3, mx, my, mz, beta.reshape(1, 1),
             n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name,
-            layout=layout, grid_order=grid_order)
+            grid_order=grid_order)
         alpha = rtz / jnp.sum(pap_b)
-        addb = jnp.concatenate([zero_plane, top[:-1]], axis=0)
-        addt = jnp.concatenate([bot[1:], zero_plane], axis=0)
+        addb, addt = _ax.shift_planes(bot, top)
         x2, r2, rcr_b = _ax.nekbone_cg_update_pallas(
             x2, p2, r2, w2, addb, addt, alpha.reshape(1, 1), cx, cy, cz,
             n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_name)
@@ -726,8 +691,8 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
              rtz0, jnp.ones((), acc), hist0, jnp.asarray(0))
     x2, r2, z2, p2, rtz, rtz_prev, hist, kk = jax.lax.while_loop(cond, body,
                                                                  state)
-    return CGResult(x=x2.reshape(b.shape), iters=kk, rnorm=hist[kk],
-                    rnorm_history=hist)
+    return CGResult(x=_ax.from_lanes(x2, n).reshape(b.shape), iters=kk,
+                    rnorm=hist[kk], rnorm_history=hist)
 
 
 # ---------------------------------------------------------------------------
@@ -735,7 +700,7 @@ def _pcg_pmg(b, D, Dt, g3, mx, my, mz, cx, cy, cz, levels, tol2, *, n: int,
 # ---------------------------------------------------------------------------
 
 def _prepare(b, D, g, grid, mask, c, sz, interpret, precision, precond,
-             layout=None, grid_order=None):
+             grid_order=None):
     """Shared operand preparation for the fused v2-family drivers."""
     from repro.kernels import ops as kernel_ops
 
@@ -752,15 +717,14 @@ def _prepare(b, D, g, grid, mask, c, sz, interpret, precision, precond,
     # — so it shares the plain pick rather than re-measuring.
     jac = (isinstance(precond, JacobiPrecond)
            or (isinstance(precond, str) and precond == "jacobi"))
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_slab_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_slab_config(
             grid, n, b.dtype, acc_dtype=policy.accum,
             precond="jacobi" if jac else None)
     elif sz is None:
         sz = _autotune.pick_slab_sz(grid, n, b.dtype,
                                     acc_dtype=policy.accum,
                                     precond="jacobi" if jac else None)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
     _check_box_fields(grid, n, mask, c)
     (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(grid, n,
@@ -768,7 +732,7 @@ def _prepare(b, D, g, grid, mask, c, sz, interpret, precision, precond,
     D_op = jnp.asarray(D, policy.op_storage_dtype)
     g3 = kernel_ops.diag_metric(jnp.asarray(g, policy.op_storage_dtype),
                                 E, n)
-    return (policy, b, n, grid, sz, layout, grid_order, interpret,
+    return (policy, b, n, grid, sz, grid_order, interpret,
             (mx, my, mz), (cx, cy, cz), D_op, g3)
 
 
@@ -783,13 +747,13 @@ def _resolve_precond(precond, *, D, g, grid, mask, c):
 
 def _dispatch(b, precond, tol2, max_iter, *, policy, n, grid, sz, interpret,
               m_factors, c_factors, D_op, g3,
-              cheb_sz: int | None = None, layout: str = "fold",
+              cheb_sz: int | None = None,
               grid_order: str = "parallel") -> CGResult:
     mx, my, mz = m_factors
     cx, cy, cz = c_factors
     common = dict(n=n, grid=grid, max_iter=max_iter, sz=sz,
                   interpret=interpret, acc_name=policy.accum,
-                  x_name=policy.x_storage_dtype.name, layout=layout,
+                  x_name=policy.x_storage_dtype.name,
                   grid_order=grid_order)
     if precond is None:
         return _cg_v2_tol(b, D_op, D_op.T, g3, mx, my, mz, cx, cy, cz,
@@ -856,7 +820,6 @@ def pcg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
                              c: jnp.ndarray | None = None,
                              sz: int | None = None,
                              cheb_sz: int | None = None,
-                             layout: str | None = None,
                              grid_order: str | None = None,
                              interpret: bool | None = None,
                              precision=None) -> CGResult:
@@ -878,9 +841,9 @@ def pcg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
     apply kernel's (defaults: autotuned — deeper polynomials want larger
     ``cheb_sz``, the halo is ``8k/sz`` streams, cost.cheb_halo_streams).
     """
-    (policy, b, n, grid, sz, layout, grid_order, interpret, m_factors,
+    (policy, b, n, grid, sz, grid_order, interpret, m_factors,
      c_factors, D_op, g3) = _prepare(b, D, g, grid, mask, c, sz, interpret,
-                                     precision, precond, layout, grid_order)
+                                     precision, precond, grid_order)
     # specs built by name use the caller's (full-precision) operator data;
     # the drivers cast the resulting fields to the policy's op-storage.
     precond = _resolve_precond(precond, D=D, g=g, grid=grid, mask=mask, c=c)
@@ -890,7 +853,7 @@ def pcg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
         _dispatch(b, precond, -1.0, niter, policy=policy, n=n, grid=grid,
                   sz=sz, interpret=interpret, m_factors=m_factors,
                   c_factors=c_factors, D_op=D_op, g3=g3, cheb_sz=cheb_sz,
-                  layout=layout, grid_order=grid_order),
+                  grid_order=grid_order),
         pipeline="fused_v2", precond=getattr(precond, "name", None))
 
 
@@ -900,7 +863,6 @@ def cg_fused_tol(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                  mask: jnp.ndarray | None = None,
                  c: jnp.ndarray | None = None, sz: int | None = None,
                  cheb_sz: int | None = None,
-                 layout: str | None = None,
                  grid_order: str | None = None,
                  interpret: bool | None = None, precision=None) -> CGResult:
     """Tolerance-driven fused-v2 (P)CG: solve to ``tol``, not 100 iters.
@@ -917,14 +879,13 @@ def cg_fused_tol(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     Args are :func:`pcg_fused_v2_fixed_iters`'s with ``tol``/``max_iter``
     replacing ``niter``; ``precond=None`` runs the plain v2 pipeline.
     """
-    (policy, b, n, grid, sz, layout, grid_order, interpret, m_factors,
+    (policy, b, n, grid, sz, grid_order, interpret, m_factors,
      c_factors, D_op, g3) = _prepare(b, D, g, grid, mask, c, sz, interpret,
-                                     precision, precond, layout, grid_order)
+                                     precision, precond, grid_order)
     precond = _resolve_precond(precond, D=D, g=g, grid=grid, mask=mask, c=c)
     return SolveResult.from_cg(
         _dispatch(b, precond, float(tol) ** 2, max_iter, policy=policy,
                   n=n, grid=grid, sz=sz, interpret=interpret,
                   m_factors=m_factors, c_factors=c_factors, D_op=D_op,
-                  g3=g3, cheb_sz=cheb_sz, layout=layout,
-                  grid_order=grid_order),
+                  g3=g3, cheb_sz=cheb_sz, grid_order=grid_order),
         pipeline="fused_v2", precond=getattr(precond, "name", None))

@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 __all__ = ["collective_matmul_allgather"]
 
@@ -34,13 +33,13 @@ def collective_matmul_allgather(x: jnp.ndarray, w: jnp.ndarray,
     Ring schedule: at step s we hold the block that originated at shard
     (i - s) mod P; matmul it into its output slot while forwarding it.
     """
-    P = compat.axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     i = jax.lax.axis_index(axis_name)
     m_loc, _ = x.shape
     n = w.shape[1]
     out = jnp.zeros((m_loc * P, n), x.dtype)
-    if hasattr(jax.lax, "pcast"):   # mark the carry as device-varying (VMA)
-        out = jax.lax.pcast(out, (axis_name,), to="varying")
+    # mark the carry as device-varying (VMA)
+    out = jax.lax.pcast(out, (axis_name,), to="varying")
     perm = [(p, (p + 1) % P) for p in range(P)]
 
     def body(s, carry):

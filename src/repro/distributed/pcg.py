@@ -39,10 +39,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import repro.core.gs as gs_mod
-from repro import compat
 from repro.core.cg import CGResult
 from repro.core.cg_fused import _check_box_fields
 from repro.core.geom import box_outer
@@ -82,11 +82,11 @@ def _pcg_jacobi_shard(b2, invd2, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2,
     Per iteration: 1 plane exchange (2 ppermutes) + 2 psums (pap;
     stacked rtz/rcr).
     """
-    E = b2.shape[0]
-    n3 = n ** 3
+    E = b2.shape[-1]
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
+    c2 = _ax.to_lanes(box_outer(cz, cy, cx).reshape(E, n ** 3),
+                      n).astype(acc)
     b_acc = b2.astype(acc)
     z0 = (invd2.astype(acc) * b_acc).astype(b2.dtype)
     s0 = jax.lax.psum(
@@ -140,16 +140,16 @@ def _pcg_cheb_shard(b2, D, Dt, g3, mx, my, mz, cx, cy, cz, gext, mzext,
     """
     ex, ey, ez_l = grid_local
     eyex = ey * ex
-    E = b2.shape[0]
-    n3 = n ** 3
+    E = b2.shape[-1]
     acc = jnp.dtype(acc_name)
     x_dtype = jnp.dtype(x_name)
-    c2 = box_outer(cz, cy, cx).reshape(E, n3).astype(acc)
+    c2 = _ax.to_lanes(box_outer(cz, cy, cx).reshape(E, n ** 3),
+                      n).astype(acc)
     rcr0_loc = jnp.sum(b2.astype(acc) * c2 * b2.astype(acc))
 
     def cheb(r2):
-        r = r2.reshape(ez_l, eyex, n3)
-        rb, ra = gs_mod.halo_exchange_z(r[ez_l - k:], r[:k], (axis_name,))
+        rb, ra = gs_mod.halo_exchange_z(r2[..., (ez_l - k) * eyex:],
+                                        r2[..., :k * eyex], (axis_name,))
         rext = _ax.sstep_extend_field(r2, grid_local, sz_c, k,
                                       below=rb, above=ra)
         z2, rtz_b = _ax.nekbone_cheb_apply_pallas(
@@ -211,11 +211,12 @@ def _jacobi_call(b2, invd2, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2, *,
         _pcg_jacobi_shard, axis_name=ax, n=n, grid_local=grid_local, sz=sz,
         max_iter=max_iter, interpret=interpret, acc_name=acc_name,
         x_name=x_name)
-    return compat.shard_map(
+    field, metric = P(None, None, ax), P(None, None, None, ax)
+    return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(ax), P(ax), P(), P(), P(ax), P(), P(), P(ax), P(), P(),
+        in_specs=(field, field, P(), P(), metric, P(), P(), P(ax), P(), P(),
                   P(ax), P()),
-        out_specs=(P(ax), P(), P()),
+        out_specs=(field, P(), P()),
         check_vma=False)(b2, invd2, D, Dt, g3, mx, my, mz, cx, cy, cz, tol2)
 
 
@@ -231,11 +232,12 @@ def _cheb_call(b2, D, Dt, g3, mx, my, mz, cx, cy, cz, gext, mzext, coef,
         _pcg_cheb_shard, axis_name=ax, n=n, grid_local=grid_local, sz=sz,
         sz_c=sz_c, k=k, max_iter=max_iter, interpret=interpret,
         acc_name=acc_name, x_name=x_name)
-    return compat.shard_map(
+    field, metric = P(None, None, ax), P(None, None, None, ax)
+    return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(ax), P(), P(), P(ax), P(), P(), P(ax), P(), P(), P(ax),
-                  P(ax), P(ax), P(), P()),
-        out_specs=(P(ax), P(), P()),
+        in_specs=(field, P(), P(), metric, P(), P(), P(ax), P(), P(), P(ax),
+                  metric, P(ax), P(), P()),
+        out_specs=(field, P(), P()),
         check_vma=False)(b2, D, Dt, g3, mx, my, mz, cx, cy, cz, gext,
                          mzext, coef, tol2)
 
@@ -286,13 +288,23 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
 
     shard = functools.partial(shard_leading, mesh=mesh, axis_name=axis_name)
     rep = functools.partial(replicate, mesh=mesh)
+
+    def lanes(f, g=_ax.to_lanes):
+        """Element-sharded natural operand -> kernel layout, sharded on
+        its element (last) axis; built in place on each device."""
+        ndim = len(jax.eval_shape(lambda a: g(a, n), f).shape)
+        spec = P(*(None,) * (ndim - 1), axis_name)
+        return jax.jit(lambda a: g(a, n),
+                       out_shardings=NamedSharding(mesh, spec))(shard(f))
+
     statics = dict(mesh=mesh, axis_name=axis_name, n=n,
                    grid_local=grid_local, sz=sz, max_iter=max_iter,
                    interpret=interpret, acc_name=policy.accum,
                    x_name=policy.x_storage_dtype.name)
-    b2 = shard(b.reshape(E, n3))
+    b2 = lanes(b.reshape(E, n3))
     tol2 = jnp.asarray(tol2, policy.accum_dtype)
-    common = (rep(D_op), rep(D_op.T), shard(g3), rep(mx), rep(my),
+    g3l = lanes(g3, _ax.metric_lanes)
+    common = (rep(D_op), rep(D_op.T), g3l, rep(mx), rep(my),
               shard(mz), rep(cx), rep(cy), shard(cz))
 
     # tracing: the sharded solve is one jitted program — the host
@@ -301,7 +313,7 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
 
     rec = _trace.active()
     if isinstance(precond, JacobiPrecond):
-        invd2 = shard(jnp.asarray(precond.invdiag,
+        invd2 = lanes(jnp.asarray(precond.invdiag,
                                   policy.op_storage_dtype).reshape(E, n3))
         with (rec.span("pcg.sharded_dispatch", precond="jacobi",
                        ndev=ndev)
@@ -322,7 +334,10 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
                              f"cheb sz {sz_c}")
         # loop-invariant operator windows on the GLOBAL field, sharded by
         # block — only the residual ghosts cross the network per apply.
-        gext = shard(_ax.sstep_extend_field(g3, grid, sz_c, k))
+        gext = jax.jit(
+            lambda g: _ax.sstep_extend_field(g, grid, sz_c, k),
+            out_shardings=NamedSharding(
+                mesh, P(None, None, None, axis_name)))(g3l)
         mzext = shard(_ax.sstep_extend_zfactor(mz, sz_c, k))
         coef = rep(jnp.asarray(precond.scalars(), policy.accum_dtype))
         with (rec.span("pcg.sharded_dispatch", precond=f"cheb{k}",
@@ -332,8 +347,8 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
                                       tol2, sz_c=sz_c, k=k, **statics)
     else:
         raise TypeError(f"unsupported preconditioner {precond!r}")
-    return CGResult(x=jnp.asarray(np.asarray(x2)).reshape(b.shape),
-                    iters=kk, rnorm=hist[kk], rnorm_history=hist)
+    x = _ax.from_lanes(jnp.asarray(np.asarray(x2)), n).reshape(b.shape)
+    return CGResult(x=x, iters=kk, rnorm=hist[kk], rnorm_history=hist)
 
 
 def pcg_sharded_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,

@@ -22,7 +22,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 __all__ = ["pipeline_apply"]
 
@@ -36,7 +35,7 @@ def pipeline_apply(stage_params, microbatches, stage_fn, *, axis_name: str):
     Returns (M, mb, ...): valid on the LAST stage (use a masked psum or
     read that shard to collect).
     """
-    S = compat.axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     sid = jax.lax.axis_index(axis_name)
     M = microbatches.shape[0]
     mb_shape = microbatches.shape[1:]

@@ -35,25 +35,15 @@ def solver_mesh(ndev: int | None = None, axis_name: str = "z",
                 devices=None):
     """A 1-D mesh over ``ndev`` devices for the sharded solver drivers.
 
-    Defaults to every visible device.  Falls back to the plain ``Mesh``
-    constructor where ``jax.make_mesh`` predates the ``devices`` argument,
-    so sub-meshes (shard-count sweeps in the tests) work across the jax
-    span this repo supports.
+    Defaults to every visible device; ``devices`` picks a sub-mesh (the
+    shard-count sweeps in the tests).
     """
-    import numpy as np
-    from repro import compat
-
     if devices is None:
         devices = jax.devices()
     if ndev is None:
         ndev = len(devices)
-    devs = np.asarray(devices[:ndev])
-    if ndev == len(jax.devices()) and devices is jax.devices():
-        return compat.make_mesh((ndev,), (axis_name,))
-    try:
-        return compat.make_mesh((ndev,), (axis_name,), devices=devs)
-    except TypeError:
-        return jax.sharding.Mesh(devs.reshape(ndev), (axis_name,))
+    return jax.make_mesh((ndev,), (axis_name,), devices=devices[:ndev],
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def shard_leading(x: jnp.ndarray, mesh, axis_name: str) -> jnp.ndarray:
@@ -68,18 +58,8 @@ def replicate(x: jnp.ndarray, mesh) -> jnp.ndarray:
 
 
 def current_mesh():
-    """The ambient mesh set by ``jax.sharding.use_mesh`` / ``with mesh:``.
-
-    ``get_abstract_mesh`` only exists on newer jax; fall back to the thread
-    resources the ``with mesh:`` context manager populates on 0.4.x.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        m = get()
-    else:
-        from jax._src import mesh as _mesh_lib
-
-        m = _mesh_lib.thread_resources.env.physical_mesh
+    """The ambient mesh set by ``jax.set_mesh``, or ``None`` outside one."""
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty:
         return None
     return m

@@ -58,7 +58,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 import repro.core.gs as gs_mod
-from repro import compat
 from repro.core.cg import CGResult
 from repro.core.cg_sstep import cycle_coefficients, estimate_theta
 from repro.core.geom import box_axis_factors, box_outer
@@ -79,16 +78,18 @@ def exchange_ghost_slabs(f: jnp.ndarray, ez_local: int, halo: int,
                          axis_names) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Exchange ``halo`` ghost z-slabs of a shard-local field.
 
-    To be called *inside* ``shard_map``.  ``f`` is ``(ez_local, ...)``
-    slab-major (reshape ``(E_local, n^3)`` fields to ``(ez_l, EY*EX, n^3)``
-    first).  Returns ``(below, above)`` — the neighbour shards' ``halo``
-    edge slabs, zeros at the global domain ends (which is exactly the
-    padding :func:`repro.kernels.nekbone_ax.sstep_extend_field` wants
-    there).  Costs one ``ppermute`` per direction.
+    To be called *inside* ``shard_map``.  ``f`` is a kernel-layout field
+    ``(..., E_local)`` with the z-major elements on the last axis.  Returns
+    ``(below, above)`` — the neighbour shards' ``halo`` edge slabs,
+    ``(..., halo*EY*EX)`` each, zeros at the global domain ends (which is
+    exactly the padding :func:`repro.kernels.nekbone_ax.sstep_extend_field`
+    wants there).  Costs one ``ppermute`` per direction.
     """
     if not (0 < halo <= ez_local):
         raise ValueError(f"halo {halo} out of range for ez_local {ez_local}")
-    return gs_mod.halo_exchange_z(f[ez_local - halo:], f[:halo], axis_names)
+    slab = f.shape[-1] // ez_local
+    return gs_mod.halo_exchange_z(f[..., (ez_local - halo) * slab:],
+                                  f[..., :halo * slab], axis_names)
 
 
 # ---------------------------------------------------------------------------
@@ -106,21 +107,21 @@ def _cycle_shard(p2, r2, D, Dt, gextl, mzextl, mx, my, cx, cy, czl,
     """
     ex, ey, ez_l = grid_local
     eyex = ey * ex
-    n3 = n ** 3
     nblk = ez_l // sz
     L = sz + 2 * s
+    Lee = L * eyex
     block_e = sz * eyex
-    p = p2.reshape(ez_l, eyex, n3)
-    r = r2.reshape(ez_l, eyex, n3)
+    lead = p2.shape[:-1]                           # (n, n^2)
+    p = p2.reshape(lead + (ez_l, eyex))
+    r = r2.reshape(lead + (ez_l, eyex))
 
     # -- the one halo exchange of the cycle: p and r edge slabs stacked
     # into a single buffer so both fields (x both directions) ride on one
     # halo_exchange_z call = 2 ppermutes.
-    buf = jnp.stack([p, r])                        # (2, ez_l, eyex, n3)
     from_below, from_above = exchange_ghost_slabs(
-        jnp.swapaxes(buf, 0, 1), ez_l, s, (axis_name,))
-    pb, rb = from_below[:, 0], from_below[:, 1]    # (s, eyex, n3) each
-    pa, ra = from_above[:, 0], from_above[:, 1]
+        jnp.stack([p2, r2]), ez_l, s, (axis_name,))
+    pb, rb = from_below[0], from_below[1]          # (n, n^2, s*eyex) each
+    pa, ra = from_above[0], from_above[1]
 
     def powers(pext, rext, gext, mzext, cz, nblocks):
         return _ax.nekbone_ax_powers_pallas(
@@ -143,30 +144,37 @@ def _cycle_shard(p2, r2, D, Dt, gextl, mzextl, mx, my, cx, cy, czl,
         # XLA can run it while the boundary halo is in flight (the ring-
         # overlap idiom of distributed/overlap.py, halo edition).
         ii = np.arange(nb, nblk - nb)
-        idx = ii[:, None] * sz - s + np.arange(L)[None, :]   # all local
-        pint = p[idx].reshape(len(ii), L * eyex, n3)
-        rint = r[idx].reshape(len(ii), L * eyex, n3)
+        idx = (ii[:, None] * sz - s + np.arange(L)[None, :]).ravel()
+
+        def windows(f, idx, nw):
+            return jnp.take(f, idx, axis=-2).reshape(lead + (nw * Lee,))
+
         basis_i, gram_i = powers(
-            pint, rint, gextl[nb:nblk - nb], mzextl[nb:nblk - nb],
+            windows(p, idx, len(ii)), windows(r, idx, len(ii)),
+            gextl[..., nb * Lee:(nblk - nb) * Lee], mzextl[nb:nblk - nb],
             czl[nb * sz:(nblk - nb) * sz], len(ii))
 
         # -- boundary blocks: windows over [ghosts-below | local | ghosts-
         # above]; in padded coordinates block i's window starts at i*sz.
-        fp = jnp.concatenate([pb, p, pa], axis=0)
-        fr = jnp.concatenate([rb, r, ra], axis=0)
+        ghost = lead + (s, eyex)
+        fp = jnp.concatenate([pb.reshape(ghost), p, pa.reshape(ghost)],
+                             axis=-2)
+        fr = jnp.concatenate([rb.reshape(ghost), r, ra.reshape(ghost)],
+                             axis=-2)
         ib = np.concatenate([np.arange(nb), np.arange(nblk - nb, nblk)])
-        idxb = ib[:, None] * sz + np.arange(L)[None, :]
-        pbnd = fp[idxb].reshape(2 * nb, L * eyex, n3)
-        rbnd = fr[idxb].reshape(2 * nb, L * eyex, n3)
-        gbnd = jnp.concatenate([gextl[:nb], gextl[nblk - nb:]], axis=0)
+        idxb = (ib[:, None] * sz + np.arange(L)[None, :]).ravel()
+        gbnd = jnp.concatenate([gextl[..., :nb * Lee],
+                                gextl[..., (nblk - nb) * Lee:]], axis=-1)
         mzbnd = jnp.concatenate([mzextl[:nb], mzextl[nblk - nb:]], axis=0)
         czbnd = jnp.concatenate([czl[:nb * sz], czl[(nblk - nb) * sz:]],
                                 axis=0)
-        basis_b, gram_bb = powers(pbnd, rbnd, gbnd, mzbnd, czbnd, 2 * nb)
+        basis_b, gram_bb = powers(windows(fp, idxb, 2 * nb),
+                                  windows(fr, idxb, 2 * nb), gbnd, mzbnd,
+                                  czbnd, 2 * nb)
 
         half = nb * block_e
         basis = jnp.concatenate(
-            [basis_b[:half], basis_i, basis_b[half:]], axis=0)
+            [basis_b[..., :half], basis_i, basis_b[..., half:]], axis=-1)
         gram_loc = jnp.sum(gram_i, axis=0) + jnp.sum(gram_bb, axis=0)
 
     G = jax.lax.psum(gram_loc, axis_name)          # the one Gram psum
@@ -182,11 +190,12 @@ def _cycle_mapped(mesh, axis_name: str, n: int,
     body = functools.partial(
         _cycle_shard, axis_name=ax, n=n, grid_local=grid_local, sz=sz, s=s,
         interpret=interpret, acc_name=acc_name)
-    return compat.shard_map(
+    field, metric = P(None, None, ax), P(None, None, None, ax)
+    return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(ax), P(ax), P(), P(), P(ax), P(ax), P(), P(), P(), P(),
+        in_specs=(field, field, P(), P(), metric, P(ax), P(), P(), P(), P(),
                   P(ax), P()),
-        out_specs=(P(ax), P()),
+        out_specs=(metric, P()),
         check_vma=False)                      # pallas_call has no VMA rule
 
 
@@ -202,10 +211,11 @@ def _update_mapped(mesh, axis_name: str, n: int,
             x2, p2, r2, basis, coef, cx, cy, czl, n=n, grid=grid_local,
             sz=sz, s=s, interpret=interpret, acc_dtype=acc_name)
 
-    return compat.shard_map(
+    field, basis = P(None, None, ax), P(None, None, None, ax)
+    return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(ax), P(ax), P(ax), P(ax), P(), P(), P(), P(ax)),
-        out_specs=(P(ax), P(ax), P(ax), P(ax)),
+        in_specs=(field, field, field, basis, P(), P(), P(), P(ax)),
+        out_specs=(field, field, field, P(ax)),
         check_vma=False)
 
 
@@ -304,12 +314,22 @@ def cg_sstep_sharded_fixed_iters(
     D_op = jnp.asarray(D, policy.op_storage_dtype)
     g3 = kernel_ops.diag_metric(jnp.asarray(g, policy.op_storage_dtype),
                                 E, n)
-    # loop-invariant halo windows, built on the GLOBAL field: block i's
-    # window holds the same slabs whether its halo padding was gathered
-    # locally or exchanged from a neighbour, so these shard by block with
-    # no per-cycle traffic.  Only p and r cross the network.
-    gext = _ax.sstep_extend_field(g3, grid, sz, s)
-    mzext = _ax.sstep_extend_zfactor(mz, sz, s)
+    shard = functools.partial(shard_leading, mesh=mesh, axis_name=axis_name)
+    rep = functools.partial(replicate, mesh=mesh)
+    by_elem = NamedSharding(mesh, P(axis_name))
+    lanes = NamedSharding(mesh, P(None, None, axis_name))
+    # loop-invariant halo windows of the GLOBAL field, in the kernels'
+    # layout: block i's window holds the same slabs whether its halo
+    # padding was gathered locally or exchanged from a neighbour, so these
+    # shard by block with no per-cycle traffic.  They are built from the
+    # sharded metric straight into their shards (nothing is assembled on
+    # one device); only p and r cross the network per cycle.
+    gext = jax.jit(
+        lambda g: _ax.sstep_extend_field(_ax.metric_lanes(g, n), grid, sz,
+                                         s),
+        out_shardings=NamedSharding(mesh, P(None, None, None, axis_name)))(
+            shard(g3))
+    mzext = shard(_ax.sstep_extend_zfactor(mz, sz, s))
     if theta is None:
         if mask is None:
             mask = box_outer(
@@ -319,11 +339,11 @@ def cg_sstep_sharded_fixed_iters(
                                jnp.asarray(mask, b.dtype))
     inv_theta = jnp.full((1, 1), 1.0 / theta, acc)
 
-    shard = functools.partial(shard_leading, mesh=mesh, axis_name=axis_name)
-    rep = functools.partial(replicate, mesh=mesh)
-    x2 = shard(jnp.zeros((E, n3), x_dtype))
-    r2 = p2 = shard(b.reshape(E, n3))
-    gext, mzext, cz = shard(gext), shard(mzext), shard(cz)
+    # the cycles run in the kernels' (n, n^2, E) layout, sharded on E
+    r2 = p2 = jax.jit(lambda f: _ax.to_lanes(f, n), out_shardings=lanes)(
+        shard(b.reshape(E, n3)))
+    x2 = jnp.zeros(r2.shape, x_dtype, device=lanes)
+    cz = shard(cz)
     D_op, mx, my, cx, cy, inv_theta = (
         rep(D_op), rep(mx), rep(my), rep(cx), rep(cy), rep(inv_theta))
     Dt_op = rep(D_op.T)
@@ -370,12 +390,13 @@ def cg_sstep_sharded_fixed_iters(
     if rcr_last is None:                  # niter == 0 (or tol met at start)
         c2 = box_outer(np.asarray(cz, np.float64), np.asarray(cy, np.float64),
                        np.asarray(cx, np.float64)).reshape(E, n3)
-        r_h = np.asarray(r2, np.float64)
+        r_h = np.asarray(_ax.from_lanes(r2, n), np.float64)
         rcr_last = float(np.sum(r_h * c2 * r_h))
     hist.append(float(np.sqrt(abs(rcr_last))))
     hist_arr = jnp.asarray(np.asarray(hist, np.float64), acc)
-    return CGResult(x=jnp.asarray(np.asarray(x2)).reshape(b.shape),
-                    iters=jnp.asarray(it), rnorm=hist_arr[-1],
+    x = jax.jit(lambda x: _ax.from_lanes(x, n).reshape(b.shape),
+                out_shardings=by_elem)(x2)
+    return CGResult(x=x, iters=jnp.asarray(it), rnorm=hist_arr[-1],
                     rnorm_history=hist_arr)
 
 
@@ -443,7 +464,6 @@ def cycle_traceables(*, grid: tuple[int, int, int], n: int,
     if ez_l % sz or s > ez_l:
         raise ValueError((grid, ndev, sz, s))
     E = ex * ey * ez
-    n3 = n ** 3
     L = sz + 2 * s
     Lee = L * ey * ex
     nblk = ez // sz
@@ -452,13 +472,14 @@ def cycle_traceables(*, grid: tuple[int, int, int], n: int,
     op = policy.op_storage_dtype
     acc = policy.accum_dtype
     S = jax.ShapeDtypeStruct
-    field = S((E, n3), st)
+    lanes = (n, n * n, E)
+    field = S(lanes, st)
     cycle_args = (field, field, S((n, n), op), S((n, n), op),
-                  S((nblk, Lee, 3, n3), op), S((nblk, L, n), st),
+                  S((3, n, n * n, nblk * Lee), op), S((nblk, L, n), st),
                   S((ex, n), st), S((ey, n), st), S((ex, n), st),
                   S((ey, n), st), S((ez, n), st), S((1, 1), acc))
-    update_args = (S((E, n3), policy.x_storage_dtype), field, field,
-                   S((E, 2 * s - 1, n3), st), S((3, K), acc),
+    update_args = (S(lanes, policy.x_storage_dtype), field, field,
+                   S((2 * s - 1,) + lanes, st), S((3, K), acc),
                    S((ex, n), st), S((ey, n), st), S((ez, n), st))
     cyc = _cycle_mapped(mesh, axis_name, n, grid_local, sz, s, interpret,
                         policy.accum)

@@ -14,14 +14,17 @@ needs.  Two block modes exist:
   intra-block, so the block must cover complete slabs of the z-major
   element order.
 
-Selection strategy (both modes):
+Selection strategy (all modes):
 
-* **Heuristic floor**: largest candidate whose ~14-array working set fits a
-  VMEM budget (default 8 MiB of the ~16 MiB/core).  This is exact enough
-  off-TPU, where kernels only run in interpret mode and wall time is
-  meaningless.
-* **Measurement** (on a TPU backend): times the real kernel over the
-  candidates below the heuristic ceiling and keeps the fastest — the
+* **Candidates**: the block sizes whose modelled VMEM footprint
+  (:func:`vmem_bytes`, the one footprint model) fits the limit every
+  compiled kernel gets (:data:`VMEM_LIMIT_BYTES`); on a TPU backend the
+  element blocks must also be 128-lane aligned.  No candidate is admitted
+  that overshoots, so a shape with none raises.
+* **Heuristic** (off-TPU): the largest candidate.  Kernels there run in
+  interpret mode, where wall time is meaningless.
+* **Measurement** (on a TPU backend, or with an injected ``measure``):
+  times the real kernel over the candidates and keeps the fastest — the
   empirical analog of the paper's per-architecture tuning sweep (its
   Table 1 re-tunes the CUDA kernel per GPU generation).
 
@@ -54,20 +57,80 @@ __all__ = ["vmem_block_e", "pick_block_e", "candidate_blocks",
            "clear_cache", "cache_info", "cache_path", "cache_stats"]
 
 _CACHE: dict[tuple, object] = {}
+# Disk-cache schema: 2 = joint configs are (sz, grid_order) pairs.
+_DISK_VERSION = 2
 _MEASURED: set[tuple] = set()     # keys whose value came from a timing sweep
 _LOCK = threading.Lock()
 _DISK_LOADED = False
 
-VMEM_BUDGET_BYTES = 8 * 2 ** 20
-# The kernels keep ~14 block-sized arrays live (fields in/out, 3 gradients,
-# metric-applied temporaries) in the accumulation dtype.  For the multi-RHS
-# block kernels (DESIGN.md §12) that count splits into operator-side
-# residents shared across the batch (metric diagonals + mask box) and
-# per-RHS vector arrays: live = _LIVE_SHARED + _LIVE_PER_RHS * b, which
-# recovers 14 at b = 1.
-_LIVE_SHARED = 4
-_LIVE_PER_RHS = 10
-_LIVE_ARRAYS = _LIVE_SHARED + _LIVE_PER_RHS
+# Scoped-VMEM limit every compiled kernel gets (kernels/nekbone_ax._call);
+# TPU v5e has 128 MiB of VMEM per core.  The candidate lists below admit
+# only blocks whose modelled footprint (vmem_bytes) fits this limit.
+VMEM_LIMIT_BYTES = 100 * 2 ** 20
+
+
+# ---------------------------------------------------------------------------
+# the VMEM footprint model every candidate list is filtered by
+# ---------------------------------------------------------------------------
+
+def _field_bytes(n: int, lanes: int, itemsize: int = 4) -> int:
+    """VMEM bytes of one ``(n, n^2, lanes)`` field block: n^2 sublanes
+    padded to 8, lanes padded to 128, held in the (>= 4-byte) accumulation
+    dtype."""
+    return (n * (-(-(n * n) // 8) * 8) * (-(-lanes // 128) * 128)
+            * max(itemsize, 4))
+
+
+def vmem_bytes(kind: str, n: int, lanes: int, *, nrhs: int = 1,
+               halo_lanes: int = 0, depth: int = 0,
+               itemsize: int = 4) -> int:
+    """Modelled VMEM footprint of one compiled kernel block.
+
+    ``lanes`` is the block's element count (its lane width); ``kind``:
+
+    * ``"flat"`` — v1 operator / fused-CG kernels: 10 double-buffered field
+      streams (p, 6 metric, mask, r|c, w), 3 scratch fields, 2 of slack;
+    * ``"slab"`` — the v2 slab and update kernels (and their multi-RHS
+      twins): 12 field buffers per RHS (the update kernel's 4 in + 2 out
+      streams, double-buffered) plus 16 shared (metric, scratch, stitch);
+    * ``"sstep"`` / ``"cheb"`` — halo'd window kernels: windows of
+      ``lanes + 2*halo_lanes`` elements, ``depth`` = s or k.  Powers: 5
+      double-buffered window inputs + 5 scratch windows, and the update's
+      ``2*(2s+5) + 2s+1`` owned fields; Chebyshev: 4 window inputs
+      double-buffered + 7 scratch windows.
+    """
+    B = _field_bytes(n, lanes, itemsize)
+    if kind == "flat":
+        return 25 * B
+    if kind == "slab":
+        return (12 * nrhs + 16) * B
+    W = _field_bytes(n, lanes + 2 * halo_lanes, itemsize)
+    if kind == "sstep":
+        return max(15 * W + 2 * (2 * depth - 1) * B,
+                   (2 * (2 * depth + 5) + 2 * depth + 1) * B)
+    if kind == "cheb":
+        return 15 * W + 2 * B
+    raise ValueError(f"unknown kernel kind {kind!r}")
+
+
+def _fits(kind: str, n: int, lanes: int, itemsize: int, tpu: bool,
+          **kw) -> bool:
+    """A block is admissible: the model fits the limit and, for a
+    compiled (TPU) kernel, its element blocks are 128-lane aligned."""
+    halo = kw.get("halo_lanes", 0)
+    if tpu and (lanes % 128 or halo % 128):
+        return False
+    return (vmem_bytes(kind, n, lanes, itemsize=itemsize, **kw)
+            <= VMEM_LIMIT_BYTES)
+
+
+def _no_fit(kind: str, what: str) -> ValueError:
+    return ValueError(
+        f"no {kind} block of {what} fits: the modelled VMEM footprint "
+        f"(autotune.vmem_bytes) must stay under {VMEM_LIMIT_BYTES >> 20} "
+        "MiB and, on a TPU backend, element blocks must be 128-lane "
+        "aligned; use a smaller n or EX*EY cross-section, or another "
+        "pipeline")
 
 
 # ---------------------------------------------------------------------------
@@ -93,13 +156,14 @@ def _load_disk_locked() -> None:
     _DISK_LOADED = True
     try:
         raw = json.loads(cache_path().read_text())
+        if raw.get("version") != _DISK_VERSION:
+            return                     # an older schema: re-measure
         for item in raw["entries"]:
             key = tuple(item["key"])
             val = item["value"]
-            # three value shapes live in the file: ints (block/slab sizes,
-            # the v1 format — kept readable for old caches), lists (joint
-            # (sz, layout, grid_order) configs; tuples round-trip through
-            # JSON as lists), and strings (pipeline picks).
+            # three value shapes live in the file: ints (block/slab sizes),
+            # lists (joint (sz, grid_order) configs; tuples round-trip
+            # through JSON as lists), and strings (pipeline picks).
             if isinstance(val, list):
                 val, ok = tuple(val), len(val) > 0
             elif isinstance(val, str):
@@ -127,7 +191,7 @@ def _save_disk_locked() -> None:
                     "value": list(v) if isinstance(v, tuple) else v}
                    for k, v in sorted(_CACHE.items(), key=lambda kv: str(kv[0]))
                    if k in _MEASURED]
-        payload = {"version": 1, "entries": entries}
+        payload = {"version": _DISK_VERSION, "entries": entries}
         tmp = path.with_name(path.name + ".tmp")
         tmp.write_text(json.dumps(payload, indent=1))
         tmp.replace(path)
@@ -179,34 +243,28 @@ def _cached_pick(key: tuple, pick: Callable[[], tuple]):
 # ---------------------------------------------------------------------------
 
 def vmem_block_e(E: int, n: int,
-                 vmem_budget_bytes: int = VMEM_BUDGET_BYTES,
+                 vmem_budget_bytes: int = VMEM_LIMIT_BYTES,
                  itemsize: int = 4) -> int:
-    """Largest power-of-two element block whose working set fits the budget.
-
-    The kernel keeps ~14 block-sized arrays live (u, w, 6 metric fields,
-    3 gradients + 3 temporaries) in the accumulation dtype (f32, or f64 on
-    the fp64 oracle path); lanes pad n^3 up to a multiple of 128.
-    """
-    n3_padded = -(-(n ** 3) // 128) * 128
-    per_elem = _LIVE_ARRAYS * n3_padded * max(itemsize, 4)
-    be = max(1, vmem_budget_bytes // per_elem)
-    be = 1 << (be.bit_length() - 1)            # floor to power of two
-    while be > 1 and E % be:
+    """Largest power-of-two divisor of ``E`` whose modelled v1 footprint
+    (:func:`vmem_bytes` ``"flat"``) fits the budget (1 if none does)."""
+    be = 1 << (E.bit_length() - 1)
+    while be > 1 and (E % be or vmem_bytes("flat", n, be, itemsize=itemsize)
+                      > vmem_budget_bytes):
         be //= 2
     return be
 
 
-def candidate_blocks(E: int, n: int, itemsize: int = 4) -> list[int]:
-    """Power-of-two candidates (descending) from the VMEM ceiling down to 1,
-    keeping only divisors of ``E`` so no padding is introduced."""
-    ceil = vmem_block_e(E, n, itemsize=itemsize)
-    cands = []
-    be = ceil
-    while be >= 1:
-        if E % be == 0:
-            cands.append(be)
-        be //= 2
-    return cands or [1]
+def candidate_blocks(E: int, n: int, itemsize: int = 4, *,
+                     tpu: bool = False) -> list[int]:
+    """Element-block candidates (descending) for the v1 kernels: divisors
+    of ``E`` (no padding) that fit :func:`vmem_bytes` — powers of two, or
+    with ``tpu`` multiples of 128 (possibly empty)."""
+    if tpu:
+        sizes = range(E - E % 128, 0, -128)
+    else:
+        sizes = [1 << k for k in range(E.bit_length() - 1, -1, -1)]
+    return [be for be in sizes
+            if E % be == 0 and _fits("flat", n, be, itemsize, tpu)]
 
 
 def _default_measure(E: int, n: int, dtype,
@@ -218,14 +276,14 @@ def _default_measure(E: int, n: int, dtype,
     from repro.kernels import nekbone_ax as _ax
 
     rng = np.random.default_rng(0)
-    u2 = jnp.asarray(rng.normal(size=(E, n ** 3)), dtype)
-    g2 = jnp.asarray(rng.normal(size=(E, 6, n ** 3)), dtype)
+    u = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
+    g = jnp.asarray(rng.normal(size=(6, n, n * n, E)), dtype)
     D = jnp.asarray(derivative_matrix(n), dtype)
     Dt = D.T
 
     def measure(block_e: int) -> float:
         def f():
-            return _ax.nekbone_ax_pallas(u2, D, Dt, g2, n=n,
+            return _ax.nekbone_ax_pallas(u, D, Dt, g, n=n,
                                          block_e=block_e, interpret=False,
                                          acc_dtype=acc_dtype)
 
@@ -263,13 +321,16 @@ def pick_block_e(E: int, n: int, dtype=jnp.float32, *,
     backend = backend or jax.default_backend()
     acc_name = _acc_name(dtype, acc_dtype)
     key = (n, E, dtype.name, acc_name, backend)
-    # the ~14 live block arrays sit in VMEM in the *accumulation* dtype,
-    # so candidates must be sized by the wider of the pair — a (bf16, f64)
+    # the live block arrays sit in VMEM in the *accumulation* dtype, so
+    # candidates must be sized by the wider of the pair — a (bf16, f64)
     # policy holds 8-byte temporaries off 2-byte streams.
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
 
     def pick() -> tuple[int, bool]:
-        cands = candidate_blocks(E, n, itemsize=size_item)
+        cands = candidate_blocks(E, n, itemsize=size_item,
+                                 tpu=backend == "tpu")
+        if not cands:
+            raise _no_fit("v1 element", f"E={E} (n={n})")
         m = measure
         if m is None and backend == "tpu":
             m = _default_measure(E, n, dtype, acc_dtype)
@@ -285,31 +346,26 @@ def pick_block_e(E: int, n: int, dtype=jnp.float32, *,
 # ---------------------------------------------------------------------------
 
 def candidate_slab_sizes(grid: tuple[int, int, int], n: int,
-                         itemsize: int = 4, nrhs: int = 1) -> list[int]:
+                         itemsize: int = 4, nrhs: int = 1, *,
+                         tpu: bool = False) -> list[int]:
     """Slabs-per-block candidates (descending divisors of EZ).
 
-    A slab block holds ``sz * EX * EY`` elements, so the VMEM ceiling caps
+    A slab block holds ``sz * EX * EY`` elements, so the VMEM limit caps
     ``sz``; ``sz`` must divide ``EZ`` so every block covers whole slabs with
-    no padding.  ``sz = 1`` is always viable (the kernel needs at least one
-    slab resident, even if that overshoots the budget on huge x/y extents).
-    ``nrhs > 1`` (the multi-RHS block kernels) scales the per-RHS vector
-    residents while the operator-side share stays constant, so viable sz
-    shrinks as b grows.
+    no padding.  ``nrhs > 1`` (the multi-RHS block kernels) scales the
+    per-RHS vector residents while the operator-side share stays constant,
+    so viable sz shrinks as b grows.  ``tpu``: blocks must also be
+    128-lane aligned.  Possibly empty.
     """
     ex, ey, ez = grid
-    n3_padded = -(-(n ** 3) // 128) * 128
-    live = _LIVE_SHARED + _LIVE_PER_RHS * nrhs
-    per_elem = live * n3_padded * max(itemsize, 4)
-    max_block = max(1, VMEM_BUDGET_BYTES // per_elem)
-    sz_max = max(1, max_block // (ex * ey))
-    cands = [s for s in range(ez, 0, -1) if ez % s == 0 and s <= sz_max]
-    return cands or [1]
+    return [c for c in range(ez, 0, -1) if ez % c == 0 and _fits(
+        "slab", n, c * ex * ey, itemsize, tpu, nrhs=nrhs)]
 
 
 def _default_measure_slab(grid: tuple[int, int, int], n: int, dtype,
                           acc_dtype=None) -> Callable[[int], float]:
     """Times the v2 slab kernel on synthetic data for one config
-    (slab count; optionally contraction layout and grid order)."""
+    (slab count; optionally grid order)."""
     import numpy as np
 
     from repro.core.geom import axis_mask_factor
@@ -319,22 +375,20 @@ def _default_measure_slab(grid: tuple[int, int, int], n: int, dtype,
     ex, ey, ez = grid
     E = ex * ey * ez
     rng = np.random.default_rng(0)
-    p2 = jnp.asarray(rng.normal(size=(E, n ** 3)), dtype)
-    r2 = jnp.asarray(rng.normal(size=(E, n ** 3)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(E, 3, n ** 3)), dtype)
+    p = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
+    r = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
+    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
     D = jnp.asarray(derivative_matrix(n), dtype)
     mx = jnp.asarray(axis_mask_factor(ex, n), dtype)
     my = jnp.asarray(axis_mask_factor(ey, n), dtype)
     mz = jnp.asarray(axis_mask_factor(ez, n), dtype)
     beta = jnp.zeros((1, 1), _ax._accum(jnp.dtype(dtype), acc_dtype))
 
-    def measure(sz: int, layout: str = "fold",
-                grid_order: str = "parallel") -> float:
+    def measure(sz: int, grid_order: str = "parallel") -> float:
         def f():
             return _ax.nekbone_ax_slab_pallas(
-                p2, r2, D, D.T, g3, mx, my, mz, beta, n=n, grid=grid, sz=sz,
-                interpret=False, acc_dtype=acc_dtype, layout=layout,
-                grid_order=grid_order)
+                p, r, D, D.T, g3, mx, my, mz, beta, n=n, grid=grid, sz=sz,
+                interpret=False, acc_dtype=acc_dtype, grid_order=grid_order)
 
         return _timing.measure(f, reps=3, warmup=1)
 
@@ -354,21 +408,20 @@ def _default_measure_slab_block(grid: tuple[int, int, int], n: int, dtype,
     ex, ey, ez = grid
     E = ex * ey * ez
     rng = np.random.default_rng(0)
-    p3 = jnp.asarray(rng.normal(size=(nrhs, E, n ** 3)), dtype)
-    r3 = jnp.asarray(rng.normal(size=(nrhs, E, n ** 3)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(E, 3, n ** 3)), dtype)
+    p3 = jnp.asarray(rng.normal(size=(nrhs, n, n * n, E)), dtype)
+    r3 = jnp.asarray(rng.normal(size=(nrhs, n, n * n, E)), dtype)
+    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
     D = jnp.asarray(derivative_matrix(n), dtype)
     mx = jnp.asarray(axis_mask_factor(ex, n), dtype)
     my = jnp.asarray(axis_mask_factor(ey, n), dtype)
     mz = jnp.asarray(axis_mask_factor(ez, n), dtype)
     beta = jnp.zeros((1, nrhs), _ax._accum(jnp.dtype(dtype), acc_dtype))
 
-    def measure(sz: int, layout: str = "fold",
-                grid_order: str = "parallel") -> float:
+    def measure(sz: int, grid_order: str = "parallel") -> float:
         def f():
             return _ax.nekbone_ax_slab_block_pallas(
                 p3, r3, D, D.T, g3, mx, my, mz, beta, n=n, grid=grid,
-                sz=sz, interpret=False, acc_dtype=acc_dtype, layout=layout,
+                sz=sz, interpret=False, acc_dtype=acc_dtype,
                 grid_order=grid_order)
 
         return _timing.measure(f, reps=3, warmup=1)
@@ -408,7 +461,10 @@ def pick_slab_sz(grid: tuple[int, int, int], n: int, dtype=jnp.float32, *,
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
 
     def pick() -> tuple[int, bool]:
-        cands = candidate_slab_sizes(grid, n, itemsize=size_item, nrhs=nrhs)
+        cands = candidate_slab_sizes(grid, n, itemsize=size_item, nrhs=nrhs,
+                                     tpu=backend == "tpu")
+        if not cands:
+            raise _no_fit("v2 slab", f"grid {grid} (n={n}, b={nrhs})")
         m = measure
         if m is None and backend == "tpu":
             if nrhs != 1:
@@ -428,24 +484,21 @@ def pick_slab_sz(grid: tuple[int, int, int], n: int, dtype=jnp.float32, *,
 # ---------------------------------------------------------------------------
 
 def candidate_slab_sizes_sstep(grid: tuple[int, int, int], n: int, s: int,
-                               itemsize: int = 4) -> list[int]:
+                               itemsize: int = 4, *,
+                               tpu: bool = False) -> list[int]:
     """Slabs-per-block candidates for the v3 powers kernel, per ``s``.
 
     The working set is *s-dependent* twice over — the block marches
     ``sz + 2s`` slabs (owned + matrix-powers halo) and keeps the whole
     ``2s+1``-vector basis live alongside the operator temporaries — so the
     VMEM ceiling on ``sz`` shrinks as ``s`` grows and the two knobs must be
-    tuned jointly.  ``sz = 1`` stays always viable, as in
-    :func:`candidate_slab_sizes`.
+    tuned jointly.  ``tpu``: owned and halo lanes must also be 128-lane
+    aligned.  Possibly empty.
     """
     ex, ey, ez = grid
-    n3_padded = -(-(n ** 3) // 128) * 128
-    live = 2 * s + 1 + 8        # basis vectors + gradients/temporaries
-    per_slab = live * ex * ey * n3_padded * max(itemsize, 4)
-    max_slabs = max(1, VMEM_BUDGET_BYTES // per_slab)
-    sz_max = max(1, max_slabs - 2 * s)
-    cands = [c for c in range(ez, 0, -1) if ez % c == 0 and c <= sz_max]
-    return cands or [1]
+    return [c for c in range(ez, 0, -1) if ez % c == 0 and _fits(
+        "sstep", n, c * ex * ey, itemsize, tpu, halo_lanes=s * ex * ey,
+        depth=s)]
 
 
 def _default_measure_sstep(grid: tuple[int, int, int], n: int, s: int,
@@ -460,9 +513,9 @@ def _default_measure_sstep(grid: tuple[int, int, int], n: int, s: int,
     ex, ey, ez = grid
     E = ex * ey * ez
     rng = np.random.default_rng(0)
-    p2 = jnp.asarray(rng.normal(size=(E, n ** 3)), dtype)
-    r2 = jnp.asarray(rng.normal(size=(E, n ** 3)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(E, 3, n ** 3)), dtype)
+    p2 = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
+    r2 = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
+    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
     D = jnp.asarray(derivative_matrix(n), dtype)
     (mx, my, mz), (cx, cy, cz) = box_axis_factors(grid, n)
     mx, my, cx, cy = (jnp.asarray(a, dtype) for a in (mx, my, cx, cy))
@@ -470,8 +523,7 @@ def _default_measure_sstep(grid: tuple[int, int, int], n: int, s: int,
     acc = _ax._accum(jnp.dtype(dtype), acc_dtype)
     inv_theta = jnp.ones((1, 1), acc)
 
-    def measure(sz: int, layout: str = "fold",
-                grid_order: str = "parallel") -> float:
+    def measure(sz: int, grid_order: str = "parallel") -> float:
         pext = _ax.sstep_extend_field(p2, grid, sz, s)
         rext = _ax.sstep_extend_field(r2, grid, sz, s)
         gext = _ax.sstep_extend_field(g3, grid, sz, s)
@@ -481,7 +533,7 @@ def _default_measure_sstep(grid: tuple[int, int, int], n: int, s: int,
             return _ax.nekbone_ax_powers_pallas(
                 pext, rext, D, D.T, gext, mx, my, mzext, cx, cy, cz,
                 inv_theta, n=n, grid=grid, sz=sz, s=s, interpret=False,
-                acc_dtype=acc_dtype, layout=layout, grid_order=grid_order)
+                acc_dtype=acc_dtype, grid_order=grid_order)
 
         return _timing.measure(f, reps=3, warmup=1)
 
@@ -507,7 +559,10 @@ def pick_slab_sz_sstep(grid: tuple[int, int, int], n: int, s: int,
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
 
     def pick() -> tuple[int, bool]:
-        cands = candidate_slab_sizes_sstep(grid, n, s, itemsize=size_item)
+        cands = candidate_slab_sizes_sstep(
+            grid, n, s, itemsize=size_item, tpu=backend == "tpu")
+        if not cands:
+            raise _no_fit("s-step powers", f"grid {grid} (n={n}, s={s})")
         m = measure
         if m is None and backend == "tpu":
             m = _default_measure_sstep(grid, n, s, dtype, acc_dtype)
@@ -525,23 +580,20 @@ def pick_slab_sz_sstep(grid: tuple[int, int, int], n: int, s: int,
 # ---------------------------------------------------------------------------
 
 def candidate_slab_sizes_cheb(grid: tuple[int, int, int], n: int, k: int,
-                              itemsize: int = 4) -> list[int]:
+                              itemsize: int = 4, *,
+                              tpu: bool = False) -> list[int]:
     """Slabs-per-block candidates for the Chebyshev-apply kernel, per ``k``.
 
     The block marches ``sz + 2k`` slabs (owned + the matrix-powers halo of
     the k chained applications, DESIGN.md §9.3) and keeps ~12 slab-sized
     arrays live (r, d, res, z + the operator gradients/temporaries), so
     the ceiling on ``sz`` shrinks with ``k`` like the v3 kernel's does
-    with ``s``.  ``sz = 1`` stays always viable.
+    with ``s``.  ``tpu`` as :func:`candidate_slab_sizes_sstep`.
     """
     ex, ey, ez = grid
-    n3_padded = -(-(n ** 3) // 128) * 128
-    live = 12
-    per_slab = live * ex * ey * n3_padded * max(itemsize, 4)
-    max_slabs = max(1, VMEM_BUDGET_BYTES // per_slab)
-    sz_max = max(1, max_slabs - 2 * k)
-    cands = [c for c in range(ez, 0, -1) if ez % c == 0 and c <= sz_max]
-    return cands or [1]
+    return [c for c in range(ez, 0, -1) if ez % c == 0 and _fits(
+        "cheb", n, c * ex * ey, itemsize, tpu, halo_lanes=k * ex * ey,
+        depth=k)]
 
 
 def _default_measure_cheb(grid: tuple[int, int, int], n: int, k: int,
@@ -556,8 +608,8 @@ def _default_measure_cheb(grid: tuple[int, int, int], n: int, k: int,
     ex, ey, ez = grid
     E = ex * ey * ez
     rng = np.random.default_rng(0)
-    r2 = jnp.asarray(rng.normal(size=(E, n ** 3)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(E, 3, n ** 3)), dtype)
+    r2 = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
+    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
     D = jnp.asarray(derivative_matrix(n), dtype)
     (mx, my, mz), (cx, cy, cz) = box_axis_factors(grid, n)
     mx, my, cx, cy = (jnp.asarray(a, dtype) for a in (mx, my, cx, cy))
@@ -565,8 +617,7 @@ def _default_measure_cheb(grid: tuple[int, int, int], n: int, k: int,
     acc = _ax._accum(jnp.dtype(dtype), acc_dtype)
     coef = jnp.ones((k + 1, 2), acc)
 
-    def measure(sz: int, layout: str = "fold",
-                grid_order: str = "parallel") -> float:
+    def measure(sz: int, grid_order: str = "parallel") -> float:
         rext = _ax.sstep_extend_field(r2, grid, sz, k)
         gext = _ax.sstep_extend_field(g3, grid, sz, k)
         mzext = _ax.sstep_extend_zfactor(jnp.asarray(mz, dtype), sz, k)
@@ -575,7 +626,7 @@ def _default_measure_cheb(grid: tuple[int, int, int], n: int, k: int,
             return _ax.nekbone_cheb_apply_pallas(
                 rext, D, D.T, gext, mx, my, mzext, cx, cy, cz, coef,
                 n=n, grid=grid, sz=sz, k=k, interpret=False,
-                acc_dtype=acc_dtype, layout=layout, grid_order=grid_order)
+                acc_dtype=acc_dtype, grid_order=grid_order)
 
         return _timing.measure(f, reps=3, warmup=1)
 
@@ -601,7 +652,10 @@ def pick_slab_sz_cheb(grid: tuple[int, int, int], n: int, k: int,
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
 
     def pick() -> tuple[int, bool]:
-        cands = candidate_slab_sizes_cheb(grid, n, k, itemsize=size_item)
+        cands = candidate_slab_sizes_cheb(
+            grid, n, k, itemsize=size_item, tpu=backend == "tpu")
+        if not cands:
+            raise _no_fit("Chebyshev", f"grid {grid} (n={n}, k={k})")
         m = measure
         if m is None and backend == "tpu":
             m = _default_measure_cheb(grid, n, k, dtype, acc_dtype)
@@ -613,34 +667,35 @@ def pick_slab_sz_cheb(grid: tuple[int, int, int], n: int, k: int,
 
 
 # ---------------------------------------------------------------------------
-# joint (contraction layout x slab sz x grid order) configs — the
-# measured-time sweep (DESIGN.md §11).  One pick per (backend/arch, case
-# key, precision policy, precond), persisted like the sz-only picks above.
+# joint (slab sz x grid order) configs — the measured-time sweep
+# (DESIGN.md §11).  One pick per (backend/arch, case key, precision policy,
+# precond), persisted like the sz-only picks above.
 # ---------------------------------------------------------------------------
 
-def candidate_configs(sz_cands: list[int]) -> list[tuple[int, str, str]]:
-    """The joint sweep space: every (sz, layout, grid_order) triple.
+def candidate_configs(sz_cands: list[int]) -> list[tuple[int, str]]:
+    """The joint sweep space: every (sz, grid_order) pair.
 
-    Ordered sz-major with the historical (fold, parallel) point first per
-    sz, so a measured tie keeps the established configuration.
+    Ordered sz-major with the ``parallel`` point first per sz, so a
+    measured tie keeps the established configuration.
     """
-    from repro.kernels.nekbone_ax import GRID_ORDERS, LAYOUTS
+    from repro.kernels.nekbone_ax import GRID_ORDERS
 
-    return [(sz, ly, go) for sz in sz_cands
-            for ly in LAYOUTS for go in GRID_ORDERS]
+    return [(sz, go) for sz in sz_cands for go in GRID_ORDERS]
 
 
 def _pick_config(key: tuple, sz_cands: list[int], measure,
                  default_measure_factory, backend: str):
     """Shared joint-config selection: measured sweep on TPU (or with an
-    explicit ``measure(sz, layout, grid_order)``), else the heuristic
-    (largest-fitting sz, fold, parallel) — the pre-sweep configuration."""
+    explicit ``measure(sz, grid_order)``), else the heuristic
+    (largest-fitting sz, parallel)."""
     def pick() -> tuple:
+        if not sz_cands:
+            raise _no_fit(key[1], f"grid {key[3:6]} (n={key[2]})")
         m = measure
         if m is None and backend == "tpu":
             m = default_measure_factory()
         if m is None:
-            return (sz_cands[0], "fold", "parallel"), False
+            return (sz_cands[0], "parallel"), False
         cands = candidate_configs(sz_cands)
         return min(cands, key=lambda c: m(*c)), True
 
@@ -650,14 +705,14 @@ def _pick_config(key: tuple, sz_cands: list[int], measure,
 def pick_slab_config(grid: tuple[int, int, int], n: int, dtype=jnp.float32,
                      *, acc_dtype=None, backend: str | None = None,
                      precond: str | None = None, nrhs: int = 1,
-                     measure=None) -> tuple[int, str, str]:
-    """Best ``(sz, layout, grid_order)`` for the v2 slab kernel, memoized.
+                     measure=None) -> tuple[int, str]:
+    """Best ``(sz, grid_order)`` for the v2 slab kernel, memoized.
 
     The joint analog of :func:`pick_slab_sz`: on a TPU backend (or with an
-    explicit ``measure``) every (slab size x contraction layout x grid
-    iteration order) point is timed and the fastest wins; elsewhere the
-    heuristic keeps the historical (fold, parallel) configuration at the
-    VMEM-ceiling sz.  Keys use a new ``("cfg", "slab", ...)`` kind so
+    explicit ``measure``) every (slab size x grid iteration order) point
+    is timed and the fastest wins; elsewhere the heuristic keeps the
+    ``parallel`` order at the largest fitting sz.  Keys use a
+    ``("cfg", "slab", ...)`` kind so
     sz-only picks (and their persisted caches) are never aliased.
     ``nrhs`` joins the key and the sweep exactly as in
     :func:`pick_slab_sz` (the RHS batch changes both the VMEM footprint
@@ -673,7 +728,8 @@ def pick_slab_config(grid: tuple[int, int, int], n: int, dtype=jnp.float32,
     if nrhs != 1:
         key = key + (f"rhs:{nrhs}",)
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-    sz_cands = candidate_slab_sizes(grid, n, itemsize=size_item, nrhs=nrhs)
+    sz_cands = candidate_slab_sizes(grid, n, itemsize=size_item, nrhs=nrhs,
+                                    tpu=backend == "tpu")
     if nrhs != 1:
         factory = lambda: _default_measure_slab_block(  # noqa: E731
             grid, n, dtype, nrhs, acc_dtype)
@@ -686,15 +742,16 @@ def pick_slab_config(grid: tuple[int, int, int], n: int, dtype=jnp.float32,
 def pick_sstep_config(grid: tuple[int, int, int], n: int, s: int,
                       dtype=jnp.float32, *, acc_dtype=None,
                       backend: str | None = None,
-                      measure=None) -> tuple[int, str, str]:
-    """Best ``(sz, layout, grid_order)`` for the v3 powers kernel at ``s``."""
+                      measure=None) -> tuple[int, str]:
+    """Best ``(sz, grid_order)`` for the v3 powers kernel at ``s``."""
     dtype = jnp.dtype(dtype)
     backend = backend or jax.default_backend()
     ex, ey, ez = grid
     acc_name = _acc_name(dtype, acc_dtype)
     key = ("cfg", "sstep", n, ex, ey, ez, s, dtype.name, acc_name, backend)
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-    sz_cands = candidate_slab_sizes_sstep(grid, n, s, itemsize=size_item)
+    sz_cands = candidate_slab_sizes_sstep(
+        grid, n, s, itemsize=size_item, tpu=backend == "tpu")
     return _pick_config(
         key, sz_cands, measure,
         lambda: _default_measure_sstep(grid, n, s, dtype, acc_dtype), backend)
@@ -703,15 +760,16 @@ def pick_sstep_config(grid: tuple[int, int, int], n: int, s: int,
 def pick_cheb_config(grid: tuple[int, int, int], n: int, k: int,
                      dtype=jnp.float32, *, acc_dtype=None,
                      backend: str | None = None,
-                     measure=None) -> tuple[int, str, str]:
-    """Best ``(sz, layout, grid_order)`` for the Chebyshev-apply kernel."""
+                     measure=None) -> tuple[int, str]:
+    """Best ``(sz, grid_order)`` for the Chebyshev-apply kernel."""
     dtype = jnp.dtype(dtype)
     backend = backend or jax.default_backend()
     ex, ey, ez = grid
     acc_name = _acc_name(dtype, acc_dtype)
     key = ("cfg", "cheb", n, ex, ey, ez, k, dtype.name, acc_name, backend)
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-    sz_cands = candidate_slab_sizes_cheb(grid, n, k, itemsize=size_item)
+    sz_cands = candidate_slab_sizes_cheb(
+        grid, n, k, itemsize=size_item, tpu=backend == "tpu")
     return _pick_config(
         key, sz_cands, measure,
         lambda: _default_measure_cheb(grid, n, k, dtype, acc_dtype), backend)

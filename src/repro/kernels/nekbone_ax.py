@@ -2,39 +2,32 @@
 
 This is the paper's optimized ``Ax`` kernel re-derived for the TPU memory
 hierarchy (DESIGN.md §2).  The CUDA version marches an ``n x n`` thread layer
-through the element's k-layers keeping the derivative matrix in shared memory
-and per-thread columns in registers; the TPU version instead keeps a *block
-of elements* fully resident in VMEM and folds the element/layer axes into the
-M dimension of skinny matmuls so the MXU sees large, lane-aligned operands.
+through the element's k-layers; the TPU version keeps a *block of elements*
+resident in VMEM and marches the same k-layers, with the elements of the
+block on the 128-wide lane axis.
 
-Both contraction stages and the metric application are fused into one kernel:
-``u`` and the six metric fields are read from HBM exactly once and only ``w``
-is written — the 7-read/1-write traffic floor of the operator (the paper's
-Eq. 2 counts 24+6 streams for the *whole CG iteration*; the operator itself
-is 7+1).
+**Kernel layout** (DESIGN.md §2.1).  Inside every kernel a field block is
+``(n, n^2, be)``: the k-layer on a major axis, the layer's ``(j, i)`` nodes
+on sublanes (n^2 = 100 rows at n = 10, 4 % sublane padding) and ``be``
+elements on lanes.  No in-kernel reshape ever touches the lane axis — the
+Mosaic compiler refuses to split or merge it — so
 
-Two kernels share the block math (:func:`ax_block`):
+* the r/s derivatives are per-layer matmuls with the Kronecker-expanded
+  matrices ``kron(I, D)`` / ``kron(D, I)`` (n^2 x n^2, on the MXU),
+* the t derivative combines whole layers with ``D``'s scalars (SMEM, VPU),
+* the direct-stiffness face sums are lane rolls (``pltpu.roll``) by 1, EX
+  and EX*EY elements, with the face rows moved by 0/1 permutation matmuls,
+* per-block inner-product partials leave as ``(1, 1, 1)`` vector tiles.
 
-* :func:`nekbone_ax_kernel` — the plain fused operator (the Fig. 2/3 ladder's
-  top rung), 7 reads / 1 write.
-* :func:`nekbone_ax_dots_kernel` — the fused *CG-iteration* kernel
-  (DESIGN.md §3): in the same VMEM residency it also applies the Dirichlet
-  mask and emits per-block partial sums for the two weighted inner products
-  a CG iteration needs (``p·c·Ap`` and ``r·c·z``), so the separate reduction
-  passes Eq. 2 charges for disappear from the HBM budget.  The ``p·c·Ap``
-  partial uses the continuity identity (DESIGN.md §3.2): for a continuous
-  ``p``, ``p·c·(mask · gs(w)) == Σ_j p_j (mask·w)_j`` element-locally, so no
-  assembled ``w`` is needed inside the kernel.
+The ``*_pallas`` wrappers take and return fields in this layout in HBM too:
+``(..., n, n^2, E)`` fields, ``(C, n, n^2, E)`` metrics, ``(nblk, n^2,
+EX*EY)`` boundary planes.  The CG drivers convert the right-hand side and
+the operator once per solve (:func:`to_lanes`, :func:`metric_lanes`) and the
+solution once at the end (:func:`from_lanes`), so no transpose runs inside
+an iteration; ``kernels/ops.py`` converts around single calls.
 
-HBM layout: callers pass natural ``(E, n, n, n)`` arrays; the wrapper
-(`ops.nekbone_ax`) reshapes them (free, row-major) to ``(E, n^3)`` /
-``(E, 6, n^3)`` so the minor dimension is ~n^3 (lane padding 1000 -> 1024,
-2.4 % waste) instead of ``n`` (10 -> 128, 12.8x waste).
-
-The kernels are generic in ``n`` (tested 2..16) and in the element block size
-``block_e`` — the TPU analog of the paper's claim that the 2-D-thread kernel
-is "not bound by shared memory" and ports across polynomial degrees "by only
-changing a few constants".
+Mosaic has no float64.  Every wrapper refuses f64 operands unless it runs in
+interpret mode, where the fp64 oracle tests exercise the same kernel code.
 """
 from __future__ import annotations
 
@@ -42,14 +35,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["LAYOUTS", "GRID_ORDERS",
-           "nekbone_ax_kernel", "nekbone_ax_pallas", "ax_block",
-           "ax_block_diag", "nekbone_ax_dots_kernel", "nekbone_ax_dots_pallas",
+from repro.kernels.autotune import VMEM_LIMIT_BYTES
+
+__all__ = ["GRID_ORDERS", "to_lanes", "from_lanes", "metric_lanes",
+           "shift_planes", "stitch_planes",
+           "nekbone_ax_kernel", "nekbone_ax_pallas",
+           "nekbone_ax_dots_kernel", "nekbone_ax_dots_pallas",
            "nekbone_ax_pap_kernel", "nekbone_ax_pap_pallas",
            "nekbone_ax_slab_kernel", "nekbone_ax_slab_pallas",
            "nekbone_cg_update_kernel", "nekbone_cg_update_pallas",
+           "nekbone_ax_slab_block_kernel", "nekbone_ax_slab_block_pallas",
+           "nekbone_cg_update_block_kernel",
+           "nekbone_cg_update_block_pallas",
            "nekbone_ax_powers_kernel", "nekbone_ax_powers_pallas",
            "nekbone_sstep_update_kernel", "nekbone_sstep_update_pallas",
            "sstep_extend_field", "sstep_extend_zfactor",
@@ -57,19 +58,21 @@ __all__ = ["LAYOUTS", "GRID_ORDERS",
            "nekbone_cheb_apply_kernel", "nekbone_cheb_apply_pallas",
            "nekbone_interp_kernel", "nekbone_interp_pallas"]
 
-from repro.compat import CompilerParams as _CompilerParams
-from repro.core.geom import box_outer as _box_outer
+# Grid-iteration-order knob for the slab-family pallas_calls: "parallel"
+# declares the (1-D) slab grid embarrassingly parallel, "arbitrary" forces
+# sequential issue order.  Swept jointly with sz by autotune.
+GRID_ORDERS = ("parallel", "arbitrary")
+
+_HI = jax.lax.Precision.HIGHEST
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _accum(dtype, acc_dtype: str | None) -> jnp.dtype:
     """In-kernel accumulation dtype for a given storage dtype.
 
     ``acc_dtype`` is the precision policy's explicit choice (DESIGN.md §7);
-    ``None`` keeps the historical rule — f64 accumulates in f64 (the CPU
-    oracle path), every narrower storage dtype (f32, bf16) in f32.  The
-    kernels upcast operands to this dtype on load and downcast field
-    outputs on store, so storage precision never touches the contraction
-    or reduction arithmetic.
+    ``None`` keeps the historical rule — f64 accumulates in f64 (the
+    interpret-mode oracle path), every narrower storage dtype in f32.
     """
     if acc_dtype is not None:
         return jnp.dtype(acc_dtype)
@@ -81,598 +84,708 @@ def _acc_tag(acc_dtype: str | None) -> str:
     return "" if acc_dtype is None else f"_acc{jnp.dtype(acc_dtype).name}"
 
 
-def _dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """2-D matmul; accumulate in the (already upcast) operand dtype — f32 on
-    the MXU, f64 on the interpret-mode oracle path."""
-    acc = jnp.float64 if a.dtype == jnp.float64 else jnp.float32
-    return jax.lax.dot(a, b, preferred_element_type=acc)
+def _order_tag(grid_order: str = "parallel") -> str:
+    """Kernel-name suffix for a non-default grid order."""
+    if grid_order not in GRID_ORDERS:
+        raise ValueError(f"unknown grid order {grid_order!r}; "
+                         f"available: {GRID_ORDERS}")
+    return "" if grid_order == "parallel" else f"_go{grid_order}"
 
 
-# Selectable contraction layouts for the per-layer tensor products (the
-# static ``layout`` kernel parameter; autotune sweeps them per backend):
-#
-#   fold — fold (e, plane) axes into the M dimension of a skinny 2-D matmul
-#          (e*n^2, n) x (n, n), transposing operands into position first
-#          (the historical order; one dot shape for all three directions).
-#   dng  — batched ``dot_general`` directly on the 4-D block, contracting
-#          the needed axis in place against the supplied matrix's *rows*;
-#          only the *output* is transposed into (e,k,j,i) order.
-#   dnt  — ``dot_general`` on the 4-D block contracting against the *other*
-#          orientation of the derivative matrix along its *columns*
-#          (flipped dimension numbers).  Both D and Dt are VMEM-resident in
-#          every kernel, so this needs no operand transposes at all — the
-#          matrix unit just sees the opposite operand orientation.
-#
-# Every layout computes each output element as the *same* length-n dot
-# product with the contraction kept innermost, so results are
-# bitwise-identical at fp64 (gated by tests/test_kernels_ax.py); only the
-# operand orientation the backend's matrix units see differs.  (A true
-# matrix-on-LHS placement is *not* offered: XLA reassociates that GEMM and
-# breaks bitwise parity, which the parity gate would reject.)
-LAYOUTS = ("fold", "dng", "dnt")
+def _call(kernel, *, name: str, grid, in_specs, out_specs, out_shape,
+          interpret: bool, operands, grid_order: str = "parallel",
+          scratch: tuple | list = ()):
+    """``pallas_call`` with the repo-wide VMEM limit and the f64 guard.
 
-# Grid-iteration-order knob for the slab-family pallas_calls: "parallel"
-# declares the (1-D) slab grid embarrassingly parallel (the historical
-# setting — lets Mosaic reorder/overlap block iterations), "arbitrary"
-# forces sequential issue order (can win when the slab working set thrashes
-# a shared cache level).  Swept jointly with (layout, sz) by autotune.
-GRID_ORDERS = ("parallel", "arbitrary")
-
-
-def _cfg_tag(layout: str, grid_order: str = "parallel") -> str:
-    """Kernel-name suffix for a non-default (layout, grid order) config."""
-    tag = "" if layout == "fold" else f"_ly{layout}"
-    if grid_order != "parallel":
-        tag += f"_go{grid_order}"
-    return tag
-
-
-def _dg(a: jnp.ndarray, m: jnp.ndarray, axis: int,
-        maxis: int = 0) -> jnp.ndarray:
-    """``dot_general`` contracting ``a``'s ``axis`` with matrix ``m``'s
-    ``maxis``; output dims = a's free dims (in order) + m's free dim last."""
-    acc = jnp.float64 if a.dtype == jnp.float64 else jnp.float32
-    return jax.lax.dot_general(a, m, (((axis,), (maxis,)), ((), ())),
-                               preferred_element_type=acc)
-
-
-def _grad3(u: jnp.ndarray, Dt: jnp.ndarray, *, n: int, e: int,
-           layout: str = "fold", D: jnp.ndarray | None = None):
-    """Forward reference-space gradient on a VMEM block: (wr, ws, wt).
-
-    Folds (e,k,j) / (e,k,i) / (e,j,i) into the M dimension of skinny matmuls
-    so the MXU sees (e*n^2, n) x (n, n) operands (``layout="fold"``), or
-    contracts the 4-D block in place via ``dot_general`` (``"dng"`` /
-    ``"dnt"`` — see ``LAYOUTS``; ``"dnt"`` contracts against ``D`` along its
-    columns and needs it passed in).
+    Compiled (non-interpret) calls refuse float64 operands before they
+    reach Mosaic, which has no f64 type.
     """
-    if layout in ("dng", "dnt"):
-        u4 = u.reshape(e, n, n, n)
-        m, maxis = (Dt, 0) if layout == "dng" else (D, 1)
-        # wr[e,k,j,i] = sum_l u[e,k,j,l] Dt[l,i] — contract in place.
-        wr = _dg(u4, m, 3, maxis)
-        # ws[e,k,j,i] = sum_l u[e,k,l,i] Dt[l,j] -> (e,k,i,j), swap back.
-        ws = _dg(u4, m, 2, maxis).transpose(0, 1, 3, 2)
-        # wt[e,k,j,i] = sum_l u[e,l,j,i] Dt[l,k] -> (e,j,i,k), rotate back.
-        wt = _dg(u4, m, 1, maxis).transpose(0, 3, 1, 2)
-        return wr, ws, wt
-    # wr[e,k,j,i] = sum_l u[e,k,j,l] D[i,l]      (M = e*n^2, K = n, N = n)
-    wr = _dot(u.reshape(e * n * n, n), Dt).reshape(e, n, n, n)
-    # ws[e,k,j,i] = sum_l u[e,k,l,i] D[j,l]: transpose j<->i, contract, undo.
-    u_kij = u.reshape(e, n, n, n).transpose(0, 1, 3, 2)  # (e,k,i,l=j)
-    ws = _dot(u_kij.reshape(e * n * n, n), Dt)
-    ws = ws.reshape(e, n, n, n).transpose(0, 1, 3, 2)
-    # wt[e,k,j,i] = sum_l u[e,l,j,i] D[k,l]: contract the layer axis.
-    u_jil = u.reshape(e, n, n * n).transpose(0, 2, 1)    # (e, ji, l=k)
-    wt = _dot(u_jil.reshape(e * n * n, n), Dt)
-    wt = wt.reshape(e, n * n, n).transpose(0, 2, 1).reshape(e, n, n, n)
-    return wr, ws, wt
+    if not interpret:
+        f64 = [jnp.dtype(x.dtype).name for x in operands
+               if jnp.dtype(x.dtype) == jnp.float64]
+        f64 += [s.dtype.name for s in jax.tree.leaves(out_shape)
+                if jnp.dtype(s.dtype) == jnp.float64]
+        if f64:
+            raise TypeError(
+                f"{name}: float64 is not supported by the TPU kernel "
+                "compiler (Mosaic); use an f32 or bf16 storage policy with "
+                "f32 accumulation, or interpret mode for the f64 oracle")
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, interpret=interpret, name=name,
+        scratch_shapes=list(scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(grid_order,),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+    )(*operands)
 
 
-def _grad3_t(ur: jnp.ndarray, us: jnp.ndarray, ut: jnp.ndarray,
-             D: jnp.ndarray, *, n: int, e: int, layout: str = "fold",
-             Dt: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Transposed gradient (weak-form assembly) on a VMEM block, (e, n^3).
+# ---------------------------------------------------------------------------
+# HBM layout conversion and structural operands (XLA side of the wrappers)
+# ---------------------------------------------------------------------------
 
-    The three contributions are summed in the same order under every
-    ``layout`` (fold order), so the reduction rounding is layout-invariant.
-    ``"dnt"`` contracts against ``Dt`` along its columns (Dt[i,l] = D[l,i])
-    and needs it passed in.
+def to_lanes(f: jnp.ndarray, n: int) -> jnp.ndarray:
+    """``(..., E, n^3)`` element-major -> ``(..., n, n^2, E)`` kernel layout."""
+    lead, E = f.shape[:-2], f.shape[-2]
+    return jnp.swapaxes(f, -1, -2).reshape(lead + (n, n * n, E))
+
+
+def from_lanes(f: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Inverse of :func:`to_lanes`."""
+    lead, E = f.shape[:-3], f.shape[-1]
+    return jnp.swapaxes(f.reshape(lead + (n ** 3, E)), -1, -2)
+
+
+def metric_lanes(g: jnp.ndarray, n: int) -> jnp.ndarray:
+    """``(..., E, C, n^3)`` metric -> ``(..., C, n, n^2, E)``."""
+    return to_lanes(jnp.swapaxes(g, -2, -3), n)
+
+
+def shift_planes(bot: jnp.ndarray, top: jnp.ndarray):
+    """Update-kernel stitch operands from the slab kernel's planes.
+
+    ``bot``/``top`` are ``(..., nblk, n^2, EX*EY)``; returns ``(addb,
+    addt)`` with ``addb[i] = top[i-1]`` and ``addt[i] = bot[i+1]``, zeros
+    at the domain ends.
     """
-    if layout in ("dng", "dnt"):
-        m, maxis = (D, 0) if layout == "dng" else (Dt, 1)
-        w = _dg(ur, m, 3, maxis)
-        w += _dg(us, m, 2, maxis).transpose(0, 1, 3, 2)
-        w += _dg(ut, m, 1, maxis).transpose(0, 3, 1, 2)
-        return w.reshape(e, n ** 3)
-    # w += sum_l D[l,i] ur[e,k,j,l]  ==  ur @ D
-    w = _dot(ur.reshape(e * n * n, n), D).reshape(e, n, n, n)
-    us_kij = us.transpose(0, 1, 3, 2)
-    w += _dot(us_kij.reshape(e * n * n, n), D).reshape(e, n, n, n).transpose(0, 1, 3, 2)
-    ut_jil = ut.reshape(e, n, n * n).transpose(0, 2, 1)
-    wt2 = _dot(ut_jil.reshape(e * n * n, n), D)
-    w += wt2.reshape(e, n * n, n).transpose(0, 2, 1).reshape(e, n, n, n)
-    return w.reshape(e, n ** 3)
+    z = jnp.zeros_like(top[..., :1, :, :])
+    return (jnp.concatenate([z, top[..., :-1, :, :]], axis=-3),
+            jnp.concatenate([bot[..., 1:, :, :], z], axis=-3))
 
 
-def ax_block(u: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
-             g: jnp.ndarray, *, n: int, e: int) -> jnp.ndarray:
-    """Block math of  w = D^T ( G (D u) )  on VMEM-resident arrays.
+def stitch_planes(w: jnp.ndarray, bot: jnp.ndarray, top: jnp.ndarray,
+                  grid: tuple[int, int, int], sz: int) -> jnp.ndarray:
+    """Add the cross-block boundary planes to a slab kernel's ``w``.
 
-    Args:
-      u: (e, n^3) nodal values for one block of ``e`` elements.
-      D/Dt: (n, n) derivative matrix and its transpose.
-      g: (e, 6, n^3) metric (rr, rs, rt, ss, st, tt).
-    Returns (e, n^3), in the accumulation dtype of ``u``.
+    ``w`` is ``(..., n, n^2, E)`` (in-block assembled), ``bot``/``top`` the
+    kernel's ``(..., nblk, n^2, EX*EY)`` planes; returns the fully
+    assembled field (the update kernels do this stitch in VMEM instead).
     """
-    wr, ws, wt = _grad3(u, Dt, n=n, e=e)
+    ex, ey, ez = grid
+    slab, nblk = ex * ey, ez // sz
+    if nblk == 1:
+        return w
+    lead, n = w.shape[:-3], w.shape[-3]
+    v = w.reshape(lead + (n, n * n, nblk, sz * slab))
+    below = jnp.moveaxis(top[..., :-1, :, :], -3, -2)
+    above = jnp.moveaxis(bot[..., 1:, :, :], -3, -2)
+    v = v.at[..., 0, :, 1:, :slab].add(below)
+    v = v.at[..., n - 1, :, :-1, (sz - 1) * slab:].add(above)
+    return v.reshape(w.shape)
 
-    # ---- metric application (element-wise, VPU) ---------------------------
-    grr, grs, grt, gss, gst, gtt = (
-        g[:, m, :].reshape(e, n, n, n) for m in range(6))
-    ur = grr * wr + grs * ws + grt * wt
-    us = grs * wr + gss * ws + gst * wt
-    ut = grt * wr + gst * ws + gtt * wt
 
-    return _grad3_t(ur, us, ut, D, n=n, e=e)
+def _kron_ops(M: jnp.ndarray, f) -> jnp.ndarray:
+    """``[kron(I, M), kron(M, I)]`` — the in-plane (r, s) contractions of
+    one k-layer with ``(j, i)`` rows, as a ``(2, n^2, n^2)`` stack."""
+    M = jnp.asarray(M, f)
+    eye = jnp.eye(M.shape[0], dtype=f)
+    return jnp.stack([jnp.kron(eye, M), jnp.kron(M, eye)])
 
 
-def ax_block_diag(u: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
-                  g3: jnp.ndarray, *, n: int, e: int,
-                  layout: str = "fold") -> jnp.ndarray:
-    """``ax_block`` for a *diagonal* metric (axis-aligned box elements).
+def _face_perms(n: int, f) -> jnp.ndarray:
+    """0/1 row moves of the x and y faces of a ``(j, i)`` layer.
 
-    For the structured box mesh the off-diagonal metric entries are
-    identically zero (core/geom.py), so the metric application collapses to
-    three products and ``G`` to three HBM streams instead of six — half the
-    metric traffic of the general kernel, with bit-identical results (adding
-    an exactly-zero product is exact in floating point).
-
-    Args:
-      u: (e, n^3); g3: (e, 3, n^3) metric diagonal (rr, ss, tt).
+    ``[0]``: row (j, n-1) <- (j, 0);  ``[1]``: (j, 0) <- (j, n-1);
+    ``[2]``: row (n-1, i) <- (0, i);  ``[3]``: (0, i) <- (n-1, i).
     """
-    wr, ws, wt = _grad3(u, Dt, n=n, e=e, layout=layout, D=D)
-    grr, gss, gtt = (g3[:, m, :].reshape(e, n, n, n) for m in range(3))
-    return _grad3_t(grr * wr, gss * ws, gtt * wt, D, n=n, e=e, layout=layout,
-                    Dt=Dt)
+    P = np.zeros((4, n * n, n * n))
+    for a in range(n):
+        P[0, a * n + n - 1, a * n] = 1
+        P[1, a * n, a * n + n - 1] = 1
+        P[2, (n - 1) * n + a, a] = 1
+        P[3, a, (n - 1) * n + a] = 1
+    return jnp.asarray(P, f)
 
 
-def nekbone_ax_kernel(u_ref, d_ref, dt_ref, g_ref, w_ref, *, n: int,
-                      block_e: int, acc_dtype: str | None = None):
-    """Fused  w = D^T ( G (D u) )  for one block of ``block_e`` elements.
+def _lane_flags(ex: int, ey: int, nz: int, f) -> jnp.ndarray:
+    """Neighbour flags of the ``nz*ey*ex`` z-major elements of one block.
 
-    Refs (VMEM blocks):
-      u_ref:  (block_e, n^3)    nodal values
-      d_ref:  (n, n)            derivative matrix D (dxm1)
-      dt_ref: (n, n)            D^T (dxtm1) — passed separately so the kernel
-                                body issues only layout-friendly matmuls
-      g_ref:  (block_e, 6, n^3) metric (rr, rs, rt, ss, st, tt)
-      w_ref:  (block_e, n^3)    output
-
-    ``acc_dtype``: explicit accumulation dtype (precision policy); operands
-    are upcast on load, the output downcast to ``w_ref``'s storage dtype.
+    Rows: x+1, x-1, y+1, y-1, z+1, z-1 neighbour inside the block (1/0),
+    padded to 8 rows.  Identical for every block of whole slabs.
     """
-    f32 = _accum(u_ref.dtype, acc_dtype)
-    u = u_ref[...].astype(f32)
-    D = d_ref[...].astype(f32)
-    Dt = dt_ref[...].astype(f32)
-    g = g_ref[...].astype(f32)
-    w = ax_block(u, D, Dt, g, n=n, e=block_e)
-    w_ref[...] = w.astype(w_ref.dtype)
+    z, y, x = np.meshgrid(np.arange(nz), np.arange(ey), np.arange(ex),
+                          indexing="ij")
+    x, y, z = x.ravel(), y.ravel(), z.ravel()
+    fl = np.zeros((8, x.size))
+    fl[0], fl[1] = x < ex - 1, x > 0
+    fl[2], fl[3] = y < ey - 1, y > 0
+    fl[4], fl[5] = z < nz - 1, z > 0
+    return jnp.asarray(fl, f)
+
+
+def _plane_factor(fx, fy, lanes: int) -> jnp.ndarray:
+    """``fy[y(e), j] * fx[x(e), i]`` as ``(n^2, lanes)`` for z-major lanes."""
+    ey, n = fy.shape
+    ex = fx.shape[0]
+    fxy = (fy[:, None, :, None] * fx[None, :, None, :]).reshape(ey * ex,
+                                                                n * n)
+    return jnp.tile(fxy.T, (1, lanes // (ex * ey)))
+
+
+def _z_lanes(fz, slab: int) -> jnp.ndarray:
+    """``(..., Z, n)`` per-slab z factor -> ``(..., n, 1, Z*slab)`` lanes."""
+    return jnp.repeat(jnp.swapaxes(fz, -1, -2), slab, axis=-1)[..., None, :]
+
+
+def _part(parts: jnp.ndarray) -> jnp.ndarray:
+    """``(nblk, 1, b)`` partial tiles -> ``(nblk, b)``."""
+    return parts.reshape(parts.shape[0], parts.shape[-1])
+
+
+# ---------------------------------------------------------------------------
+# in-kernel building blocks (values are lists of n layers of (n^2, lanes))
+# ---------------------------------------------------------------------------
+
+def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Full-precision 2-D matmul in the operand (accumulation) dtype."""
+    return jax.lax.dot(a, b, precision=_HI, preferred_element_type=a.dtype)
+
+
+def _tdir(d_ref, vs, trans: bool):
+    """Layer combination ``out[k] = sum_l D[k, l] vs[l]`` (``D[l, k]`` when
+    ``trans``) over a static layer list, with ``D``'s scalars from SMEM."""
+    nout = d_ref.shape[1] if trans else d_ref.shape[0]
+    return [_lincomb(d_ref, lambda l: vs[l], k, len(vs), trans)
+            for k in range(nout)]
+
+
+def _lincomb(d_ref, src, k, n: int, trans: bool):
+    """``sum_l D[k, l] src(l)`` (``D[l, k]`` when ``trans``) for one output
+    layer ``k`` (static or traced), summed in ``l`` order."""
+    acc = None
+    for l in range(n):
+        t = (d_ref[l, k] if trans else d_ref[k, l]) * src(l)
+        acc = t if acc is None else acc + t
+    return acc
+
+
+def _scratch(n: int, lanes: int, f, count: int) -> list:
+    """``count`` VMEM scratch buffers of one ``(n, n^2, lanes)`` field."""
+    return [pltpu.VMEM((n, n * n, lanes), f) for _ in range(count)]
+
+
+def _apply(src, dst, kf_ref, kb_ref, d_ref, g, scr, f, *, full=False,
+           post=None, carry=0):
+    """One operator application ``D^T G D``, marched layer by layer.
+
+    ``src(k)`` loads input layer ``k`` (traced ``k``) in the accumulation
+    dtype ``f``, ``g(m, k)`` metric component ``m`` of layer ``k`` (the
+    diagonal rr, ss, tt, or all six rr rs rt ss st tt when ``full``).  The
+    metric-applied gradient goes through the three ``scr`` scratch fields;
+    output layer ``w`` is passed through ``post(k, w, carry) -> (w,
+    carry)`` and stored to ``dst[k]``.  Returns the final carry.  Per
+    layer: the r/s contractions are Kronecker matmuls, the t contraction
+    combines layers with D's SMEM scalars; the sum is r + s + t.
+    """
+    ur, us, ut = scr
+    n = ur.shape[0]
+
+    def fwd(k, c):
+        a = src(k)
+        wr = _mm(kf_ref[0].astype(f), a)
+        ws = _mm(kf_ref[1].astype(f), a)
+        wt = _lincomb(d_ref, src, k, n, trans=False)
+        if full:
+            grr, grs, grt, gss, gst, gtt = (g(m, k) for m in range(6))
+            ur[k] = grr * wr + grs * ws + grt * wt
+            us[k] = grs * wr + gss * ws + gst * wt
+            ut[k] = grt * wr + gst * ws + gtt * wt
+        else:
+            ur[k] = g(0, k) * wr
+            us[k] = g(1, k) * ws
+            ut[k] = g(2, k) * wt
+        return c
+
+    def bwd(k, c):
+        w = (_mm(kb_ref[0].astype(f), ur[k]) + _mm(kb_ref[1].astype(f), us[k])
+             + _lincomb(d_ref, lambda l: ut[l], k, n, trans=True))
+        if post is not None:
+            w, c = post(k, w, c)
+        dst[k] = w.astype(dst.dtype)
+        return c
+
+    jax.lax.fori_loop(0, n, fwd, 0)
+    return jax.lax.fori_loop(0, n, bwd, carry)
+
+
+def _total(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum of a 2-D value as a ``(1, 1)`` value."""
+    return jnp.sum(jnp.sum(x, axis=0, keepdims=True), axis=1, keepdims=True)
+
+
+def _dot_layers(a, b, c=None) -> jnp.ndarray:
+    """``sum(a * c * b)`` over layer lists (``c`` optional), as ``(1, 1)``."""
+    acc = None
+    for k in range(len(a)):
+        t = a[k] * c[k] * b[k] if c is not None else a[k] * b[k]
+        acc = t if acc is None else acc + t
+    return _total(acc)
+
+
+def _roll(a: jnp.ndarray, shift: int) -> jnp.ndarray:
+    """``out[:, e] = a[:, e + shift]`` (cyclic over the lane axis)."""
+    lanes = a.shape[-1]
+    return pltpu.roll(a, (-shift) % lanes, 1)
+
+
+def _ds_xy(a, pm_ref, fl, *, ex: int, ey: int):
+    """x then y face sums of one ``(n^2, lanes)`` layer of z-major elements.
+
+    The same pair sums as ``core/gs.ds_sum_local`` restricted to the block:
+    each face pair is ``a + b`` from the pre-step values, written to both
+    sides (``pm_ref``: :func:`_face_perms`; ``fl``: :func:`_lane_flags`).
+    """
+    if ex > 1:
+        a = (a + fl[0:1] * _mm(pm_ref[0], _roll(a, 1))
+             + fl[1:2] * _mm(pm_ref[1], _roll(a, -1)))
+    if ey > 1:
+        a = (a + fl[2:3] * _mm(pm_ref[2], _roll(a, ex))
+             + fl[3:4] * _mm(pm_ref[3], _roll(a, -ex)))
+    return a
+
+
+def _ds_z(ref, fl, *, slab: int, nz: int, lead: tuple = ()):
+    """In-place z face sums between the slabs of a block (after x, y)."""
+    if nz > 1:
+        n = ref.shape[len(lead)]
+        lo, hi = ref[lead + (0,)], ref[lead + (n - 1,)]
+        ref[lead + (n - 1,)] = hi + fl[4:5] * _roll(lo, slab)
+        ref[lead + (0,)] = lo + fl[5:6] * _roll(hi, -slab)
+
+
+def _load(ref, f, *lead):
+    """Layer list of ``ref[lead..., k]`` upcast to ``f``."""
+    n = ref.shape[len(lead)]
+    return [ref[lead + (k,)].astype(f) for k in range(n)]
+
+
+def _store(ref, layers, *lead):
+    for k, a in enumerate(layers):
+        ref[lead + (k,)] = a.astype(ref.dtype)
+
+
+def _zrow(ref, k, *lead):
+    """Row ``k`` ``(1, lanes)`` of a ``(..., n, 1, lanes)`` z-factor ref."""
+    return ref[lead + (k,)]
+
+
+# ---------------------------------------------------------------------------
+# BlockSpec helpers (1-D grid over element blocks)
+# ---------------------------------------------------------------------------
+
+def _field_spec(n: int, be: int, lead: tuple = ()):
+    nl = len(lead)
+    return pl.BlockSpec(lead + (n, n * n, be),
+                        lambda i: (0,) * (nl + 2) + (i,))
+
+
+def _full_spec(shape):
+    return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+
+
+def _lane_spec(rows: int, lanes: int):
+    """``(rows, 1, lanes)`` block of a lane-blocked ``(rows, 1, E)`` array."""
+    return pl.BlockSpec((rows, 1, lanes), lambda i: (0, 0, i))
+
+
+def _plane_spec(n: int, slab: int, lead: tuple = ()):
+    """``(..., 1, n^2, slab)`` block of ``(..., nblk, n^2, slab)`` planes."""
+    nl = len(lead)
+    return pl.BlockSpec(lead + (1, n * n, slab),
+                        lambda i: (0,) * nl + (i, 0, 0))
+
+
+def _widen(plane: jnp.ndarray, be: int, last: bool) -> jnp.ndarray:
+    """``(n^2, slab)`` plane -> ``(n^2, be)`` block layer holding it at the
+    first (``last=False``) or last slab's lanes, zeros elsewhere."""
+    slab = plane.shape[-1]
+    if slab == be:
+        return plane
+    z = jnp.zeros((plane.shape[0], be - slab), plane.dtype)
+    return jnp.concatenate([z, plane] if last else [plane, z], axis=1)
+
+
+def _part_spec(b: int = 1):
+    return pl.BlockSpec((1, 1, b), lambda i: (i, 0, 0))
+
+
+def _part_shape(nblk: int, acc, b: int = 1):
+    return jax.ShapeDtypeStruct((nblk, 1, b), acc)
+
+
+# ---------------------------------------------------------------------------
+# v1: the plain fused operator and the fused CG-iteration kernels
+# ---------------------------------------------------------------------------
+
+def nekbone_ax_kernel(u_ref, kf_ref, kb_ref, d_ref, g_ref, w_ref, *scr,
+                      acc_dtype: str | None = None):
+    """Fused  w = D^T ( G (D u) )  for one block of elements.
+
+    Refs: u_ref/w_ref ``(n, n^2, be)``; kf_ref/kb_ref ``(2, n^2, n^2)``
+    forward/backward Kronecker operators; d_ref ``(n, n)`` D in SMEM;
+    g_ref ``(6, n, n^2, be)`` metric; ``scr`` three VMEM scratch fields.
+    """
+    f = _accum(u_ref.dtype, acc_dtype)
+    _apply(lambda k: u_ref[k].astype(f), w_ref, kf_ref, kb_ref, d_ref,
+           lambda m, k: g_ref[m, k].astype(f), scr, f, full=True)
+
+
+def _operator_operands(D, Dt, f):
+    return _kron_ops(D, f), _kron_ops(Dt, f), jnp.asarray(D, f)
+
+
+def _op_specs(n):
+    return [_full_spec((2, n * n, n * n)), _full_spec((2, n * n, n * n)),
+            _SMEM]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "block_e", "interpret",
                                              "acc_dtype"))
-def nekbone_ax_pallas(u2: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
-                      g2: jnp.ndarray, *, n: int, block_e: int,
+def nekbone_ax_pallas(u: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
+                      g: jnp.ndarray, *, n: int, block_e: int,
                       interpret: bool = False,
                       acc_dtype: str | None = None) -> jnp.ndarray:
-    """pallas_call wrapper on pre-flattened operands.
+    """pallas_call wrapper on kernel-layout operands.
 
     Args:
-      u2: (E, n^3), g2: (E, 6, n^3), D/Dt: (n, n); E divisible by block_e.
-      acc_dtype: explicit in-kernel accumulation dtype name (default: the
-        storage-derived rule of :func:`_accum`).
+      u: (n, n^2, E), g: (6, n, n^2, E), D/Dt: (n, n); E divisible by
+      block_e.  Returns w (n, n^2, E).
     """
-    E = u2.shape[0]
+    E = u.shape[-1]
     assert E % block_e == 0, (E, block_e)
-    n3 = n ** 3
-    grid = (E // block_e,)
-    return pl.pallas_call(
-        functools.partial(nekbone_ax_kernel, n=n, block_e=block_e,
-                          acc_dtype=acc_dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_e, n3), lambda i: (i, 0)),
-            pl.BlockSpec((n, n), lambda i: (0, 0)),
-            pl.BlockSpec((n, n), lambda i: (0, 0)),
-            pl.BlockSpec((block_e, 6, n3), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_e, n3), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((E, n3), u2.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
+    f = _accum(u.dtype, acc_dtype)
+    kf, kb, d = _operator_operands(D, Dt, f)
+    return _call(
+        functools.partial(nekbone_ax_kernel, acc_dtype=acc_dtype),
         name=f"nekbone_ax_n{n}_be{block_e}{_acc_tag(acc_dtype)}",
-    )(u2, D, Dt, g2)
+        grid=(E // block_e,),
+        in_specs=[_field_spec(n, block_e)] + _op_specs(n)
+        + [_field_spec(n, block_e, (6,))],
+        out_specs=_field_spec(n, block_e),
+        out_shape=jax.ShapeDtypeStruct((n, n * n, E), u.dtype),
+        interpret=interpret, scratch=_scratch(n, block_e, f, 3),
+        operands=(u, kf, kb, d, g))
 
 
-# ---------------------------------------------------------------------------
-# Fused CG-iteration kernel: masked Ax + per-block partial inner products
-# ---------------------------------------------------------------------------
+def _masked_pap(p_ref, w_ref, kf_ref, kb_ref, d_ref, g_ref, mask_ref, scr,
+                f):
+    """Masked full-metric Ax into ``w_ref``; returns the ``sum(p * w)``
+    partial as ``(1, 1)``."""
+    def post(k, w, acc):
+        w = w * mask_ref[k].astype(f)
+        return w, acc + p_ref[k].astype(f) * w
 
-def nekbone_ax_dots_kernel(p_ref, d_ref, dt_ref, g_ref, mask_ref, r_ref,
-                           c_ref, w_ref, pap_ref, rcz_ref, *, n: int,
-                           block_e: int, acc_dtype: str | None = None):
+    acc = _apply(lambda k: p_ref[k].astype(f), w_ref, kf_ref, kb_ref, d_ref,
+                 lambda m, k: g_ref[m, k].astype(f), scr, f, full=True,
+                 post=post, carry=jnp.zeros(p_ref.shape[1:], f))
+    return _total(acc)
+
+
+def nekbone_ax_dots_kernel(p_ref, kf_ref, kb_ref, d_ref, g_ref, mask_ref,
+                           r_ref, c_ref, w_ref, pap_ref, rcz_ref, *scr,
+                           acc_dtype: str | None = None):
     """Masked Ax plus the two CG inner-product partials, one element block.
-
-    In the same VMEM residency as the operator this computes
 
         w   = mask * (D^T G D p)                    (block output)
         pap = sum(p * w)                            (per-block partial)
         rcz = sum(r * c * r)                        (per-block partial)
 
-    ``pap`` relies on ``p`` being continuous (all copies of a shared node
-    equal — the CG invariant): then ``Σ_blocks pap == p·c·A p`` with
-    ``A = mask ∘ gs ∘ ax_local``, because the gather-scatter transfers onto
-    the other factor of the product (DESIGN.md §3.2).  ``rcz`` is the
-    weighted residual norm ``r·c·z`` with ``z = r`` (unpreconditioned CG).
-
-    Refs (VMEM blocks):
-      p_ref:    (block_e, n^3)     search direction
-      d_ref:    (n, n)             D;  dt_ref: (n, n)  D^T
-      g_ref:    (block_e, 6, n^3)  metric
-      mask_ref: (block_e, n^3)     Dirichlet mask (0/1)
-      r_ref:    (block_e, n^3)     residual
-      c_ref:    (block_e, n^3)     inner-product weight  mask/multiplicity
-      w_ref:    (block_e, n^3)     masked local Ax output
-      pap_ref:  (1, 1)             partial  Σ p * w
-      rcz_ref:  (1, 1)             partial  Σ r * c * r
+    ``pap`` relies on ``p`` being continuous (the CG invariant): then
+    ``sum_blocks pap == p·c·A p`` with ``A = mask ∘ gs ∘ ax_local``
+    (DESIGN.md §3.2).  Fields are ``(n, n^2, be)`` blocks; the partials are
+    ``(1, 1, 1)`` tiles.
     """
-    f32 = _accum(p_ref.dtype, acc_dtype)
-    p = p_ref[...].astype(f32)
-    D = d_ref[...].astype(f32)
-    Dt = dt_ref[...].astype(f32)
-    g = g_ref[...].astype(f32)
-    w = ax_block(p, D, Dt, g, n=n, e=block_e)
-    w = w * mask_ref[...].astype(f32)
-
-    r = r_ref[...].astype(f32)
-    c = c_ref[...].astype(f32)
-    pap_ref[0, 0] = jnp.sum(p * w).astype(pap_ref.dtype)
-    rcz_ref[0, 0] = jnp.sum(r * c * r).astype(rcz_ref.dtype)
-    w_ref[...] = w.astype(w_ref.dtype)
+    f = _accum(p_ref.dtype, acc_dtype)
+    pap_ref[0] = _masked_pap(p_ref, w_ref, kf_ref, kb_ref, d_ref, g_ref,
+                             mask_ref, scr, f).astype(pap_ref.dtype)
+    rcz_ref[0] = _dot_layers(_load(r_ref, f), _load(r_ref, f),
+                             _load(c_ref, f)).astype(rcz_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "block_e", "interpret",
                                              "acc_dtype"))
-def nekbone_ax_dots_pallas(p2: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
-                           g2: jnp.ndarray, mask2: jnp.ndarray,
-                           r2: jnp.ndarray, c2: jnp.ndarray, *, n: int,
+def nekbone_ax_dots_pallas(p: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
+                           g: jnp.ndarray, mask: jnp.ndarray,
+                           r: jnp.ndarray, c: jnp.ndarray, *, n: int,
                            block_e: int, interpret: bool = False,
                            acc_dtype: str | None = None):
     """Multi-output pallas_call for the fused CG iteration.
 
-    Args: all field operands pre-flattened to (E, n^3) (g2: (E, 6, n^3));
-    E divisible by block_e.  Returns ``(w2, pap_parts, rcz_parts)`` with the
-    partials of shape ``(E // block_e, 1)`` — tree-reduce them with
-    ``jnp.sum`` on the host side of the call.
-
-    Partials accumulate (and are emitted) in ``acc_dtype`` when given, else
-    f32 for <=f32 inputs and f64 for f64 (the paper's precision, exercised
-    through interpret mode).
+    Args: kernel-layout fields (n, n^2, E) (g: (6, n, n^2, E)); E divisible
+    by block_e.  Returns ``(w, pap_parts, rcz_parts)`` with partials
+    ``(E//block_e, 1)`` in the accumulation dtype.
     """
-    E = p2.shape[0]
+    E = p.shape[-1]
     assert E % block_e == 0, (E, block_e)
-    n3 = n ** 3
     nblk = E // block_e
-    acc = _accum(p2.dtype, acc_dtype)
-    field = pl.BlockSpec((block_e, n3), lambda i: (i, 0))
-    part = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_ax_dots_kernel, n=n, block_e=block_e,
-                          acc_dtype=acc_dtype),
-        grid=(nblk,),
-        in_specs=[
-            field,                                      # p
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # D
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # Dt
-            pl.BlockSpec((block_e, 6, n3), lambda i: (i, 0, 0)),  # g
-            field,                                      # mask
-            field,                                      # r
-            field,                                      # c
-        ],
-        out_specs=(field, part, part),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, n3), p2.dtype),
-            jax.ShapeDtypeStruct((nblk, 1), acc),
-            jax.ShapeDtypeStruct((nblk, 1), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
+    f = _accum(p.dtype, acc_dtype)
+    kf, kb, d = _operator_operands(D, Dt, f)
+    field = _field_spec(n, block_e)
+    w, pap, rcz = _call(
+        functools.partial(nekbone_ax_dots_kernel, acc_dtype=acc_dtype),
         name=f"nekbone_ax_dots_n{n}_be{block_e}{_acc_tag(acc_dtype)}",
-    )(p2, D, Dt, g2, mask2, r2, c2)
+        grid=(nblk,),
+        in_specs=[field] + _op_specs(n)
+        + [_field_spec(n, block_e, (6,)), field, field, field],
+        out_specs=(field, _part_spec(), _part_spec()),
+        out_shape=(jax.ShapeDtypeStruct((n, n * n, E), p.dtype),
+                   _part_shape(nblk, f), _part_shape(nblk, f)),
+        interpret=interpret, scratch=_scratch(n, block_e, f, 3),
+        operands=(p, kf, kb, d, g, mask, r, c))
+    return w, _part(pap), _part(rcz)
 
 
-# ---------------------------------------------------------------------------
-# pap-only kernel: the dots kernel with the r·c·r partial carried instead
-# ---------------------------------------------------------------------------
-
-def nekbone_ax_pap_kernel(p_ref, d_ref, dt_ref, g_ref, mask_ref, w_ref,
-                          pap_ref, *, n: int, block_e: int,
-                          acc_dtype: str | None = None):
-    """Masked Ax plus the ``p·c·Ap`` partial only (DESIGN.md §3.3).
-
-    The ``r·c·r`` partial of :func:`nekbone_ax_dots_kernel` equals the
-    previous iteration's post-update reduction; once the solver carries that
-    scalar through its loop state the kernel's ``r``/``c`` operands are dead
-    weight — dropping them takes the fused-v1 iteration from 19 to 17
-    streams.  Refs as in :func:`nekbone_ax_dots_kernel` minus ``r``/``c``
-    and ``rcz``.
-    """
-    f32 = _accum(p_ref.dtype, acc_dtype)
-    p = p_ref[...].astype(f32)
-    D = d_ref[...].astype(f32)
-    Dt = dt_ref[...].astype(f32)
-    g = g_ref[...].astype(f32)
-    w = ax_block(p, D, Dt, g, n=n, e=block_e)
-    w = w * mask_ref[...].astype(f32)
-    pap_ref[0, 0] = jnp.sum(p * w).astype(pap_ref.dtype)
-    w_ref[...] = w.astype(w_ref.dtype)
+def nekbone_ax_pap_kernel(p_ref, kf_ref, kb_ref, d_ref, g_ref, mask_ref,
+                          w_ref, pap_ref, *scr, acc_dtype: str | None = None):
+    """Masked Ax plus the ``p·c·Ap`` partial only (DESIGN.md §3.3): the
+    dots kernel with the ``r·c·r`` partial carried by the solver instead."""
+    f = _accum(p_ref.dtype, acc_dtype)
+    pap_ref[0] = _masked_pap(p_ref, w_ref, kf_ref, kb_ref, d_ref, g_ref,
+                             mask_ref, scr, f).astype(pap_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "block_e", "interpret",
                                              "acc_dtype"))
-def nekbone_ax_pap_pallas(p2: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
-                          g2: jnp.ndarray, mask2: jnp.ndarray, *, n: int,
+def nekbone_ax_pap_pallas(p: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
+                          g: jnp.ndarray, mask: jnp.ndarray, *, n: int,
                           block_e: int, interpret: bool = False,
                           acc_dtype: str | None = None):
-    """pallas_call wrapper: returns ``(w2, pap_parts)`` (carried-rtz path)."""
-    E = p2.shape[0]
+    """pallas_call wrapper: returns ``(w, pap_parts)`` (carried-rtz path);
+    operands as :func:`nekbone_ax_dots_pallas`."""
+    E = p.shape[-1]
     assert E % block_e == 0, (E, block_e)
-    n3 = n ** 3
     nblk = E // block_e
-    acc = _accum(p2.dtype, acc_dtype)
-    field = pl.BlockSpec((block_e, n3), lambda i: (i, 0))
-    part = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_ax_pap_kernel, n=n, block_e=block_e,
-                          acc_dtype=acc_dtype),
-        grid=(nblk,),
-        in_specs=[
-            field,                                      # p
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # D
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # Dt
-            pl.BlockSpec((block_e, 6, n3), lambda i: (i, 0, 0)),  # g
-            field,                                      # mask
-        ],
-        out_specs=(field, part),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, n3), p2.dtype),
-            jax.ShapeDtypeStruct((nblk, 1), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
+    f = _accum(p.dtype, acc_dtype)
+    kf, kb, d = _operator_operands(D, Dt, f)
+    field = _field_spec(n, block_e)
+    w, pap = _call(
+        functools.partial(nekbone_ax_pap_kernel, acc_dtype=acc_dtype),
         name=f"nekbone_ax_pap_n{n}_be{block_e}{_acc_tag(acc_dtype)}",
-    )(p2, D, Dt, g2, mask2)
+        grid=(nblk,),
+        in_specs=[field] + _op_specs(n)
+        + [_field_spec(n, block_e, (6,)), field],
+        out_specs=(field, _part_spec()),
+        out_shape=(jax.ShapeDtypeStruct((n, n * n, E), p.dtype),
+                   _part_shape(nblk, f)),
+        interpret=interpret, scratch=_scratch(n, block_e, f, 3),
+        operands=(p, kf, kb, d, g, mask))
+    return w, _part(pap)
 
 
 # ---------------------------------------------------------------------------
 # v2 slab pipeline: in-kernel gather-scatter + merged vector updates
-# (DESIGN.md §3.4).  The grid marches whole z-slabs of the element box so the
-# x/y direct-stiffness summation and the intra-block z interfaces are summed
-# on the VMEM-resident output; only the two block-boundary z-planes leave the
-# kernel as O(E n^2) side outputs.  The Dirichlet mask and the inner-product
-# weight c = mask/mult are *per-axis index products* on the structured box
-# (core/geom.py), so both kernels rebuild them in VMEM from three tiny
-# (extent, n) factor arrays instead of streaming full fields.
+# (DESIGN.md §3.4).  The grid marches whole z-slabs of the element box, so a
+# block's lanes are ``sz*EY*EX`` z-major elements and the x/y direct-
+# stiffness summation and the intra-block z interfaces are lane rolls on the
+# VMEM-resident output; the block's two boundary layers leave the kernel as
+# side outputs.  The Dirichlet mask and the weight c = mask/mult are per-axis
+# products on the structured box (core/geom.py): the wrappers expand them to
+# an (n^2, lanes) x/y plane factor shared by every block and an (n, E)
+# lane-blocked z factor.
 # ---------------------------------------------------------------------------
 
-def nekbone_ax_slab_kernel(p_ref, r_ref, d_ref, dt_ref, g_ref, mx_ref, my_ref,
-                           mz_ref, beta_ref, p_out, w_ref, bot_ref, top_ref,
-                           pap_ref, *, n: int, ex: int, ey: int, sz: int,
-                           acc_dtype: str | None = None,
-                           layout: str = "fold"):
-    """Fused CG front-half on one block of ``sz`` whole z-slabs.
+def _slab_front(src, dst, kf_ref, kb_ref, d_ref, g, mask, pm_ref, fl, scr,
+                f, *, ex, ey, nz):
+    """Masked operator, the pre-assembly ``sum(src * w)`` partial, and the
+    in-block assembly of one block of ``nz`` slabs into ``dst``; returns
+    the partial as ``(1, 1)``.  ``mask(k)`` is layer k's Dirichlet mask;
+    ``scr`` four scratch fields in the accumulation dtype (the last holds
+    the output until its assembly is complete)."""
+    def post(k, w, acc):
+        v = w * mask(k)
+        # continuity identity (DESIGN.md §3.2): the partial must see the
+        # *unassembled* masked output — summation redistributes values.
+        return _ds_xy(v, pm_ref, fl, ex=ex, ey=ey), acc + src(k) * v
 
-    In one VMEM residency:
+    ur, us, ut, wacc = scr
+    acc = _apply(src, wacc, kf_ref, kb_ref, d_ref, g, (ur, us, ut), f,
+                 post=post, carry=jnp.zeros(dst.shape[1:], f))
+    _ds_z(wacc, fl, slab=ex * ey, nz=nz)
+
+    def store(k, c):
+        dst[k] = wacc[k].astype(dst.dtype)
+        return c
+
+    jax.lax.fori_loop(0, dst.shape[0], store, 0)
+    return _total(acc)
+
+
+def _direction(p_out, r_ref, p_ref, beta, f):
+    """``p_out = r + beta * p_prev``, rounded through the storage dtype: the
+    update kernel applies alpha to the stored p, so w must be A of exactly
+    that vector (identity for f32/f64, load-bearing for bf16)."""
+    def body(k, c):
+        p_out[k] = (r_ref[k].astype(f)
+                    + beta * p_ref[k].astype(f)).astype(p_out.dtype)
+        return c
+
+    jax.lax.fori_loop(0, p_out.shape[0], body, 0)
+
+
+def nekbone_ax_slab_kernel(p_ref, r_ref, kf_ref, kb_ref, d_ref, g_ref,
+                           mxy_ref, mz_ref, fl_ref, pm_ref, beta_ref, p_out,
+                           w_ref, bot_ref, top_ref, pap_ref, *scr, ex: int,
+                           ey: int, sz: int, acc_dtype: str | None = None):
+    """Fused CG front-half on one block of ``sz`` whole z-slabs.
 
         p   = r + beta * p_prev              (merged-CG direction update)
         w   = mask * (D^T G D p)             (diagonal metric, structural mask)
         pap = sum(p * w)                     (partial, *before* assembly)
         w  <- ds_sum within the block        (x, y, and intra-block z faces)
 
-    The block's outermost z-planes (after x/y assembly; untouched by the
-    intra-block z summation) are emitted so the update kernel can stitch
-    neighbouring blocks without a full-field pass.
-
-    Refs (VMEM blocks; ``block_e = sz*ey*ex`` elements, z-major):
-      p_ref:    (block_e, n^3)   previous search direction
-      r_ref:    (block_e, n^3)   residual
-      d_ref/dt_ref: (n, n)       D and D^T
-      g_ref:    (block_e, 3, n^3) metric diagonal (rr, ss, tt)
-      mx_ref:   (ex, n)          per-axis Dirichlet factors (my: (ey, n),
-      my_ref:   (ey, n)           mz: the block's (sz, n) slice of (EZ, n))
-      mz_ref:   (sz, n)
-      beta_ref: (1, 1)           beta scalar (0 on the first iteration)
-      p_out:    (block_e, n^3)   updated direction
-      w_ref:    (block_e, n^3)   masked, block-assembled operator output
-      bot_ref:  (1, ey*ex*n^2)   bottom boundary plane (k = 0 of slab 0)
-      top_ref:  (1, ey*ex*n^2)   top boundary plane (k = n-1 of slab sz-1)
-      pap_ref:  (1, 1)           partial  sum(p * mask * w_local)
+    Refs: p/r/p_out/w ``(n, n^2, be)``; g ``(3, n, n^2, be)`` metric
+    diagonal; mxy ``(n^2, be)`` and mz ``(n, 1, be)`` mask factors; fl
+    ``(8, be)`` lane flags; pm ``(4, n^2, n^2)`` face moves; beta ``(1, 1)``
+    in SMEM; bot/top ``(1, n^2, EX*EY)`` the assembled layer k=0 of the
+    block's first slab / k=n-1 of its last; pap ``(1, 1, 1)``; ``scr``
+    four VMEM scratch fields.
     """
-    block_e = sz * ey * ex
-    f32 = _accum(p_ref.dtype, acc_dtype)
-    out_dtype = w_ref.dtype
-    beta = beta_ref[0, 0].astype(f32)
-    p = r_ref[...].astype(f32) + beta * p_ref[...].astype(f32)
-    # round the direction through the *storage* dtype before the operator:
-    # the update kernel applies alpha to the stored p, so w must be A of
-    # exactly that vector — an unrounded p here would make w inconsistent
-    # with the CG algebra by O(storage eps), which diverges bf16 CG on
-    # ill-conditioned cases.  For f32/f64 storage this is the identity.
-    p = p.astype(out_dtype).astype(f32)
-    D = d_ref[...].astype(f32)
-    Dt = dt_ref[...].astype(f32)
-    g3 = g_ref[...].astype(f32)
-    w = ax_block_diag(p, D, Dt, g3, n=n, e=block_e, layout=layout)
+    f = _accum(p_ref.dtype, acc_dtype)
+    _direction(p_out, r_ref, p_ref, beta_ref[0, 0], f)
+    mxy = mxy_ref[...].astype(f)
+    pap = _slab_front(lambda k: p_out[k].astype(f), w_ref, kf_ref, kb_ref,
+                      d_ref, lambda m, k: g_ref[m, k].astype(f),
+                      lambda k: mz_ref[k].astype(f) * mxy, pm_ref,
+                      fl_ref[...], scr, f, ex=ex, ey=ey, nz=sz)
+    pap_ref[0] = pap.astype(pap_ref.dtype)
+    _boundary_planes(w_ref, bot_ref, top_ref, ex * ey)
 
-    # structural mask: outer product of the three per-axis 0/1 factors
-    mask = _box_outer(mz_ref[...].astype(f32), my_ref[...].astype(f32),
-                      mx_ref[...].astype(f32))
-    v = w.reshape(sz, ey, ex, n, n, n) * mask
 
-    # continuity identity (DESIGN.md §3.2): the partial must see the
-    # *unassembled* masked output — summation below redistributes values.
-    pap_ref[0, 0] = jnp.sum(p.reshape(v.shape) * v).astype(pap_ref.dtype)
+def _boundary_planes(w_ref, bot_ref, top_ref, slab: int, *lead):
+    """The block's outgoing planes: layer 0 of its first slab and layer
+    n-1 of its last (the cross-block halves of the z face sums)."""
+    n, be = w_ref.shape[len(lead)], w_ref.shape[-1]
+    bot_ref[lead + (0,)] = w_ref[lead + (0, slice(None), slice(0, slab))]
+    top_ref[lead + (0,)] = w_ref[lead + (n - 1, slice(None),
+                                         slice(be - slab, be))]
 
-    # in-block direct stiffness: same pair sums, same order as
-    # core/gs.ds_sum_local restricted to the block (x, then y, then z).
-    if ex > 1:
-        s = v[:, :, :-1, :, :, -1] + v[:, :, 1:, :, :, 0]
-        v = v.at[:, :, :-1, :, :, -1].set(s)
-        v = v.at[:, :, 1:, :, :, 0].set(s)
-    if ey > 1:
-        s = v[:, :-1, :, :, -1, :] + v[:, 1:, :, :, 0, :]
-        v = v.at[:, :-1, :, :, -1, :].set(s)
-        v = v.at[:, 1:, :, :, 0, :].set(s)
-    if sz > 1:
-        s = v[:-1, :, :, -1, :, :] + v[1:, :, :, 0, :, :]
-        v = v.at[:-1, :, :, -1, :, :].set(s)
-        v = v.at[1:, :, :, 0, :, :].set(s)
 
-    w_ref[...] = v.reshape(block_e, n ** 3).astype(out_dtype)
-    p_out[...] = p.astype(out_dtype)
-    pln = ey * ex * n * n
-    bot_ref[...] = v[0, :, :, 0, :, :].reshape(1, pln).astype(out_dtype)
-    top_ref[...] = v[-1, :, :, -1, :, :].reshape(1, pln).astype(out_dtype)
+def _box_layers(fz_ref, fxy, f, *lead):
+    """Per-layer structural factor ``fz[k] * fxy`` of a block."""
+    n = fz_ref.shape[len(lead)]
+    return [_zrow(fz_ref, k, *lead).astype(f) * fxy for k in range(n)]
+
+
+def _slab_structure(grid, n, sz, mx, my, mz, f):
+    """Structural operands of a slab block: (mxy, mz lanes, flags, perms)."""
+    ex, ey, _ = grid
+    be = sz * ex * ey
+    return (_plane_factor(mx, my, be), _z_lanes(mz, ex * ey),
+            _lane_flags(ex, ey, sz, f), _face_perms(n, f))
+
+
+def _struct_specs(n, be):
+    return [_full_spec((n * n, be)), _lane_spec(n, be), _full_spec((8, be)),
+            _full_spec((4, n * n, n * n))]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "interpret",
-                                             "acc_dtype", "layout",
-                                             "grid_order"))
-def nekbone_ax_slab_pallas(p2: jnp.ndarray, r2: jnp.ndarray, D: jnp.ndarray,
+                                             "acc_dtype", "grid_order"))
+def nekbone_ax_slab_pallas(p: jnp.ndarray, r: jnp.ndarray, D: jnp.ndarray,
                            Dt: jnp.ndarray, g3: jnp.ndarray, mx: jnp.ndarray,
                            my: jnp.ndarray, mz: jnp.ndarray,
                            beta: jnp.ndarray, *, n: int,
                            grid: tuple[int, int, int], sz: int,
                            interpret: bool = False,
                            acc_dtype: str | None = None,
-                           layout: str = "fold",
                            grid_order: str = "parallel"):
     """Multi-output pallas_call for the v2 slab dots kernel.
 
     Args:
-      p2/r2: (E, n^3); g3: (E, 3, n^3); mx/my/mz: (EX|EY|EZ, n) per-axis
-      mask factors; beta: (1, 1) scalar operand; grid: (EX, EY, EZ) with
-      ``EZ % sz == 0`` and elements z-major.
+      p/r: (n, n^2, E); g3: (3, n, n^2, E) metric diagonal; mx/my/mz:
+      (EX|EY|EZ, n) per-axis mask factors; beta: (1, 1) scalar operand;
+      grid: (EX, EY, EZ) with ``EZ % sz == 0`` and elements z-major.
       acc_dtype: explicit accumulation dtype (precision policy); the field
-      outputs stay in the storage dtype of ``p2``, the pap partials in acc.
-      layout/grid_order: static contraction layout (``LAYOUTS``) and grid
-      iteration order (``GRID_ORDERS``) — autotuned jointly with ``sz``.
+      outputs stay in the storage dtype of ``p``, the pap partials in acc.
+      grid_order: ``GRID_ORDERS`` point, autotuned jointly with ``sz``.
 
-    Returns ``(p2_new, w2, bot, top, pap_parts)`` with the boundary planes of
-    shape ``(EZ//sz, EY*EX*n^2)`` and partials ``(EZ//sz, 1)``.
+    Returns ``(p_new, w, bot, top, pap_parts)`` with the boundary planes of
+    shape ``(EZ//sz, n^2, EX*EY)`` and partials ``(EZ//sz, 1)``.
     """
     ex, ey, ez = grid
-    E = p2.shape[0]
+    E = p.shape[-1]
     assert E == ex * ey * ez and ez % sz == 0, (grid, sz, E)
-    block_e = sz * ey * ex
+    slab = ex * ey
+    be = sz * slab
     nblk = ez // sz
-    n3 = n ** 3
-    pln = ey * ex * n * n
-    acc = _accum(p2.dtype, acc_dtype)
-    field = pl.BlockSpec((block_e, n3), lambda i: (i, 0))
-    plane = pl.BlockSpec((1, pln), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_ax_slab_kernel, n=n, ex=ex, ey=ey, sz=sz,
-                          acc_dtype=acc_dtype, layout=layout),
-        grid=(nblk,),
-        in_specs=[
-            field,                                      # p_prev
-            field,                                      # r
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # D
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # Dt
-            pl.BlockSpec((block_e, 3, n3), lambda i: (i, 0, 0)),  # g diag
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # mask factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # mask factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # mask factor z slice
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),     # beta
-        ],
-        out_specs=(field, field, plane, plane,
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, n3), p2.dtype),    # p
-            jax.ShapeDtypeStruct((E, n3), p2.dtype),    # w
-            jax.ShapeDtypeStruct((nblk, pln), p2.dtype),
-            jax.ShapeDtypeStruct((nblk, pln), p2.dtype),
-            jax.ShapeDtypeStruct((nblk, 1), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=(grid_order,),
-        ),
-        interpret=interpret,
+    f = _accum(p.dtype, acc_dtype)
+    kf, kb, d = _operator_operands(D, Dt, f)
+    field = _field_spec(n, be)
+    sdt = p.dtype
+    p, w, bot, top, pap = _call(
+        functools.partial(nekbone_ax_slab_kernel, ex=ex, ey=ey, sz=sz,
+                          acc_dtype=acc_dtype),
         name=(f"nekbone_ax_slab_n{n}_sz{sz}{_acc_tag(acc_dtype)}"
-              f"{_cfg_tag(layout, grid_order)}"),
-    )(p2, r2, D, Dt, g3, mx, my, mz, beta)
+              f"{_order_tag(grid_order)}"),
+        grid=(nblk,),
+        in_specs=[field, field] + _op_specs(n)
+        + [_field_spec(n, be, (3,))] + _struct_specs(n, be) + [_SMEM],
+        out_specs=(field, field, _plane_spec(n, slab), _plane_spec(n, slab),
+                   _part_spec()),
+        out_shape=(jax.ShapeDtypeStruct((n, n * n, E), sdt),
+                   jax.ShapeDtypeStruct((n, n * n, E), sdt),
+                   jax.ShapeDtypeStruct((nblk, n * n, slab), sdt),
+                   jax.ShapeDtypeStruct((nblk, n * n, slab), sdt),
+                   _part_shape(nblk, f)),
+        interpret=interpret, grid_order=grid_order,
+        scratch=_scratch(n, be, f, 4),
+        operands=(p, r, kf, kb, d, g3,
+                  *_slab_structure(grid, n, sz, mx, my, mz, f),
+                  jnp.asarray(beta, f).reshape(1, 1)))
+    return p, w, bot, top, _part(pap)
 
 
-def nekbone_cg_update_kernel(x_ref, p_ref, r_ref, w_ref, addb_ref, addt_ref,
-                             alpha_ref, cx_ref, cy_ref, cz_ref, x_out, r_out,
-                             rcr_ref, *, n: int, ex: int, ey: int, sz: int,
-                             acc_dtype: str | None = None):
-    """Merged CG back-half on one slab block (DESIGN.md §3.4).
+def _stitch(v, addb, addt):
+    """Add the neighbour planes ``(n^2, slab)`` to a block's layer list:
+    ``addb`` to layer 0 of its first slab, ``addt`` to layer n-1 of its
+    last."""
+    v = list(v)
+    be = v[0].shape[-1]
+    v[0] = v[0] + _widen(addb, be, last=False)
+    v[-1] = v[-1] + _widen(addt, be, last=True)
+    return v
 
-    In one VMEM residency: stitch the cross-block z-interface planes into
-    ``w`` (completing the direct-stiffness summation), apply both axpys, and
-    emit the weighted-norm partial of the *updated* residual:
 
-        w   += neighbour boundary planes     (VMEM-local, O(n^2) operands)
-        x   += alpha * p
-        r   -= alpha * w
-        rcr  = sum(r * c * r)                (c from per-axis factors)
-
-    Refs:
-      x_ref/p_ref/r_ref/w_ref: (block_e, n^3)
-      addb_ref/addt_ref: (1, ey*ex*n^2)  neighbour planes to add at the
-                         block's bottom / top boundary (zeros at the ends)
-      alpha_ref: (1, 1)
-      cx_ref/cy_ref/cz_ref: per-axis c = mask/mult factors ((ex|ey|sz), n)
-      x_out/r_out: (block_e, n^3);  rcr_ref: (1, 1)
-    """
-    block_e = sz * ey * ex
-    f32 = _accum(x_ref.dtype, acc_dtype)
-    alpha = alpha_ref[0, 0].astype(f32)
-    v = w_ref[...].astype(f32).reshape(sz, ey, ex, n, n, n)
-    v = v.at[0, :, :, 0, :, :].add(
-        addb_ref[...].astype(f32).reshape(ey, ex, n, n))
-    v = v.at[-1, :, :, -1, :, :].add(
-        addt_ref[...].astype(f32).reshape(ey, ex, n, n))
-
-    x = x_ref[...].astype(f32) + alpha * p_ref[...].astype(f32)
-    r = r_ref[...].astype(f32) - alpha * v.reshape(block_e, n ** 3)
+def _update_one(x, p, r, w, addb, addt, alpha, c, r_dtype):
+    """Stitch + both axpys + post-update ``r·c·r`` for one RHS."""
+    v = _stitch(w, addb, addt)
+    x = [a + alpha * b for a, b in zip(x, p)]
     # the r·c·r partial must see the *stored* residual: the carried rtz is
     # next iteration's beta numerator, and that iteration reads the rounded
     # r from HBM.  Identity for f32/f64 storage; load-bearing for bf16.
-    r = r.astype(r_out.dtype)
+    r = [(a - alpha * b).astype(r_dtype).astype(a.dtype)
+         for a, b in zip(r, v)]
+    return x, r, _dot_layers(r, r, c)
 
-    c = _box_outer(cz_ref[...].astype(f32), cy_ref[...].astype(f32),
-                   cx_ref[...].astype(f32))
-    r6 = r.astype(f32).reshape(sz, ey, ex, n, n, n)
-    rcr_ref[0, 0] = jnp.sum(r6 * c * r6).astype(rcr_ref.dtype)
-    x_out[...] = x.astype(x_out.dtype)
-    r_out[...] = r
+
+def nekbone_cg_update_kernel(x_ref, p_ref, r_ref, w_ref, addb_ref, addt_ref,
+                             alpha_ref, cxy_ref, cz_ref, x_out, r_out,
+                             rcr_ref, *, acc_dtype: str | None = None):
+    """Merged CG back-half on one slab block (DESIGN.md §3.4).
+
+        w   += neighbour boundary layers     (zero outside the edge slab)
+        x   += alpha * p
+        r   -= alpha * w
+        rcr  = sum(r * c * r)                (c from the structural factors)
+
+    Refs: fields ``(n, n^2, be)``; addb/addt ``(1, n^2, EX*EY)``; alpha
+    ``(1, 1)`` in SMEM; cxy ``(n^2, be)``, cz ``(n, be)``; rcr ``(1, 1, 1)``.
+    """
+    f = _accum(x_ref.dtype, acc_dtype)
+    c = _box_layers(cz_ref, cxy_ref[...].astype(f), f)
+    x, r, rcr = _update_one(
+        _load(x_ref, f), _load(p_ref, f), _load(r_ref, f), _load(w_ref, f),
+        addb_ref[0].astype(f), addt_ref[0].astype(f), alpha_ref[0, 0], c,
+        r_out.dtype)
+    rcr_ref[0] = rcr.astype(rcr_ref.dtype)
+    _store(x_out, x)
+    _store(r_out, r)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "interpret",
                                              "acc_dtype"))
-def nekbone_cg_update_pallas(x2: jnp.ndarray, p2: jnp.ndarray,
-                             r2: jnp.ndarray, w2: jnp.ndarray,
+def nekbone_cg_update_pallas(x: jnp.ndarray, p: jnp.ndarray,
+                             r: jnp.ndarray, w: jnp.ndarray,
                              addb: jnp.ndarray, addt: jnp.ndarray,
                              alpha: jnp.ndarray, cx: jnp.ndarray,
                              cy: jnp.ndarray, cz: jnp.ndarray, *, n: int,
@@ -682,120 +795,87 @@ def nekbone_cg_update_pallas(x2: jnp.ndarray, p2: jnp.ndarray,
     """Multi-output pallas_call for the merged vector-update kernel.
 
     Args mirror :func:`nekbone_ax_slab_pallas`; ``addb``/``addt`` are the
-    *shifted* boundary planes (``addb[b] = top[b-1]``, ``addt[b] = bot[b+1]``,
-    zeros at the global ends).  Returns ``(x2_new, r2_new, rcr_parts)``.
+    *shifted* boundary planes ``(EZ//sz, n^2, EX*EY)`` (``addb[b] =
+    top[b-1]``, ``addt[b] = bot[b+1]``, zeros at the global ends —
+    :func:`shift_planes`).  Returns ``(x_new, r_new, rcr_parts)``.
     """
     ex, ey, ez = grid
-    E = x2.shape[0]
+    E = x.shape[-1]
     assert E == ex * ey * ez and ez % sz == 0, (grid, sz, E)
-    block_e = sz * ey * ex
+    slab = ex * ey
+    be = sz * slab
     nblk = ez // sz
-    n3 = n ** 3
-    pln = ey * ex * n * n
-    acc = _accum(x2.dtype, acc_dtype)
-    field = pl.BlockSpec((block_e, n3), lambda i: (i, 0))
-    plane = pl.BlockSpec((1, pln), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_cg_update_kernel, n=n, ex=ex, ey=ey, sz=sz,
-                          acc_dtype=acc_dtype),
-        grid=(nblk,),
-        in_specs=[
-            field, field, field, field,                 # x, p, r, w
-            plane, plane,                               # addb, addt
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),     # alpha
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # c factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # c factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # c factor z slice
-        ],
-        out_specs=(field, field, pl.BlockSpec((1, 1), lambda i: (i, 0))),
-        out_shape=(
-            # x keeps its (possibly wider, DESIGN.md §7) storage dtype;
-            # r stays in the field storage dtype.
-            jax.ShapeDtypeStruct((E, n3), x2.dtype),
-            jax.ShapeDtypeStruct((E, n3), r2.dtype),
-            jax.ShapeDtypeStruct((nblk, 1), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
+    f = _accum(x.dtype, acc_dtype)
+    field = _field_spec(n, be)
+    plane = _plane_spec(n, slab)
+    x, r, rcr = _call(
+        functools.partial(nekbone_cg_update_kernel, acc_dtype=acc_dtype),
         name=f"nekbone_cg_update_n{n}_sz{sz}{_acc_tag(acc_dtype)}",
-    )(x2, p2, r2, w2, addb, addt, alpha, cx, cy, cz)
+        grid=(nblk,),
+        in_specs=[field] * 4 + [plane, plane, _SMEM,
+                                _full_spec((n * n, be)), _lane_spec(n, be)],
+        out_specs=(field, field, _part_spec()),
+        out_shape=(jax.ShapeDtypeStruct((n, n * n, E), x.dtype),
+                   jax.ShapeDtypeStruct((n, n * n, E), r.dtype),
+                   _part_shape(nblk, f)),
+        interpret=interpret,
+        operands=(x, p, r, w, addb, addt,
+                  jnp.asarray(alpha, f).reshape(1, 1),
+                  _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
+    return x, r, _part(rcr)
 
 
 # ---------------------------------------------------------------------------
 # Multi-RHS (block) v2 pipeline: the same two slab kernels carrying a static
-# RHS-batch dimension b (DESIGN.md §12).  The operator-side residents — D,
-# D^T, the 3 metric diagonals, and the per-axis mask/weight factors — are
-# loaded ONCE per slab residency and reused across all b right-hand sides;
-# only the vector streams (p, r, w, x) scale with b.  That amortization is
-# the whole point: streams/RHS = per-RHS vector streams + shared operator
-# streams / b (cost.multi_rhs_streams).  The per-RHS work is a static
-# python unroll over identical single-RHS expression graphs, so at b=1 the
-# arithmetic is operation-for-operation the b=1 kernel's and the block CG
-# driver (core/cg_block.py) is fp64-bitwise identical to cg_fused_v2.
-# Per-RHS scalars travel as length-b vectors: beta/alpha come in as (1, b)
-# operands, the pap/rcr partials leave as (nblk, b) outputs.
+# RHS-batch dimension b (DESIGN.md §12).  The operator-side residents are
+# loaded once per slab residency and reused across all b right-hand sides;
+# the per-RHS work is a static unroll of the single-RHS expression graph, so
+# each lane is operation-for-operation the b=1 kernel (fp64-bitwise).
+# Per-RHS scalars travel as (1, b) SMEM operands; the partials leave as a
+# (1, 1, b) tile, one lane per RHS.
 # ---------------------------------------------------------------------------
 
-def nekbone_ax_slab_block_kernel(p_ref, r_ref, d_ref, dt_ref, g_ref, mx_ref,
-                                 my_ref, mz_ref, beta_ref, p_out, w_ref,
-                                 bot_ref, top_ref, pap_ref, *, n: int,
-                                 ex: int, ey: int, sz: int, nrhs: int,
-                                 acc_dtype: str | None = None,
-                                 layout: str = "fold"):
+def _lane_row(vals) -> jnp.ndarray:
+    """``(1, b)`` row from ``b`` values of shape ``(1, 1)``."""
+    b = len(vals)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
+    row = jnp.zeros((1, b), vals[0].dtype)
+    for j, v in enumerate(vals):
+        row = jnp.where(idx == j, v, row)
+    return row
+
+
+def nekbone_ax_slab_block_kernel(p_ref, r_ref, kf_ref, kb_ref, d_ref, g_ref,
+                                 mxy_ref, mz_ref, fl_ref, pm_ref, beta_ref,
+                                 p_out, w_ref, bot_ref, top_ref, pap_ref,
+                                 *scr, ex: int, ey: int, sz: int, nrhs: int,
+                                 acc_dtype: str | None = None):
     """Batched CG front-half: ``nekbone_ax_slab_kernel`` over ``nrhs`` RHS.
 
-    Refs are the single-RHS kernel's with a leading ``nrhs`` axis on the
-    vector operands (``p_ref``/``r_ref``: (nrhs, block_e, n^3); planes
-    (nrhs, 1, pln)) while the operator operands keep their shapes — they
-    are read once and shared.  ``beta_ref`` is (1, nrhs), ``pap_ref``
-    (1, nrhs).
+    Vector refs carry a leading ``nrhs`` axis (fields ``(nrhs, n, n^2,
+    be)``, planes ``(nrhs, 1, n^2, EX*EY)``); operator refs keep their
+    shapes and are shared.  ``beta_ref`` is ``(1, nrhs)`` SMEM, ``pap_ref``
+    ``(1, 1, nrhs)``.
     """
-    block_e = sz * ey * ex
-    f32 = _accum(p_ref.dtype, acc_dtype)
-    out_dtype = w_ref.dtype
-    pln = ey * ex * n * n
-    # shared per-residency loads: operator data + structural mask, once
-    # for all nrhs right-hand sides.
-    D = d_ref[...].astype(f32)
-    Dt = dt_ref[...].astype(f32)
-    g3 = g_ref[...].astype(f32)
-    mask = _box_outer(mz_ref[...].astype(f32), my_ref[...].astype(f32),
-                      mx_ref[...].astype(f32))
+    f = _accum(p_ref.dtype, acc_dtype)
+    mxy = mxy_ref[...].astype(f)
+    fl = fl_ref[...]
+    paps = []
     for j in range(nrhs):
-        beta = beta_ref[0, j].astype(f32)
-        p = r_ref[j].astype(f32) + beta * p_ref[j].astype(f32)
-        # storage rounding of the direction — same contract as the
-        # single-RHS kernel (alpha is applied to the *stored* p).
-        p = p.astype(out_dtype).astype(f32)
-        w = ax_block_diag(p, D, Dt, g3, n=n, e=block_e, layout=layout)
-        v = w.reshape(sz, ey, ex, n, n, n) * mask
-        # continuity identity: the partial sees the unassembled masked
-        # output (DESIGN.md §3.2), one lane per RHS.
-        pap_ref[0, j] = jnp.sum(p.reshape(v.shape) * v).astype(pap_ref.dtype)
-        if ex > 1:
-            s = v[:, :, :-1, :, :, -1] + v[:, :, 1:, :, :, 0]
-            v = v.at[:, :, :-1, :, :, -1].set(s)
-            v = v.at[:, :, 1:, :, :, 0].set(s)
-        if ey > 1:
-            s = v[:, :-1, :, :, -1, :] + v[:, 1:, :, :, 0, :]
-            v = v.at[:, :-1, :, :, -1, :].set(s)
-            v = v.at[:, 1:, :, :, 0, :].set(s)
-        if sz > 1:
-            s = v[:-1, :, :, -1, :, :] + v[1:, :, :, 0, :, :]
-            v = v.at[:-1, :, :, -1, :, :].set(s)
-            v = v.at[1:, :, :, 0, :, :].set(s)
-        w_ref[j] = v.reshape(block_e, n ** 3).astype(out_dtype)
-        p_out[j] = p.astype(out_dtype)
-        bot_ref[j] = v[0, :, :, 0, :, :].reshape(1, pln).astype(out_dtype)
-        top_ref[j] = v[-1, :, :, -1, :, :].reshape(1, pln).astype(out_dtype)
+        pj, wj = p_out.at[j], w_ref.at[j]
+        _direction(pj, r_ref.at[j], p_ref.at[j], beta_ref[0, j], f)
+        paps.append(_slab_front(
+            lambda k, pj=pj: pj[k].astype(f), wj, kf_ref, kb_ref, d_ref,
+            lambda m, k: g_ref[m, k].astype(f),
+            lambda k: mz_ref[k].astype(f) * mxy, pm_ref, fl, scr, f,
+            ex=ex, ey=ey, nz=sz))
+        _boundary_planes(w_ref, bot_ref, top_ref, ex * ey, j)
+    pap_ref[0] = _lane_row(paps).astype(pap_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "interpret",
-                                             "acc_dtype", "layout",
-                                             "grid_order"))
-def nekbone_ax_slab_block_pallas(p3: jnp.ndarray, r3: jnp.ndarray,
+                                             "acc_dtype", "grid_order"))
+def nekbone_ax_slab_block_pallas(p: jnp.ndarray, r: jnp.ndarray,
                                  D: jnp.ndarray, Dt: jnp.ndarray,
                                  g3: jnp.ndarray, mx: jnp.ndarray,
                                  my: jnp.ndarray, mz: jnp.ndarray,
@@ -803,96 +883,75 @@ def nekbone_ax_slab_block_pallas(p3: jnp.ndarray, r3: jnp.ndarray,
                                  grid: tuple[int, int, int], sz: int,
                                  interpret: bool = False,
                                  acc_dtype: str | None = None,
-                                 layout: str = "fold",
                                  grid_order: str = "parallel"):
     """Multi-output pallas_call for the batched v2 slab kernel.
 
     Args mirror :func:`nekbone_ax_slab_pallas` with a leading RHS axis:
-    ``p3``/``r3`` are (b, E, n^3) and ``beta`` is (1, b).  Returns
-    ``(p3_new, w3, bot, top, pap_parts)`` with planes (b, EZ//sz, pln)
-    and partials (EZ//sz, b) — one lane per RHS.
+    ``p``/``r`` are (b, n, n^2, E) and ``beta`` is (1, b).  Returns
+    ``(p_new, w, bot, top, pap_parts)`` with planes (b, EZ//sz, n^2,
+    EX*EY) and partials (EZ//sz, b).
     """
     ex, ey, ez = grid
-    nrhs, E = p3.shape[0], p3.shape[1]
+    nrhs, E = p.shape[0], p.shape[-1]
     assert E == ex * ey * ez and ez % sz == 0, (grid, sz, E)
-    block_e = sz * ey * ex
+    slab = ex * ey
+    be = sz * slab
     nblk = ez // sz
-    n3 = n ** 3
-    pln = ey * ex * n * n
-    acc = _accum(p3.dtype, acc_dtype)
-    field = pl.BlockSpec((nrhs, block_e, n3), lambda i: (0, i, 0))
-    plane = pl.BlockSpec((nrhs, 1, pln), lambda i: (0, i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_ax_slab_block_kernel, n=n, ex=ex, ey=ey,
-                          sz=sz, nrhs=nrhs, acc_dtype=acc_dtype,
-                          layout=layout),
-        grid=(nblk,),
-        in_specs=[
-            field,                                      # p_prev (b, ., .)
-            field,                                      # r      (b, ., .)
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # D       shared
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # Dt      shared
-            pl.BlockSpec((block_e, 3, n3), lambda i: (i, 0, 0)),  # g diag
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # mask factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # mask factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # mask factor z
-            pl.BlockSpec((1, nrhs), lambda i: (0, 0)),  # beta vector
-        ],
-        out_specs=(field, field, plane, plane,
-                   pl.BlockSpec((1, nrhs), lambda i: (i, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((nrhs, E, n3), p3.dtype),    # p
-            jax.ShapeDtypeStruct((nrhs, E, n3), p3.dtype),    # w
-            jax.ShapeDtypeStruct((nrhs, nblk, pln), p3.dtype),
-            jax.ShapeDtypeStruct((nrhs, nblk, pln), p3.dtype),
-            jax.ShapeDtypeStruct((nblk, nrhs), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=(grid_order,),
-        ),
-        interpret=interpret,
+    f = _accum(p.dtype, acc_dtype)
+    kf, kb, d = _operator_operands(D, Dt, f)
+    field = _field_spec(n, be, (nrhs,))
+    plane = _plane_spec(n, slab, (nrhs,))
+    sdt = p.dtype
+    p, w, bot, top, pap = _call(
+        functools.partial(nekbone_ax_slab_block_kernel, ex=ex, ey=ey, sz=sz,
+                          nrhs=nrhs, acc_dtype=acc_dtype),
         name=(f"nekbone_ax_slab_b{nrhs}_n{n}_sz{sz}{_acc_tag(acc_dtype)}"
-              f"{_cfg_tag(layout, grid_order)}"),
-    )(p3, r3, D, Dt, g3, mx, my, mz, beta)
+              f"{_order_tag(grid_order)}"),
+        grid=(nblk,),
+        in_specs=[field, field] + _op_specs(n)
+        + [_field_spec(n, be, (3,))] + _struct_specs(n, be) + [_SMEM],
+        out_specs=(field, field, plane, plane, _part_spec(nrhs)),
+        out_shape=(jax.ShapeDtypeStruct((nrhs, n, n * n, E), sdt),
+                   jax.ShapeDtypeStruct((nrhs, n, n * n, E), sdt),
+                   jax.ShapeDtypeStruct((nrhs, nblk, n * n, slab), sdt),
+                   jax.ShapeDtypeStruct((nrhs, nblk, n * n, slab), sdt),
+                   _part_shape(nblk, f, nrhs)),
+        interpret=interpret, grid_order=grid_order,
+        scratch=_scratch(n, be, f, 4),
+        operands=(p, r, kf, kb, d, g3,
+                  *_slab_structure(grid, n, sz, mx, my, mz, f),
+                  jnp.asarray(beta, f).reshape(1, nrhs)))
+    return p, w, bot, top, _part(pap)
 
 
 def nekbone_cg_update_block_kernel(x_ref, p_ref, r_ref, w_ref, addb_ref,
-                                   addt_ref, alpha_ref, cx_ref, cy_ref,
-                                   cz_ref, x_out, r_out, rcr_ref, *, n: int,
-                                   ex: int, ey: int, sz: int, nrhs: int,
+                                   addt_ref, alpha_ref, cxy_ref, cz_ref,
+                                   x_out, r_out, rcr_ref, *, nrhs: int,
                                    acc_dtype: str | None = None):
     """Batched CG back-half: ``nekbone_cg_update_kernel`` over ``nrhs`` RHS.
 
-    The weight box ``c`` is rebuilt from its per-axis factors once and
-    shared across the batch; plane stitch, both axpys, and the post-update
-    r·c·r partial run per RHS (``alpha_ref``/``rcr_ref``: (1, nrhs)).
+    The weight ``c`` is rebuilt once and shared across the batch; stitch,
+    both axpys and the ``r·c·r`` partial run per RHS (``alpha_ref`` (1, b)
+    SMEM, ``rcr_ref`` (1, 1, b)).
     """
-    block_e = sz * ey * ex
-    f32 = _accum(x_ref.dtype, acc_dtype)
-    # shared per-residency load: the inner-product weight, once for all b.
-    c = _box_outer(cz_ref[...].astype(f32), cy_ref[...].astype(f32),
-                   cx_ref[...].astype(f32))
+    f = _accum(x_ref.dtype, acc_dtype)
+    c = _box_layers(cz_ref, cxy_ref[...].astype(f), f)
+    rcrs = []
     for j in range(nrhs):
-        alpha = alpha_ref[0, j].astype(f32)
-        v = w_ref[j].astype(f32).reshape(sz, ey, ex, n, n, n)
-        v = v.at[0, :, :, 0, :, :].add(
-            addb_ref[j].astype(f32).reshape(ey, ex, n, n))
-        v = v.at[-1, :, :, -1, :, :].add(
-            addt_ref[j].astype(f32).reshape(ey, ex, n, n))
-        x = x_ref[j].astype(f32) + alpha * p_ref[j].astype(f32)
-        r = r_ref[j].astype(f32) - alpha * v.reshape(block_e, n ** 3)
-        # rcr must see the *stored* residual (same contract as b=1).
-        r = r.astype(r_out.dtype)
-        r6 = r.astype(f32).reshape(sz, ey, ex, n, n, n)
-        rcr_ref[0, j] = jnp.sum(r6 * c * r6).astype(rcr_ref.dtype)
-        x_out[j] = x.astype(x_out.dtype)
-        r_out[j] = r
+        x, r, rcr = _update_one(
+            _load(x_ref, f, j), _load(p_ref, f, j), _load(r_ref, f, j),
+            _load(w_ref, f, j), addb_ref[j, 0].astype(f),
+            addt_ref[j, 0].astype(f), alpha_ref[0, j], c, r_out.dtype)
+        rcrs.append(rcr)
+        _store(x_out, x, j)
+        _store(r_out, r, j)
+    rcr_ref[0] = _lane_row(rcrs).astype(rcr_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "interpret",
                                              "acc_dtype"))
-def nekbone_cg_update_block_pallas(x3: jnp.ndarray, p3: jnp.ndarray,
-                                   r3: jnp.ndarray, w3: jnp.ndarray,
+def nekbone_cg_update_block_pallas(x: jnp.ndarray, p: jnp.ndarray,
+                                   r: jnp.ndarray, w: jnp.ndarray,
                                    addb: jnp.ndarray, addt: jnp.ndarray,
                                    alpha: jnp.ndarray, cx: jnp.ndarray,
                                    cy: jnp.ndarray, cz: jnp.ndarray, *,
@@ -902,101 +961,82 @@ def nekbone_cg_update_block_pallas(x3: jnp.ndarray, p3: jnp.ndarray,
     """Multi-output pallas_call for the batched merged-update kernel.
 
     Args mirror :func:`nekbone_cg_update_pallas` with a leading RHS axis
-    ((b, E, n^3) fields, (b, EZ//sz, pln) shifted planes, (1, b) alpha).
-    Returns ``(x3_new, r3_new, rcr_parts)`` with partials (EZ//sz, b).
+    ((b, n, n^2, E) fields, (b, EZ//sz, n^2, EX*EY) shifted planes, (1, b)
+    alpha).  Returns ``(x_new, r_new, rcr_parts)`` with partials
+    (EZ//sz, b).
     """
     ex, ey, ez = grid
-    nrhs, E = x3.shape[0], x3.shape[1]
+    nrhs, E = x.shape[0], x.shape[-1]
     assert E == ex * ey * ez and ez % sz == 0, (grid, sz, E)
-    block_e = sz * ey * ex
+    slab = ex * ey
+    be = sz * slab
     nblk = ez // sz
-    n3 = n ** 3
-    pln = ey * ex * n * n
-    acc = _accum(x3.dtype, acc_dtype)
-    field = pl.BlockSpec((nrhs, block_e, n3), lambda i: (0, i, 0))
-    plane = pl.BlockSpec((nrhs, 1, pln), lambda i: (0, i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_cg_update_block_kernel, n=n, ex=ex, ey=ey,
-                          sz=sz, nrhs=nrhs, acc_dtype=acc_dtype),
-        grid=(nblk,),
-        in_specs=[
-            field, field, field, field,                 # x, p, r, w
-            plane, plane,                               # addb, addt
-            pl.BlockSpec((1, nrhs), lambda i: (0, 0)),  # alpha vector
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # c factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # c factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # c factor z slice
-        ],
-        out_specs=(field, field, pl.BlockSpec((1, nrhs), lambda i: (i, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((nrhs, E, n3), x3.dtype),
-            jax.ShapeDtypeStruct((nrhs, E, n3), r3.dtype),
-            jax.ShapeDtypeStruct((nblk, nrhs), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
+    f = _accum(x.dtype, acc_dtype)
+    field = _field_spec(n, be, (nrhs,))
+    plane = _plane_spec(n, slab, (nrhs,))
+    x, r, rcr = _call(
+        functools.partial(nekbone_cg_update_block_kernel, nrhs=nrhs,
+                          acc_dtype=acc_dtype),
         name=f"nekbone_cg_update_b{nrhs}_n{n}_sz{sz}{_acc_tag(acc_dtype)}",
-    )(x3, p3, r3, w3, addb, addt, alpha, cx, cy, cz)
+        grid=(nblk,),
+        in_specs=[field] * 4 + [plane, plane, _SMEM,
+                                _full_spec((n * n, be)), _lane_spec(n, be)],
+        out_specs=(field, field, _part_spec(nrhs)),
+        out_shape=(jax.ShapeDtypeStruct((nrhs, n, n * n, E), x.dtype),
+                   jax.ShapeDtypeStruct((nrhs, n, n * n, E), r.dtype),
+                   _part_shape(nblk, f, nrhs)),
+        interpret=interpret,
+        operands=(x, p, r, w, addb, addt,
+                  jnp.asarray(alpha, f).reshape(1, nrhs),
+                  _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
+    return x, r, _part(rcr)
 
 
 # ---------------------------------------------------------------------------
 # v3 s-step pipeline: matrix-powers slab kernel + multi-axpy update
 # (DESIGN.md §8).  One kernel invocation evaluates the whole 2s+1-vector
 # Krylov basis {p, Ap, .., A^s p, r, Ar, .., A^{s-1} r} of an s-step CG
-# cycle in a single slab residency: the 3 metric diagonals, D/D^T, and the
-# per-axis mask factors are loaded once per s operator applications and the
-# chained contractions never leave VMEM.  Chaining A across block boundaries
-# needs a matrix-powers ghost region: each application pollutes one slab
-# inward from the block edge, so blocks march sz owned slabs plus s halo
-# slabs on each side (zero-padded past the domain ends — zero elements
-# contribute exactly the nothing a missing neighbour would).  The owned
-# basis slices are fully assembled (the halo supplies both neighbours'
-# direct-stiffness contributions in-block), so no plane side channel exists;
-# the redundant halo reads are the side channel instead
-# (cost.sstep_halo_streams).  The (2s+1)^2 Gram/moment block of the s-step
-# recurrence is reduced in-kernel over the owned slabs and emitted as
-# per-block partials; the s x s recurrence itself is solved in f64 on the
-# host (core/cg_sstep.py).
+# cycle in a single slab residency.  Chaining A across block boundaries
+# needs a matrix-powers ghost region: blocks march sz owned slabs plus s
+# halo slabs on each side (zero-padded past the domain ends).  The owned
+# basis slices are fully assembled, so no plane side channel exists; the
+# redundant halo reads are the side channel instead
+# (cost.sstep_halo_streams).  The (2s+1)^2 Gram block is reduced in-kernel
+# over the owned slabs; the s x s recurrence is solved in f64 on the host
+# (core/cg_sstep.py).
 # ---------------------------------------------------------------------------
 
-def sstep_extend_field(f2: jnp.ndarray, grid: tuple[int, int, int], sz: int,
+def sstep_extend_field(f: jnp.ndarray, grid: tuple[int, int, int], sz: int,
                        halo: int, below: jnp.ndarray | None = None,
                        above: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Gather per-block halo windows of a z-major field, zero-padded.
+    """Gather per-block halo windows of a kernel-layout field, zero-padded.
 
     Args:
-      f2: (E, ...) element-major field (z-major over ``grid``); trailing
-          dims are carried through.
+      f: (..., E) field with z-major elements on the last axis (a
+          ``(n, n^2, E)`` vector or a ``(3, n, n^2, E)`` metric).
       below/above: optional ``halo``-deep ghost slabs replacing the zero
-          padding at the low/high z end — ``(halo, EY*EX, ...)`` (any
-          layout reshapeable to it).  This is the distributed halo hook
-          (distributed/sstep.py): when ``grid`` is a *shard-local* grid,
-          the neighbour shards' boundary slabs go here and the resulting
-          windows are exactly the single-device ones (zeros remain the
-          correct padding at the global domain ends, where
-          ``gs.halo_exchange_z`` delivers zeros).
-    Returns (EZ//sz, (sz + 2*halo)*EY*EX, ...): block ``i`` holds slabs
-    ``[i*sz - halo, i*sz + sz + halo)`` with zeros past the domain ends —
-    the matrix-powers ghost region of the v3 powers kernel.  (A production
-    TPU lowering would express these as overlapping block windows; the
-    reference build materializes them, which the cost model charges as the
-    halo side channel.)
+          padding at the low/high z end — ``(..., halo*EY*EX)``.  This is
+          the distributed halo hook (distributed/sstep.py): when ``grid``
+          is a *shard-local* grid, the neighbour shards' boundary slabs go
+          here and the resulting windows are exactly the single-device ones.
+    Returns (..., (EZ//sz) * (sz + 2*halo)*EY*EX): the windows side by side
+    on the last axis; block ``i``'s holds slabs ``[i*sz - halo, i*sz + sz +
+    halo)`` with zeros past the domain ends.
     """
     ex, ey, ez = grid
+    slab = ex * ey
     nblk = ez // sz
     L = sz + 2 * halo
-    rest = f2.shape[1:]
-    f = f2.reshape((ez, ey * ex) + rest)
-    pad_shape = (halo,) + f.shape[1:]
-    pb = (jnp.zeros(pad_shape, f2.dtype) if below is None
-          else below.reshape(pad_shape).astype(f2.dtype))
-    pa = (jnp.zeros(pad_shape, f2.dtype) if above is None
-          else above.reshape(pad_shape).astype(f2.dtype))
-    fp = jnp.concatenate([pb, f, pa], axis=0)
-    idx = jnp.arange(nblk)[:, None] * sz + jnp.arange(L)[None, :]
-    return fp[idx].reshape((nblk, L * ey * ex) + rest)
+    lead = f.shape[:-1]
+    fz = f.reshape(lead + (ez, slab))
+    pad_shape = lead + (halo, slab)
+    pb = (jnp.zeros(pad_shape, f.dtype) if below is None
+          else below.reshape(pad_shape).astype(f.dtype))
+    pa = (jnp.zeros(pad_shape, f.dtype) if above is None
+          else above.reshape(pad_shape).astype(f.dtype))
+    fp = jnp.concatenate([pb, fz, pa], axis=-2)
+    idx = (np.arange(nblk)[:, None] * sz + np.arange(L)[None, :]).ravel()
+    return jnp.take(fp, idx, axis=-2).reshape(lead + (nblk * L * slab,))
 
 
 def sstep_extend_zfactor(fz: jnp.ndarray, sz: int, halo: int,
@@ -1005,10 +1045,9 @@ def sstep_extend_zfactor(fz: jnp.ndarray, sz: int, halo: int,
     """Per-block halo windows of a per-axis z factor ``(EZ, n)``.
 
     Out-of-domain halo rows are padded with ones: the fields there are
-    zero (``sstep_extend_field``), so the factor value is inert, and ones
-    never introduce false Dirichlet zeros.  ``below``/``above`` replace
-    the pad with neighbour-shard factor rows ``(halo, n)`` when ``fz`` is
-    a shard-local slice (the distributed hook, as in
+    zero, so the factor value is inert, and ones never introduce false
+    Dirichlet zeros.  ``below``/``above`` replace the pad with
+    neighbour-shard factor rows ``(halo, n)`` (the distributed hook, as in
     :func:`sstep_extend_field`).  Returns (EZ//sz, sz+2*halo, n).
     """
     ez, n = fz.shape
@@ -1023,12 +1062,42 @@ def sstep_extend_zfactor(fz: jnp.ndarray, sz: int, halo: int,
     return fp[idx]
 
 
-def nekbone_ax_powers_kernel(pext_ref, rext_ref, d_ref, dt_ref, gext_ref,
-                             mx_ref, my_ref, mzext_ref, cx_ref, cy_ref,
-                             cz_ref, th_ref, basis_ref, gram_ref, *, n: int,
-                             ex: int, ey: int, sz: int, s: int, halo: int,
-                             acc_dtype: str | None = None,
-                             layout: str = "fold"):
+def _window_apply(src, dst, kf_ref, kb_ref, d_ref, g, mask, pm_ref, fl,
+                  scr, f, *, ex, ey, L):
+    """One masked, window-assembled operator application into ``dst``."""
+    def post(k, w, c):
+        return _ds_xy(w * mask(k), pm_ref, fl, ex=ex, ey=ey), c
+
+    _apply(src, dst, kf_ref, kb_ref, d_ref, g, scr, f, post=post)
+    _ds_z(dst, fl, slab=ex * ey, nz=L)
+
+
+def _own(a: jnp.ndarray, ho: int, be: int) -> jnp.ndarray:
+    """Owned lanes ``[ho, ho + be)`` of a window layer."""
+    return a[:, ho:ho + be]
+
+
+def _window_specs(n, Lee):
+    """The metric window and the window structure operands."""
+    return [_field_spec(n, Lee, (3,)), _full_spec((n * n, Lee)),
+            pl.BlockSpec((1, n, 1, Lee), lambda i: (i, 0, 0, 0)),
+            _full_spec((8, Lee)), _full_spec((4, n * n, n * n))]
+
+
+def _window_operands(gext, mx, my, mzext, grid, n, L, f):
+    ex, ey, _ = grid
+    Lee = L * ex * ey
+    return (gext, _plane_factor(mx, my, Lee),
+            _z_lanes(mzext, ex * ey), _lane_flags(ex, ey, L, f),
+            _face_perms(n, f))
+
+
+def nekbone_ax_powers_kernel(pext_ref, rext_ref, kf_ref, kb_ref, d_ref,
+                             gext_ref, mxy_ref, mzext_ref, fl_ref, pm_ref,
+                             cxy_ref, cz_ref, th_ref, basis_ref, gram_ref,
+                             ur, us, ut, va, vb, *, ex: int, ey: int,
+                             sz: int, s: int, halo: int,
+                             acc_dtype: str | None = None):
     """Matrix-powers front-half of one s-step CG cycle, one slab block.
 
     In one VMEM residency over ``L = sz + 2*halo`` slabs (``halo = s``):
@@ -1037,93 +1106,75 @@ def nekbone_ax_powers_kernel(pext_ref, rext_ref, d_ref, dt_ref, gext_ref,
                   from v_0 = p, and s-1 times from v_0 = r
         G_ab    = sum_own(V_a * c * V_b)                     Gram partials
 
-    with ``V = [p, Ap', .., A'^s p, r, A'r, .., A'^{s-1} r]`` (``A' = A /
-    theta`` — the theta scaling keeps the monomial basis O(1) so the f64
-    host recurrence stays conditioned, DESIGN.md §8).  Every basis vector
-    is rounded through the *storage* dtype before it feeds the next
-    application and before the Gram reduction: the update kernel combines
-    the stored basis, so Gram and basis must describe the same (rounded)
-    vectors — identities for f32/f64, load-bearing for bf16 (the §7 rules).
+    with ``V = [p, A'p, .., A'^s p, r, A'r, .., A'^{s-1} r]`` (``A' = A /
+    theta``, DESIGN.md §8).  Every basis vector is rounded through the
+    *storage* dtype before it feeds the next application and the Gram.
 
-    The in-block direct stiffness runs over the whole extended block, so
-    owned slabs receive both neighbours' contributions (computed
-    redundantly in the halo) and the emitted basis needs no plane stitch.
-    Gram partials reduce over owned slabs only — blocks partition E.
-
-    Refs (VMEM blocks; ``Lee = L*ey*ex``, ``block_e = sz*ey*ex``):
-      pext_ref/rext_ref: (1, Lee, n^3)  halo'd p / r windows
-      d_ref/dt_ref: (n, n)
-      gext_ref:  (1, Lee, 3, n^3)       halo'd metric diagonal
-      mx_ref/my_ref: (ex|ey, n)         per-axis Dirichlet factors
-      mzext_ref: (1, L, n)              halo'd z mask factor window
-      cx_ref/cy_ref: (ex|ey, n)         per-axis c factors
-      cz_ref:    (sz, n)                owned z c-factor slice
-      th_ref:    (1, 1)                 1/theta basis scale
-      basis_ref: (block_e, 2s-1, n^3)   owned [A'p..A'^s p, A'r..A'^{s-1}r]
-      gram_ref:  (1, 2s+1, 2s+1)        Gram partial over owned slabs
+    Refs: pext/rext ``(n, n^2, Lee)`` windows; gext ``(3, n, n^2,
+    Lee)``; mxy ``(n^2, Lee)``, mzext ``(1, n, 1, Lee)``, fl ``(8, Lee)``,
+    pm as the slab kernel; cxy ``(n^2, be)`` and cz ``(n, 1, be)`` the
+    owned weight; th ``(1, 1)`` 1/theta in SMEM; basis ``(2s-1, n, n^2,
+    be)`` owned ``[A'p..A'^s p, A'r..A'^{s-1} r]``; gram ``(1, 2s+1,
+    2s+1)`` (symmetric: the upper triangle, mirrored); ur/us/ut/va/vb VMEM
+    scratch windows.
     """
     L = sz + 2 * halo
-    Lee = L * ey * ex
-    block_e = sz * ey * ex
-    n3 = n ** 3
-    f32 = _accum(pext_ref.dtype, acc_dtype)
+    slab = ex * ey
+    be, ho = sz * slab, halo * slab
+    n = pext_ref.shape[0]
+    f = _accum(pext_ref.dtype, acc_dtype)
     out_dtype = basis_ref.dtype
-    D = d_ref[...].astype(f32)
-    Dt = dt_ref[...].astype(f32)
-    g3 = gext_ref[0].astype(f32)
-    inv_th = th_ref[0, 0].astype(f32)
-    mask = _box_outer(mzext_ref[0].astype(f32), my_ref[...].astype(f32),
-                      mx_ref[...].astype(f32))
+    fl = fl_ref[...]
+    mxy = mxy_ref[...].astype(f)
+    inv_th = th_ref[0, 0]
+    g = lambda m, k: gext_ref[m, k].astype(f)            # noqa: E731
+    mask = lambda k: mzext_ref[0, k].astype(f) * mxy     # noqa: E731
 
-    def apply_scaled(v):
-        """One masked, block-assembled, theta-scaled operator application."""
-        w = ax_block_diag(v, D, Dt, g3, n=n, e=Lee, layout=layout)
-        v6 = w.reshape(L, ey, ex, n, n, n) * mask
-        if ex > 1:
-            t = v6[:, :, :-1, :, :, -1] + v6[:, :, 1:, :, :, 0]
-            v6 = v6.at[:, :, :-1, :, :, -1].set(t)
-            v6 = v6.at[:, :, 1:, :, :, 0].set(t)
-        if ey > 1:
-            t = v6[:, :-1, :, :, -1, :] + v6[:, 1:, :, :, 0, :]
-            v6 = v6.at[:, :-1, :, :, -1, :].set(t)
-            v6 = v6.at[:, 1:, :, :, 0, :].set(t)
-        if L > 1:
-            t = v6[:-1, :, :, -1, :, :] + v6[1:, :, :, 0, :, :]
-            v6 = v6.at[:-1, :, :, -1, :, :].set(t)
-            v6 = v6.at[1:, :, :, 0, :, :].set(t)
-        return (v6.reshape(Lee, n3) * inv_th)
+    def chain(v0_ref, napps, m0):
+        src = lambda k: v0_ref[k].astype(f)              # noqa: E731
+        for j in range(napps):
+            dst = (va, vb)[j % 2]
+            _window_apply(src, dst, kf_ref, kb_ref, d_ref, g, mask, pm_ref,
+                          fl, (ur, us, ut), f, ex=ex, ey=ey, L=L)
 
-    def chain(v0, napps):
-        vecs = [v0]
-        v = v0
-        for _ in range(napps):
-            # round through storage: the next application and the Gram must
-            # see exactly the vector the update kernel will re-read.
-            v = apply_scaled(v).astype(out_dtype).astype(f32)
-            vecs.append(v)
-        return vecs
+            def scale(k, c, dst=dst, m=m0 + j):
+                # round through storage: the next application and the
+                # Gram must see exactly the vector the update re-reads.
+                v = (dst[k] * inv_th).astype(out_dtype)
+                dst[k] = v.astype(f)
+                basis_ref[m, k] = _own(v, ho, be)
+                return c
 
-    p = pext_ref[0].astype(f32)
-    r = rext_ref[0].astype(f32)
-    V = chain(p, s) + chain(r, s - 1)          # order: p-powers, r-powers
+            jax.lax.fori_loop(0, n, scale, 0)
+            src = lambda k, dst=dst: dst[k]              # noqa: E731
 
-    ho = halo * ey * ex
-    own = [v[ho:ho + block_e] for v in V]
-    c6 = _box_outer(cz_ref[...].astype(f32), cy_ref[...].astype(f32),
-                    cx_ref[...].astype(f32))
-    cw = c6.reshape(1, block_e * n3)
-    Vo = jnp.stack([v.reshape(block_e * n3) for v in own])
-    gram_ref[0] = _dot(Vo * cw, Vo.T).astype(gram_ref.dtype)
+    chain(pext_ref, s, 0)
+    chain(rext_ref, s - 1, s)
+    K = 2 * s + 1
+    ri = jax.lax.broadcasted_iota(jnp.int32, (K, K), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
+    cxy = cxy_ref[...].astype(f)
 
-    # owned basis, minus p and r themselves (the update kernel re-reads
-    # those from their own streams): [A'p..A'^s p, A'r..A'^{s-1} r].
-    new = own[1:s + 1] + own[s + 2:]
-    basis_ref[...] = jnp.stack(new, axis=1).astype(out_dtype)
+    def gram_layer(k, gram):
+        c = cz_ref[k].astype(f) * cxy
+        V = ([_own(pext_ref[k], ho, be).astype(f)]
+             + [basis_ref[m, k].astype(f) for m in range(s)]
+             + [_own(rext_ref[k], ho, be).astype(f)]
+             + [basis_ref[s + m, k].astype(f) for m in range(s - 1)])
+        for a in range(K):
+            ca = V[a] * c
+            for b in range(a, K):
+                hit = ((ri == a) & (ci == b)) | ((ri == b) & (ci == a))
+                gram = jnp.where(hit, gram + _total(ca * V[b]), gram)
+        return gram
+
+    gram = jax.lax.fori_loop(0, n, gram_layer, jnp.zeros((K, K), f))
+    gram_ref[0] = gram.astype(gram_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "s",
                                              "interpret", "acc_dtype",
-                                             "layout", "grid_order"))
+                                             "grid_order"))
 def nekbone_ax_powers_pallas(pext: jnp.ndarray, rext: jnp.ndarray,
                              D: jnp.ndarray, Dt: jnp.ndarray,
                              gext: jnp.ndarray, mx: jnp.ndarray,
@@ -1133,17 +1184,16 @@ def nekbone_ax_powers_pallas(pext: jnp.ndarray, rext: jnp.ndarray,
                              n: int, grid: tuple[int, int, int], sz: int,
                              s: int, interpret: bool = False,
                              acc_dtype: str | None = None,
-                             layout: str = "fold",
                              grid_order: str = "parallel"):
     """Multi-output pallas_call for the v3 matrix-powers kernel.
 
     Args:
-      pext/rext: (EZ//sz, Lee, n^3) halo windows (:func:`sstep_extend_field`
-        with ``halo = s``); gext: (EZ//sz, Lee, 3, n^3); mzext:
-        (EZ//sz, L, n) (:func:`sstep_extend_zfactor`); cz: (EZ, n) —
-        blocked into owned (sz, n) slices; inv_theta: (1, 1) basis scale.
+      pext/rext: (n, n^2, (EZ//sz)*Lee) halo windows
+        (:func:`sstep_extend_field` with ``halo = s``); gext: (3, n, n^2,
+        (EZ//sz)*Lee); mzext: (EZ//sz, L, n) (:func:`sstep_extend_zfactor`);
+        cz: (EZ, n); inv_theta: (1, 1) basis scale.
 
-    Returns ``(basis, gram_parts)``: basis ``(E, 2s-1, n^3)`` in the
+    Returns ``(basis, gram_parts)``: basis ``(2s-1, n, n^2, E)`` in the
     storage dtype of ``pext``, Gram partials ``(EZ//sz, 2s+1, 2s+1)`` in
     the accumulation dtype.
     """
@@ -1151,105 +1201,78 @@ def nekbone_ax_powers_pallas(pext: jnp.ndarray, rext: jnp.ndarray,
     assert ez % sz == 0 and s >= 1, (grid, sz, s)
     halo = s
     L = sz + 2 * halo
-    Lee = L * ey * ex
-    block_e = sz * ey * ex
+    slab = ex * ey
+    Lee = L * slab
+    be = sz * slab
     nblk = ez // sz
-    E = nblk * block_e
-    n3 = n ** 3
+    E = nblk * be
     K = 2 * s + 1
     nb = 2 * s - 1
-    assert pext.shape == (nblk, Lee, n3), (pext.shape, (nblk, Lee, n3))
-    acc = _accum(pext.dtype, acc_dtype)
-    ext = pl.BlockSpec((1, Lee, n3), lambda i: (i, 0, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_ax_powers_kernel, n=n, ex=ex, ey=ey,
-                          sz=sz, s=s, halo=halo, acc_dtype=acc_dtype,
-                          layout=layout),
-        grid=(nblk,),
-        in_specs=[
-            ext,                                        # p window
-            ext,                                        # r window
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # D
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # Dt
-            pl.BlockSpec((1, Lee, 3, n3), lambda i: (i, 0, 0, 0)),  # g diag
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # mask factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # mask factor y
-            pl.BlockSpec((1, L, n), lambda i: (i, 0, 0)),  # mask z window
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # c factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # c factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # c factor z slice
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),     # 1/theta
-        ],
-        out_specs=(pl.BlockSpec((block_e, nb, n3), lambda i: (i, 0, 0)),
-                   pl.BlockSpec((1, K, K), lambda i: (i, 0, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, nb, n3), pext.dtype),
-            jax.ShapeDtypeStruct((nblk, K, K), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=(grid_order,),
-        ),
-        interpret=interpret,
+    assert pext.shape == (n, n * n, nblk * Lee), (pext.shape, (nblk, Lee))
+    f = _accum(pext.dtype, acc_dtype)
+    kf, kb, d = _operator_operands(D, Dt, f)
+    win = _field_spec(n, Lee)
+    basis, gram = _call(
+        functools.partial(nekbone_ax_powers_kernel, ex=ex, ey=ey, sz=sz,
+                          s=s, halo=halo, acc_dtype=acc_dtype),
         name=(f"nekbone_ax_powers_n{n}_sz{sz}_s{s}{_acc_tag(acc_dtype)}"
-              f"{_cfg_tag(layout, grid_order)}"),
-    )(pext, rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, inv_theta)
+              f"{_order_tag(grid_order)}"),
+        grid=(nblk,),
+        in_specs=[win, win] + _op_specs(n) + _window_specs(n, Lee)
+        + [_full_spec((n * n, be)), _lane_spec(n, be), _SMEM],
+        out_specs=(_field_spec(n, be, (nb,)),
+                   pl.BlockSpec((1, K, K), lambda i: (i, 0, 0))),
+        out_shape=(jax.ShapeDtypeStruct((nb, n, n * n, E), pext.dtype),
+                   jax.ShapeDtypeStruct((nblk, K, K), f)),
+        interpret=interpret, grid_order=grid_order,
+        scratch=_scratch(n, Lee, f, 5),
+        operands=(pext, rext, kf, kb, d,
+                  *_window_operands(gext, mx, my, mzext, grid, n, L, f),
+                  _plane_factor(cx, cy, be), _z_lanes(cz, slab),
+                  jnp.asarray(inv_theta, f).reshape(1, 1)))
+    return basis, gram
 
 
 def nekbone_sstep_update_kernel(x_ref, p_ref, r_ref, basis_ref, coef_ref,
-                                cx_ref, cy_ref, cz_ref, x_out, r_out, p_out,
-                                rcr_ref, *, n: int, ex: int, ey: int,
-                                sz: int, s: int,
+                                cxy_ref, cz_ref, x_out, r_out, p_out,
+                                rcr_ref, *, s: int,
                                 acc_dtype: str | None = None):
     """Multi-axpy back-half of one s-step cycle (DESIGN.md §8).
-
-    Applies the whole s-step of vector updates in one pass over the basis:
 
         x += V @ e_s,   r = V @ b_s,   p = V @ a_s,   rcr = sum(r*c*r)
 
     with ``V = [p, basis.., r, basis..]`` in the powers kernel's column
-    order and ``(e_s, b_s, a_s)`` the f64-solved recurrence coefficients
-    (rows of ``coef_ref``).  The ``r·c·r`` partial reduces over the
-    *stored* residual — it seeds the next cycle's final-history entry and
-    must match what the next powers kernel reads from HBM (§7 rule 2).
-
-    Refs:
-      x_ref/p_ref/r_ref: (block_e, n^3)
-      basis_ref: (block_e, 2s-1, n^3)   [A'p..A'^s p, A'r..A'^{s-1} r]
-      coef_ref:  (3, 2s+1)              rows: x-, r-, p-update coefficients
-      cx_ref/cy_ref/cz_ref: per-axis c factors ((ex|ey|sz), n)
-      x_out/r_out/p_out: (block_e, n^3);  rcr_ref: (1, 1)
+    order and ``(e_s, b_s, a_s)`` the rows of ``coef_ref`` ((3, 2s+1),
+    SMEM).  The ``r·c·r`` partial reduces over the *stored* residual.
     """
-    block_e = sz * ey * ex
-    n3 = n ** 3
-    f32 = _accum(x_ref.dtype, acc_dtype)
-    coef = coef_ref[...].astype(f32)
-    basis = basis_ref[...].astype(f32)
-    p = p_ref[...].astype(f32)
-    r = r_ref[...].astype(f32)
-    # V column order (powers kernel): p, A'p..A'^s p, r, A'r..A'^{s-1} r
-    terms = ([p] + [basis[:, m, :] for m in range(s)]
-             + [r] + [basis[:, s + m, :] for m in range(s - 1)])
-    xacc = x_ref[...].astype(f32)
-    racc = jnp.zeros((block_e, n3), f32)
-    pacc = jnp.zeros((block_e, n3), f32)
-    for k, v in enumerate(terms):
-        xacc = xacc + coef[0, k] * v
-        racc = racc + coef[1, k] * v
-        pacc = pacc + coef[2, k] * v
-    r_st = racc.astype(r_out.dtype)
-    c6 = _box_outer(cz_ref[...].astype(f32), cy_ref[...].astype(f32),
-                    cx_ref[...].astype(f32))
-    r6 = r_st.astype(f32).reshape(sz, ey, ex, n, n, n)
-    rcr_ref[0, 0] = jnp.sum(r6 * c6 * r6).astype(rcr_ref.dtype)
-    x_out[...] = xacc.astype(x_out.dtype)
-    r_out[...] = r_st
-    p_out[...] = pacc.astype(p_out.dtype)
+    f = _accum(x_ref.dtype, acc_dtype)
+    n = x_ref.shape[0]
+    c = _box_layers(cz_ref, cxy_ref[...].astype(f), f)
+    p, r = _load(p_ref, f), _load(r_ref, f)
+    basis = [_load(basis_ref, f, m) for m in range(2 * s - 1)]
+    terms = [p] + basis[:s] + [r] + basis[s:]
+    x = _load(x_ref, f)
+    rn, pn = [], []
+    for k in range(n):
+        xa, ra, pa = x[k], None, None
+        for t, v in enumerate(terms):
+            xa = xa + coef_ref[0, t] * v[k]
+            rt, pt = coef_ref[1, t] * v[k], coef_ref[2, t] * v[k]
+            ra = rt if ra is None else ra + rt
+            pa = pt if pa is None else pa + pt
+        x[k] = xa
+        rn.append(ra.astype(r_out.dtype).astype(f))
+        pn.append(pa)
+    rcr_ref[0] = _dot_layers(rn, rn, c).astype(rcr_ref.dtype)
+    _store(x_out, x)
+    _store(r_out, rn)
+    _store(p_out, pn)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "s",
                                              "interpret", "acc_dtype"))
-def nekbone_sstep_update_pallas(x2: jnp.ndarray, p2: jnp.ndarray,
-                                r2: jnp.ndarray, basis: jnp.ndarray,
+def nekbone_sstep_update_pallas(x: jnp.ndarray, p: jnp.ndarray,
+                                r: jnp.ndarray, basis: jnp.ndarray,
                                 coef: jnp.ndarray, cx: jnp.ndarray,
                                 cy: jnp.ndarray, cz: jnp.ndarray, *, n: int,
                                 grid: tuple[int, int, int], sz: int, s: int,
@@ -1257,129 +1280,85 @@ def nekbone_sstep_update_pallas(x2: jnp.ndarray, p2: jnp.ndarray,
                                 acc_dtype: str | None = None):
     """Multi-output pallas_call for the s-step update kernel.
 
-    Args mirror :func:`nekbone_ax_powers_pallas`; ``coef`` is the (3, 2s+1)
-    coefficient block (x/r/p rows).  Returns
-    ``(x2_new, r2_new, p2_new, rcr_parts)``.
+    Args mirror :func:`nekbone_ax_powers_pallas`; fields are (n, n^2, E),
+    ``basis`` is ``(2s-1, n, n^2, E)`` and ``coef`` the (3, 2s+1)
+    coefficient block.  Returns ``(x_new, r_new, p_new, rcr_parts)``.
     """
     ex, ey, ez = grid
-    E = x2.shape[0]
+    E = x.shape[-1]
     assert E == ex * ey * ez and ez % sz == 0, (grid, sz, E)
-    block_e = sz * ey * ex
+    slab = ex * ey
+    be = sz * slab
     nblk = ez // sz
-    n3 = n ** 3
-    K = 2 * s + 1
     nb = 2 * s - 1
-    acc = _accum(x2.dtype, acc_dtype)
-    field = pl.BlockSpec((block_e, n3), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_sstep_update_kernel, n=n, ex=ex, ey=ey,
-                          sz=sz, s=s, acc_dtype=acc_dtype),
-        grid=(nblk,),
-        in_specs=[
-            field, field, field,                        # x, p, r
-            pl.BlockSpec((block_e, nb, n3), lambda i: (i, 0, 0)),  # basis
-            pl.BlockSpec((3, K), lambda i: (0, 0)),     # coefficients
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # c factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # c factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # c factor z slice
-        ],
-        out_specs=(field, field, field,
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, n3), x2.dtype),    # x
-            jax.ShapeDtypeStruct((E, n3), r2.dtype),    # r
-            jax.ShapeDtypeStruct((E, n3), p2.dtype),    # p
-            jax.ShapeDtypeStruct((nblk, 1), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
+    f = _accum(x.dtype, acc_dtype)
+    field = _field_spec(n, be)
+    x, r, p, rcr = _call(
+        functools.partial(nekbone_sstep_update_kernel, s=s,
+                          acc_dtype=acc_dtype),
         name=f"nekbone_sstep_update_n{n}_sz{sz}_s{s}{_acc_tag(acc_dtype)}",
-    )(x2, p2, r2, basis, coef, cx, cy, cz)
+        grid=(nblk,),
+        in_specs=[field] * 3 + [_field_spec(n, be, (nb,)), _SMEM,
+                                _full_spec((n * n, be)), _lane_spec(n, be)],
+        out_specs=(field, field, field, _part_spec()),
+        out_shape=(jax.ShapeDtypeStruct((n, n * n, E), x.dtype),
+                   jax.ShapeDtypeStruct((n, n * n, E), r.dtype),
+                   jax.ShapeDtypeStruct((n, n * n, E), p.dtype),
+                   _part_shape(nblk, f)),
+        interpret=interpret,
+        operands=(x, p, r, basis, jnp.asarray(coef, f),
+                  _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
+    return x, r, p, _part(rcr)
 
 
 # ---------------------------------------------------------------------------
-# Preconditioning kernels (DESIGN.md §9).  Two PCG pipelines share the v2
-# slab front-half (nekbone_ax_slab_kernel applied with z = M^-1 r in the
-# residual slot — the direction update p = z + beta p and the p·c·Ap partial
-# are already exactly what PCG needs):
-#
-# * Jacobi: the solver carries the *preconditioned* residual z = D^-1 r
-#   instead of r, so the only new stream is the operator diagonal — the
-#   merged update kernel below applies D^-1 to the stitched operator output
-#   (z -= alpha D^-1 w), reconstructs r = D z in VMEM, and emits both the
-#   r·c·z (beta numerator) and r·c·r (history) partials.  10R + 4W = 14
-#   streams/iter, one more than unpreconditioned v2.
-# * Chebyshev: z = q_k(A) r for the degree-k Chebyshev approximation of
-#   A^-1 on an interval [lmin, lmax] ⊇ spec(A).  One application is k
-#   chained assembled operator applications — exactly the v3 matrix-powers
-#   structure, so the kernel reuses its halo machinery (k ghost slabs per
-#   block side, sstep_extend_field windows) to evaluate the whole
-#   polynomial in one slab residency: r + 3 metric diagonals in, z out.
+# Preconditioning kernels (DESIGN.md §9).  Jacobi PCG carries z = D^-1 r and
+# replaces the v2 update kernel; Chebyshev evaluates z = q_k(A) r with the
+# v3 halo machinery (k ghost slabs per block side) in one slab residency.
 # ---------------------------------------------------------------------------
 
-def nekbone_pcg_update_kernel(x_ref, p_ref, z_ref, w_ref, addb_ref, addt_ref,
-                              alpha_ref, invd_ref, cx_ref, cy_ref, cz_ref,
-                              x_out, z_out, rtz_ref, rcr_ref, *, n: int,
-                              ex: int, ey: int, sz: int,
+def nekbone_pcg_update_kernel(x_ref, p_ref, z_ref, w_ref, addb_ref,
+                              addt_ref, alpha_ref, invd_ref, cxy_ref,
+                              cz_ref, x_out, z_out, rtz_ref, rcr_ref, *,
                               acc_dtype: str | None = None):
     """Merged Jacobi-PCG back-half on one slab block (DESIGN.md §9.2).
 
-    The solver carries z = D^-1 r (D = diag(A)); r itself never streams.
-    In one VMEM residency: stitch the cross-block z-interface planes into
-    ``w``, apply both axpys in z-coordinates, and emit the two weighted
-    partials of the *updated*, *stored* residual:
-
-        w   += neighbour boundary planes          (the v2 stitch)
+        w   += neighbour boundary layers          (the v2 stitch)
         x   += alpha * p
         z   -= alpha * invdiag * w                (z-coordinate r-update)
         rtz  = sum(r * c * z) = sum(z * c * z / invdiag)
         rcr  = sum(r * c * r) = sum(z * c * z / invdiag^2)
 
     with ``r = z / invdiag`` reconstructed in VMEM (invdiag is 1 at masked
-    rows, where z is identically 0, so the reconstruction is exact there).
-    ``rtz`` is next iteration's beta numerator; ``rcr`` is the residual-
-    norm history entry, directly comparable to unpreconditioned CG's.
-
-    Refs as :func:`nekbone_cg_update_kernel` with ``z`` in place of ``r``
-    plus ``invd_ref``: (block_e, n^3) assembled 1/diag(A), and the two
-    (1, 1) partial outputs.
+    rows).  Refs as :func:`nekbone_cg_update_kernel` with ``z`` in place
+    of ``r`` plus the ``invd`` field and two ``(1, 1, 1)`` partials.
     """
-    block_e = sz * ey * ex
-    n3 = n ** 3
-    f32 = _accum(x_ref.dtype, acc_dtype)
-    alpha = alpha_ref[0, 0].astype(f32)
-    v = w_ref[...].astype(f32).reshape(sz, ey, ex, n, n, n)
-    v = v.at[0, :, :, 0, :, :].add(
-        addb_ref[...].astype(f32).reshape(ey, ex, n, n))
-    v = v.at[-1, :, :, -1, :, :].add(
-        addt_ref[...].astype(f32).reshape(ey, ex, n, n))
-
-    invd = invd_ref[...].astype(f32)
-    x = x_ref[...].astype(f32) + alpha * p_ref[...].astype(f32)
-    z = z_ref[...].astype(f32) - alpha * (invd * v.reshape(block_e, n3))
-    # both partials must see the *stored* z (§7 rule 2): rtz is the beta
-    # numerator of the iteration that re-reads z from HBM.
-    z = z.astype(z_out.dtype)
-
-    diag = 1.0 / invd                      # exact where invd == 1 (masked)
-    c = _box_outer(cz_ref[...].astype(f32), cy_ref[...].astype(f32),
-                   cx_ref[...].astype(f32))
-    z6 = z.astype(f32).reshape(sz, ey, ex, n, n, n)
-    d6 = diag.reshape(sz, ey, ex, n, n, n)
-    rtz_ref[0, 0] = jnp.sum(z6 * c * z6 * d6).astype(rtz_ref.dtype)
-    rcr_ref[0, 0] = jnp.sum(z6 * c * z6 * d6 * d6).astype(rcr_ref.dtype)
-    x_out[...] = x.astype(x_out.dtype)
-    z_out[...] = z
+    f = _accum(x_ref.dtype, acc_dtype)
+    alpha = alpha_ref[0, 0]
+    c = _box_layers(cz_ref, cxy_ref[...].astype(f), f)
+    v = _stitch(_load(w_ref, f), addb_ref[0].astype(f),
+                addt_ref[0].astype(f))
+    invd = _load(invd_ref, f)
+    x = [a + alpha * b for a, b in zip(_load(x_ref, f), _load(p_ref, f))]
+    # both partials see the *stored* z (§7 rule 2).
+    z = [(a - alpha * (i * b)).astype(z_out.dtype).astype(f)
+         for a, i, b in zip(_load(z_ref, f), invd, v)]
+    diag = [1.0 / i for i in invd]          # exact where invd == 1 (masked)
+    zcz = [a * ck * a for a, ck in zip(z, c)]
+    rtz_ref[0] = _total(sum(t * d for t, d in zip(zcz, diag))
+                        ).astype(rtz_ref.dtype)
+    rcr_ref[0] = _total(sum(t * d * d for t, d in zip(zcz, diag))
+                        ).astype(rcr_ref.dtype)
+    _store(x_out, x)
+    _store(z_out, z)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "interpret",
                                              "acc_dtype"))
-def nekbone_pcg_update_pallas(x2: jnp.ndarray, p2: jnp.ndarray,
-                              z2: jnp.ndarray, w2: jnp.ndarray,
+def nekbone_pcg_update_pallas(x: jnp.ndarray, p: jnp.ndarray,
+                              z: jnp.ndarray, w: jnp.ndarray,
                               addb: jnp.ndarray, addt: jnp.ndarray,
-                              alpha: jnp.ndarray, invd2: jnp.ndarray,
+                              alpha: jnp.ndarray, invd: jnp.ndarray,
                               cx: jnp.ndarray, cy: jnp.ndarray,
                               cz: jnp.ndarray, *, n: int,
                               grid: tuple[int, int, int], sz: int,
@@ -1388,142 +1367,106 @@ def nekbone_pcg_update_pallas(x2: jnp.ndarray, p2: jnp.ndarray,
     """Multi-output pallas_call for the Jacobi-PCG update kernel.
 
     Args mirror :func:`nekbone_cg_update_pallas` with the carried
-    preconditioned residual ``z2`` in the residual slot plus ``invd2``:
-    (E, n^3) assembled 1/diag(A) in the operator-storage dtype.  Returns
-    ``(x2_new, z2_new, rtz_parts, rcr_parts)``.
+    preconditioned residual ``z`` in the residual slot plus ``invd``:
+    (n, n^2, E) assembled 1/diag(A).  Returns
+    ``(x_new, z_new, rtz_parts, rcr_parts)``.
     """
     ex, ey, ez = grid
-    E = x2.shape[0]
+    E = x.shape[-1]
     assert E == ex * ey * ez and ez % sz == 0, (grid, sz, E)
-    block_e = sz * ey * ex
+    slab = ex * ey
+    be = sz * slab
     nblk = ez // sz
-    n3 = n ** 3
-    pln = ey * ex * n * n
-    acc = _accum(x2.dtype, acc_dtype)
-    field = pl.BlockSpec((block_e, n3), lambda i: (i, 0))
-    plane = pl.BlockSpec((1, pln), lambda i: (i, 0))
-    part = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_pcg_update_kernel, n=n, ex=ex, ey=ey,
-                          sz=sz, acc_dtype=acc_dtype),
-        grid=(nblk,),
-        in_specs=[
-            field, field, field, field,                 # x, p, z, w
-            plane, plane,                               # addb, addt
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),     # alpha
-            field,                                      # invdiag
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # c factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # c factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # c factor z slice
-        ],
-        out_specs=(field, field, part, part),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, n3), x2.dtype),    # x
-            jax.ShapeDtypeStruct((E, n3), z2.dtype),    # z
-            jax.ShapeDtypeStruct((nblk, 1), acc),       # rtz partials
-            jax.ShapeDtypeStruct((nblk, 1), acc),       # rcr partials
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
+    f = _accum(x.dtype, acc_dtype)
+    field = _field_spec(n, be)
+    plane = _plane_spec(n, slab)
+    x, z, rtz, rcr = _call(
+        functools.partial(nekbone_pcg_update_kernel, acc_dtype=acc_dtype),
         name=f"nekbone_pcg_update_n{n}_sz{sz}{_acc_tag(acc_dtype)}",
-    )(x2, p2, z2, w2, addb, addt, alpha, invd2, cx, cy, cz)
+        grid=(nblk,),
+        in_specs=[field] * 4 + [plane, plane, _SMEM, field,
+                                _full_spec((n * n, be)), _lane_spec(n, be)],
+        out_specs=(field, field, _part_spec(), _part_spec()),
+        out_shape=(jax.ShapeDtypeStruct((n, n * n, E), x.dtype),
+                   jax.ShapeDtypeStruct((n, n * n, E), z.dtype),
+                   _part_shape(nblk, f), _part_shape(nblk, f)),
+        interpret=interpret,
+        operands=(x, p, z, w, addb, addt,
+                  jnp.asarray(alpha, f).reshape(1, 1), invd,
+                  _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
+    return x, z, _part(rtz), _part(rcr)
 
 
-def nekbone_cheb_apply_kernel(rext_ref, d_ref, dt_ref, gext_ref, mx_ref,
-                              my_ref, mzext_ref, cx_ref, cy_ref, cz_ref,
-                              coef_ref, z_ref, rtz_ref, *, n: int, ex: int,
-                              ey: int, sz: int, k: int, halo: int,
-                              acc_dtype: str | None = None,
-                              layout: str = "fold"):
+def nekbone_cheb_apply_kernel(rext_ref, kf_ref, kb_ref, d_ref, gext_ref,
+                              mxy_ref, mzext_ref, fl_ref, pm_ref, cxy_ref,
+                              cz_ref, coef_ref, z_ref, rtz_ref, ur, us, ut,
+                              d_s, res_s, z_s, ad_s, *, ex: int, ey: int,
+                              sz: int, k: int, halo: int,
+                              acc_dtype: str | None = None):
     """Chebyshev preconditioner application, one slab block (DESIGN.md §9.3).
 
-    Evaluates ``z = q_k(A) r`` — the degree-k Chebyshev-semi-iteration
-    approximation of ``A^-1`` on ``[lmin, lmax]`` — in one VMEM residency
-    over ``L = sz + 2*halo`` slabs (``halo = k``), by the incremental-
-    residual Chebyshev recurrence (the scalars are precomputed host-side
-    in f64 from the interval, ``core/precond.cheb_scalars``):
+    Evaluates ``z = q_k(A) r`` in one VMEM residency over ``L = sz +
+    2*halo`` slabs (``halo = k``) by the incremental-residual recurrence
+    (scalars precomputed host-side, ``core/precond.cheb_scalars``):
 
         d   = coef[0,0] * r;   z = d;   res = r
         for i in 1..k:
-            res -= A d                      (masked, block-assembled)
+            res -= A d                      (masked, window-assembled)
             d    = coef[i,0] * d + coef[i,1] * res
             z   += d
         rtz = sum_own(r * c * z)            (the PCG beta numerator)
 
-    Each application of A pollutes one slab inward from the block edge
-    (the matrix-powers ghost-region argument of §8.2), so k chained
-    applications need exactly the v3 halo: owned slabs of ``z`` leave
-    fully assembled, no plane side channel.  ``z`` is rounded through the
-    storage dtype before the rtz reduction (§7 rule 2 — the v2 slab
-    kernel re-reads the stored z as its direction-update operand).
-
-    Refs (``Lee = L*ey*ex``, ``block_e = sz*ey*ex``):
-      rext_ref:  (1, Lee, n^3)   halo'd residual window
-      d_ref/dt_ref: (n, n)
-      gext_ref:  (1, Lee, 3, n^3) halo'd metric diagonal
-      mx_ref/my_ref: (ex|ey, n)  per-axis Dirichlet factors
-      mzext_ref: (1, L, n)       halo'd z mask-factor window
-      cx_ref/cy_ref: (ex|ey, n); cz_ref: (sz, n) owned z c-factor slice
-      coef_ref:  (k+1, 2)        Chebyshev recurrence scalars
-      z_ref:     (block_e, n^3)  owned q_k(A) r
-      rtz_ref:   (1, 1)          partial  sum(r * c * z)
+    ``z`` is rounded through the storage dtype before the reduction.  Refs
+    as :func:`nekbone_ax_powers_kernel`, with ``coef`` (k+1, 2) in SMEM,
+    ``z_ref`` the owned ``(n, n^2, be)`` block, ``rtz`` ``(1, 1, 1)`` and
+    seven VMEM scratch windows.
     """
     L = sz + 2 * halo
-    Lee = L * ey * ex
-    block_e = sz * ey * ex
-    n3 = n ** 3
-    f32 = _accum(rext_ref.dtype, acc_dtype)
-    out_dtype = z_ref.dtype
-    D = d_ref[...].astype(f32)
-    Dt = dt_ref[...].astype(f32)
-    g3 = gext_ref[0].astype(f32)
-    mask = _box_outer(mzext_ref[0].astype(f32), my_ref[...].astype(f32),
-                      mx_ref[...].astype(f32))
-    coef = coef_ref[...].astype(f32)
+    slab = ex * ey
+    be, ho = sz * slab, halo * slab
+    n = rext_ref.shape[0]
+    f = _accum(rext_ref.dtype, acc_dtype)
+    fl = fl_ref[...]
+    mxy = mxy_ref[...].astype(f)
+    r = lambda kk: rext_ref[kk].astype(f)                # noqa: E731
 
-    def apply_a(v):
-        """One masked, block-assembled operator application (unscaled)."""
-        w = ax_block_diag(v, D, Dt, g3, n=n, e=Lee, layout=layout)
-        v6 = w.reshape(L, ey, ex, n, n, n) * mask
-        if ex > 1:
-            t = v6[:, :, :-1, :, :, -1] + v6[:, :, 1:, :, :, 0]
-            v6 = v6.at[:, :, :-1, :, :, -1].set(t)
-            v6 = v6.at[:, :, 1:, :, :, 0].set(t)
-        if ey > 1:
-            t = v6[:, :-1, :, :, -1, :] + v6[:, 1:, :, :, 0, :]
-            v6 = v6.at[:, :-1, :, :, -1, :].set(t)
-            v6 = v6.at[:, 1:, :, :, 0, :].set(t)
-        if L > 1:
-            t = v6[:-1, :, :, -1, :, :] + v6[1:, :, :, 0, :, :]
-            v6 = v6.at[:-1, :, :, -1, :, :].set(t)
-            v6 = v6.at[1:, :, :, 0, :, :].set(t)
-        return v6.reshape(Lee, n3)
+    def init(kk, c):
+        d_s[kk] = coef_ref[0, 0] * r(kk)
+        z_s[kk] = d_s[kk]
+        res_s[kk] = r(kk)
+        return c
 
-    r = rext_ref[0].astype(f32)
-    d = coef[0, 0] * r
-    z = d
-    res = r
+    jax.lax.fori_loop(0, n, init, 0)
     for i in range(1, k + 1):
-        res = res - apply_a(d)
-        d = coef[i, 0] * d + coef[i, 1] * res
-        z = z + d
+        _window_apply(lambda kk: d_s[kk], ad_s, kf_ref, kb_ref, d_ref,
+                      lambda m, kk: gext_ref[m, kk].astype(f),
+                      lambda kk: mzext_ref[0, kk].astype(f) * mxy, pm_ref,
+                      fl, (ur, us, ut), f, ex=ex, ey=ey, L=L)
 
-    ho = halo * ey * ex
-    z_own = z[ho:ho + block_e].astype(out_dtype)
-    r_own = r[ho:ho + block_e]
-    c6 = _box_outer(cz_ref[...].astype(f32), cy_ref[...].astype(f32),
-                    cx_ref[...].astype(f32))
-    z6 = z_own.astype(f32).reshape(sz, ey, ex, n, n, n)
-    r6 = r_own.reshape(sz, ey, ex, n, n, n)
-    rtz_ref[0, 0] = jnp.sum(r6 * c6 * z6).astype(rtz_ref.dtype)
-    z_ref[...] = z_own
+        def step(kk, c, i=i):
+            res = res_s[kk] - ad_s[kk]
+            res_s[kk] = res
+            dn = coef_ref[i, 0] * d_s[kk] + coef_ref[i, 1] * res
+            d_s[kk] = dn
+            z_s[kk] = z_s[kk] + dn
+            return c
+
+        jax.lax.fori_loop(0, n, step, 0)
+    cxy = cxy_ref[...].astype(f)
+
+    def own(kk, acc):
+        z = _own(z_s[kk], ho, be).astype(z_ref.dtype)
+        z_ref[kk] = z
+        c = cz_ref[kk].astype(f) * cxy
+        return acc + _own(r(kk), ho, be) * c * z.astype(f)
+
+    acc = jax.lax.fori_loop(0, n, own, jnp.zeros((n * n, be), f))
+    rtz_ref[0] = _total(acc).astype(rtz_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "k",
                                              "interpret", "acc_dtype",
-                                             "layout", "grid_order"))
+                                             "grid_order"))
 def nekbone_cheb_apply_pallas(rext: jnp.ndarray, D: jnp.ndarray,
                               Dt: jnp.ndarray, gext: jnp.ndarray,
                               mx: jnp.ndarray, my: jnp.ndarray,
@@ -1533,131 +1476,120 @@ def nekbone_cheb_apply_pallas(rext: jnp.ndarray, D: jnp.ndarray,
                               grid: tuple[int, int, int], sz: int, k: int,
                               interpret: bool = False,
                               acc_dtype: str | None = None,
-                              layout: str = "fold",
                               grid_order: str = "parallel"):
     """Multi-output pallas_call for the Chebyshev-apply kernel.
 
     Args:
-      rext: (EZ//sz, Lee, n^3) halo'd residual windows
+      rext: (n, n^2, (EZ//sz)*Lee) halo'd residual windows
         (:func:`sstep_extend_field` with ``halo = k``); gext:
-        (EZ//sz, Lee, 3, n^3); mzext: (EZ//sz, L, n)
-        (:func:`sstep_extend_zfactor`); cz: (EZ, n) — blocked into owned
-        (sz, n) slices; coef: (k+1, 2) Chebyshev recurrence scalars.
+        (3, n, n^2, (EZ//sz)*Lee); mzext: (EZ//sz, L, n); cz: (EZ, n);
+        coef: (k+1, 2) Chebyshev recurrence scalars.
 
-    Returns ``(z, rtz_parts)``: z ``(E, n^3)`` in the storage dtype of
+    Returns ``(z, rtz_parts)``: z ``(n, n^2, E)`` in the storage dtype of
     ``rext``, rtz partials ``(EZ//sz, 1)`` in the accumulation dtype.
     """
     ex, ey, ez = grid
     assert ez % sz == 0 and k >= 1, (grid, sz, k)
     halo = k
     L = sz + 2 * halo
-    Lee = L * ey * ex
-    block_e = sz * ey * ex
+    slab = ex * ey
+    Lee = L * slab
+    be = sz * slab
     nblk = ez // sz
-    E = nblk * block_e
-    n3 = n ** 3
-    assert rext.shape == (nblk, Lee, n3), (rext.shape, (nblk, Lee, n3))
+    E = nblk * be
+    assert rext.shape == (n, n * n, nblk * Lee), (rext.shape, (nblk, Lee))
     assert coef.shape == (k + 1, 2), coef.shape
-    acc = _accum(rext.dtype, acc_dtype)
-    ext = pl.BlockSpec((1, Lee, n3), lambda i: (i, 0, 0))
-    return pl.pallas_call(
-        functools.partial(nekbone_cheb_apply_kernel, n=n, ex=ex, ey=ey,
-                          sz=sz, k=k, halo=halo, acc_dtype=acc_dtype,
-                          layout=layout),
-        grid=(nblk,),
-        in_specs=[
-            ext,                                        # r window
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # D
-            pl.BlockSpec((n, n), lambda i: (0, 0)),     # Dt
-            pl.BlockSpec((1, Lee, 3, n3), lambda i: (i, 0, 0, 0)),  # g diag
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # mask factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # mask factor y
-            pl.BlockSpec((1, L, n), lambda i: (i, 0, 0)),  # mask z window
-            pl.BlockSpec((ex, n), lambda i: (0, 0)),    # c factor x
-            pl.BlockSpec((ey, n), lambda i: (0, 0)),    # c factor y
-            pl.BlockSpec((sz, n), lambda i: (i, 0)),    # c factor z slice
-            pl.BlockSpec((k + 1, 2), lambda i: (0, 0)),  # cheb scalars
-        ],
-        out_specs=(pl.BlockSpec((block_e, n3), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))),
-        out_shape=(
-            jax.ShapeDtypeStruct((E, n3), rext.dtype),
-            jax.ShapeDtypeStruct((nblk, 1), acc),
-        ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=(grid_order,),
-        ),
-        interpret=interpret,
+    f = _accum(rext.dtype, acc_dtype)
+    kf, kb, d = _operator_operands(D, Dt, f)
+    win = _field_spec(n, Lee)
+    z, rtz = _call(
+        functools.partial(nekbone_cheb_apply_kernel, ex=ex, ey=ey, sz=sz,
+                          k=k, halo=halo, acc_dtype=acc_dtype),
         name=(f"nekbone_cheb_apply_n{n}_sz{sz}_k{k}{_acc_tag(acc_dtype)}"
-              f"{_cfg_tag(layout, grid_order)}"),
-    )(rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, coef)
+              f"{_order_tag(grid_order)}"),
+        grid=(nblk,),
+        in_specs=[win] + _op_specs(n) + _window_specs(n, Lee)
+        + [_full_spec((n * n, be)), _lane_spec(n, be), _SMEM],
+        out_specs=(_field_spec(n, be), _part_spec()),
+        out_shape=(jax.ShapeDtypeStruct((n, n * n, E), rext.dtype),
+                   _part_shape(nblk, f)),
+        interpret=interpret, grid_order=grid_order,
+        scratch=_scratch(n, Lee, f, 7),
+        operands=(rext, kf, kb, d,
+                  *_window_operands(gext, mx, my, mzext, grid, n, L, f),
+                  _plane_factor(cx, cy, be), _z_lanes(cz, slab),
+                  jnp.asarray(coef, f)))
+    return z, _part(rtz)
 
 
-def nekbone_interp_kernel(u_ref, mt_ref, v_ref, *, nin: int, nout: int,
-                          block_e: int, acc_dtype: str | None = None):
+def nekbone_interp_kernel(u_ref, ar_ref, as_ref, mt_ref, v_ref, *,
+                          acc_dtype: str | None = None):
     """Tensor-product GLL-to-GLL interpolation of one element block.
 
-    The p-multigrid transfer operator (DESIGN.md §13): the VMEM-resident
-    transfer matrix ``mt`` — ``(nin, nout)``, i.e. rows indexed by the
-    *input* grid like the ``_dg`` convention — is contracted along each
-    of the three local directions with the same dot_general + output-
-    transpose pattern the ``dng`` operator layout uses, so one kernel
-    serves both directions: ``mt = J^T`` prolongs (coarse -> fine),
-    ``mt = J`` restricts (fine -> coarse, the unweighted core of the
-    c-weighted adjoint — the c-multiply / gather-scatter / mask around
-    it stay outside).  Purely element-local (interpolation never crosses
-    element faces), so there is no halo or plane side channel and slab
-    splits are fp64-bitwise by construction.
+    The p-multigrid transfer operator (DESIGN.md §13): ``mt`` — ``(nin,
+    nout)``, rows indexed by the *input* grid — is contracted along i
+    (``ar = kron(I, mt^T)``), then j (``as = kron(mt^T, I)``), both per
+    k-layer on the MXU, then along k with ``mt``'s scalars (SMEM).
+    ``mt = J^T`` prolongs, ``mt = J`` restricts.  Element-local, so slab
+    splits give identical results.
 
-    Refs: u_ref (block_e, nin^3), mt_ref (nin, nout),
-    v_ref (block_e, nout^3).
+    Refs: u_ref ``(nin, nin^2, be)``, ar ``(nin*nout, nin^2)``, as
+    ``(nout^2, nin*nout)``, v_ref ``(nout, nout^2, be)``.
     """
-    f32 = _accum(u_ref.dtype, acc_dtype)
-    mt = mt_ref[...].astype(f32)
-    u = u_ref[...].astype(f32).reshape(block_e, nin, nin, nin)
-    v = _dg(u, mt, 3)                           # (e, k, j, io)
-    v = _dg(v, mt, 2).transpose(0, 1, 3, 2)     # (e, k, jo, io)
-    v = _dg(v, mt, 1).transpose(0, 3, 1, 2)     # (e, ko, jo, io)
-    v_ref[...] = v.reshape(block_e, nout ** 3).astype(v_ref.dtype)
+    f = _accum(u_ref.dtype, acc_dtype)
+    _store(v_ref, _interp_layers(_load(u_ref, f), ar_ref[...].astype(f),
+                                as_ref[...].astype(f), mt_ref))
+
+
+def _interp_operators(mt: jnp.ndarray):
+    """In-plane operators ``(kron(I, mt^T), kron(mt^T, I))`` of the
+    interpolation by ``mt`` (nin, nout), in ``mt``'s dtype."""
+    nin, nout = mt.shape
+    return (jnp.kron(jnp.eye(nin, dtype=mt.dtype), mt.T),
+            jnp.kron(mt.T, jnp.eye(nout, dtype=mt.dtype)))
+
+
+def _interp_layers(layers, ar, as_, mt):
+    """Interpolate a list of ``nin`` k-layers ``(nin^2, lanes)`` to
+    ``nout`` layers: i then j per layer (MXU), then k (scalars of ``mt``).
+    """
+    w = [_mm(as_, _mm(ar, a)) for a in layers]
+    return _tdir(mt, w, True)
 
 
 @functools.partial(jax.jit, static_argnames=("nin", "nout", "grid", "sz",
                                              "interpret", "acc_dtype",
                                              "grid_order"))
-def nekbone_interp_pallas(u2: jnp.ndarray, mt: jnp.ndarray, *, nin: int,
+def nekbone_interp_pallas(u: jnp.ndarray, mt: jnp.ndarray, *, nin: int,
                           nout: int, grid: tuple[int, int, int], sz: int,
                           interpret: bool = False,
                           acc_dtype: str | None = None,
                           grid_order: str = "parallel") -> jnp.ndarray:
     """pallas_call wrapper for :func:`nekbone_interp_kernel`.
 
-    ``u2`` is ``(E, nin^3)`` flat-local; ``mt`` is ``(nin, nout)``;
-    returns ``(E, nout^3)`` in the storage dtype of ``u2``.  Blocked by
-    z-slabs of ``sz`` element layers like the rest of the slab family
-    (same BlockSpec shape, grid and dimension-semantics machinery) so a
-    V-cycle level reuses its autotuned slab split for the transfers.
+    ``u`` is ``(nin, nin^2, E)``; ``mt`` is ``(nin, nout)``; returns
+    ``(nout, nout^2, E)`` in the storage dtype of ``u``.  Blocked by
+    z-slabs of ``sz`` element layers like the rest of the slab family.
     """
     ex, ey, ez = grid
     assert ez % sz == 0, (grid, sz)
-    block_e = sz * ey * ex
+    be = sz * ey * ex
     nblk = ez // sz
-    E = nblk * block_e
-    assert u2.shape == (E, nin ** 3), (u2.shape, (E, nin ** 3))
+    E = nblk * be
+    assert u.shape == (nin, nin * nin, E), (u.shape, (nin, E))
     assert mt.shape == (nin, nout), (mt.shape, (nin, nout))
-    return pl.pallas_call(
-        functools.partial(nekbone_interp_kernel, nin=nin, nout=nout,
-                          block_e=block_e, acc_dtype=acc_dtype),
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((block_e, nin ** 3), lambda i: (i, 0)),
-            pl.BlockSpec((nin, nout), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_e, nout ** 3), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((E, nout ** 3), u2.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=(grid_order,),
-        ),
-        interpret=interpret,
+    f = _accum(u.dtype, acc_dtype)
+    mtf = jnp.asarray(mt, f)
+    ar, as_ = _interp_operators(mtf)
+    v = _call(
+        functools.partial(nekbone_interp_kernel, acc_dtype=acc_dtype),
         name=(f"nekbone_interp_{nin}to{nout}_sz{sz}{_acc_tag(acc_dtype)}"
-              f"{_cfg_tag('fold', grid_order)}"),
-    )(u2, mt)
+              f"{_order_tag(grid_order)}"),
+        grid=(nblk,),
+        in_specs=[_field_spec(nin, be), _full_spec(ar.shape),
+                  _full_spec(as_.shape), _SMEM],
+        out_specs=_field_spec(nout, be),
+        out_shape=jax.ShapeDtypeStruct((nout, nout * nout, E), u.dtype),
+        interpret=interpret, grid_order=grid_order,
+        operands=(u, ar, as_, mtf))
+    return v

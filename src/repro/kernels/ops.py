@@ -2,8 +2,12 @@
 
 Each wrapper:
   * accepts natural shapes and reshapes/pads to the kernel's HBM layout,
-  * picks ``interpret=True`` automatically off-TPU (this container is
-    CPU-only; the TPU lowering is exercised structurally by the dry-run),
+  * picks ``interpret=True`` off-TPU, so the CPU test suite runs the kernel
+    code in the Pallas interpreter, and ``interpret=False`` on a TPU
+    backend, where Mosaic compiles every kernel (``chip_smoke.py`` runs
+    them on a chip, ``tests/test_tpu_compile.py`` compiles them for a
+    described v5e); a kernel Mosaic refuses raises there, it never falls
+    back,
   * exposes the tuning knobs (block sizes) with roofline-reasoned defaults.
 """
 from __future__ import annotations
@@ -30,7 +34,29 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pick_block_e(E: int, n: int, vmem_budget_bytes: int = 8 * 2 ** 20) -> int:
+def _lanes(f: jnp.ndarray) -> jnp.ndarray:
+    """``(..., E, n, n, n)`` natural field -> ``(..., n, n^2, E)``."""
+    n = f.shape[-1]
+    return _ax.to_lanes(f.reshape(f.shape[:-3] + (n ** 3,)), n)
+
+
+def _natural(f: jnp.ndarray, shape) -> jnp.ndarray:
+    """Inverse of :func:`_lanes`, to the natural ``shape``."""
+    return _ax.from_lanes(f, shape[-1]).reshape(shape)
+
+
+def _plane_lanes(planes, lead: tuple, nblk: int, n: int,
+                 slab: int) -> jnp.ndarray:
+    """``(..., nblk, EY*EX*n^2)`` planes ordered ``(y, x, j, i)`` (zeros
+    when ``None``) -> the kernels' ``(..., nblk, n^2, EY*EX)``."""
+    if planes is None:
+        return jnp.zeros(lead + (nblk, n * n, slab))
+    p = jnp.asarray(planes).reshape(lead + (nblk, slab, n * n))
+    return jnp.swapaxes(p, -1, -2)
+
+
+def _pick_block_e(E: int, n: int,
+                  vmem_budget_bytes: int = _autotune.VMEM_LIMIT_BYTES) -> int:
     """Back-compat alias for the VMEM heuristic (see kernels/autotune.py).
 
     Default ``block_e`` selection now goes through the cached
@@ -45,11 +71,10 @@ def _pick_block_e(E: int, n: int, vmem_budget_bytes: int = 8 * 2 ** 20) -> int:
 def _nekbone_ax_impl(u, D, Dt, g, block_e, interpret):
     E = u.shape[0]
     n = u.shape[-1]
-    u2 = u.reshape(E, n ** 3)
-    g2 = g.reshape(E, 6, n ** 3)
-    w2 = _ax.nekbone_ax_pallas(u2, D, Dt, g2, n=n, block_e=block_e,
-                               interpret=interpret)
-    return w2.reshape(u.shape)
+    w = _ax.nekbone_ax_pallas(
+        _lanes(u), D, Dt, _ax.metric_lanes(g.reshape(E, 6, n ** 3), n),
+        n=n, block_e=block_e, interpret=interpret)
+    return _natural(w, u.shape)
 
 
 def nekbone_ax(u: jnp.ndarray, D: jnp.ndarray, g: jnp.ndarray, *,
@@ -105,12 +130,11 @@ def nekbone_ax_dots(p: jnp.ndarray, D: jnp.ndarray, g: jnp.ndarray,
 
         p, g, mask, r, c = map(zpad, (p, g, mask, r, c))
     Ep = p.shape[0]
-    n3 = n ** 3
-    w2, pap_b, rcz_b = _ax.nekbone_ax_dots_pallas(
-        p.reshape(Ep, n3), jnp.asarray(D), jnp.asarray(D).T,
-        g.reshape(Ep, 6, n3), mask.reshape(Ep, n3), r.reshape(Ep, n3),
-        c.reshape(Ep, n3), n=n, block_e=block_e, interpret=interpret)
-    w = w2.reshape(Ep, n, n, n)
+    w, pap_b, rcz_b = _ax.nekbone_ax_dots_pallas(
+        _lanes(p), jnp.asarray(D), jnp.asarray(D).T,
+        _ax.metric_lanes(g.reshape(Ep, 6, n ** 3), n), _lanes(mask),
+        _lanes(r), _lanes(c), n=n, block_e=block_e, interpret=interpret)
+    w = _natural(w, p.shape)
     return (w[:E] if pad else w), jnp.sum(pap_b), jnp.sum(rcz_b)
 
 
@@ -161,7 +185,6 @@ def nekbone_ax_dots_slab(p_prev: jnp.ndarray, r: jnp.ndarray,
                          D: jnp.ndarray, g3: jnp.ndarray,
                          grid: tuple[int, int, int], *, beta: float = 0.0,
                          sz: int | None = None,
-                         layout: str | None = None,
                          grid_order: str | None = None,
                          interpret: bool | None = None,
                          acc_dtype: str | None = None):
@@ -180,52 +203,43 @@ def nekbone_ax_dots_slab(p_prev: jnp.ndarray, r: jnp.ndarray,
          validated zero, then dropped — see :func:`diag_metric`).
       grid: (EX, EY, EZ); beta: direction-update scalar.
       sz: slabs per block (default: autotuned divisor of EZ).
-      layout, grid_order: contraction layout / grid iteration order
-        (defaults: jointly autotuned with sz when all three are None,
-        see :func:`repro.kernels.autotune.pick_slab_config`; otherwise
-        the historical ``("fold", "parallel")``).
+      grid_order: grid iteration order (default: autotuned jointly with
+        sz when both are None, see
+        :func:`repro.kernels.autotune.pick_slab_config`; otherwise
+        ``"parallel"``).
       acc_dtype: explicit in-kernel accumulation dtype (precision policy).
 
     Returns ``(p, w, pap)`` with ``pap == p·c·(mask gs w_local)`` tree-
     reduced from the per-block partials.
     """
-    ex, ey, ez = grid = tuple(grid)
+    grid = tuple(grid)
     E = p_prev.shape[0]
     n = p_prev.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_slab_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_slab_config(
             grid, n, p_prev.dtype, acc_dtype=acc_dtype)
     elif sz is None:
         sz = _autotune.pick_slab_sz(grid, n, p_prev.dtype,
                                     acc_dtype=acc_dtype)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
-    n3 = n ** 3
-    nblk = ez // sz
     (mx, my, mz), _ = slab_axis_factors(grid, n, p_prev.dtype)
     D = jnp.asarray(D, p_prev.dtype)
     g3 = diag_metric(jnp.asarray(g3, p_prev.dtype), E, n)
     acc = _ax._accum(p_prev.dtype, acc_dtype)
     beta_arr = jnp.full((1, 1), beta, acc)
     p2, w2, bot, top, pap_b = _ax.nekbone_ax_slab_pallas(
-        p_prev.reshape(E, n3), r.reshape(E, n3), D, D.T,
-        g3, mx, my, mz,
-        beta_arr, n=n, grid=grid, sz=sz, interpret=interpret,
-        acc_dtype=acc_dtype, layout=layout, grid_order=grid_order)
-    vb = w2.reshape(nblk, sz, ey, ex, n, n, n)
-    plane = (nblk - 1, ey, ex, n, n)
-    if nblk > 1:
-        vb = vb.at[1:, 0, :, :, 0, :, :].add(top[:-1].reshape(plane))
-        vb = vb.at[:-1, -1, :, :, -1, :, :].add(bot[1:].reshape(plane))
-    return (p2.reshape(p_prev.shape), vb.reshape(p_prev.shape),
+        _lanes(p_prev), _lanes(r), D, D.T, _ax.metric_lanes(g3, n),
+        mx, my, mz, beta_arr, n=n, grid=grid, sz=sz, interpret=interpret,
+        acc_dtype=acc_dtype, grid_order=grid_order)
+    w2 = _ax.stitch_planes(w2, bot, top, grid, sz)
+    return (_natural(p2, p_prev.shape), _natural(w2, p_prev.shape),
             jnp.sum(pap_b))
 
 
 def nekbone_ax_powers(p: jnp.ndarray, r: jnp.ndarray, D: jnp.ndarray,
                       g3: jnp.ndarray, grid: tuple[int, int, int], *,
                       s: int, theta: float = 1.0, sz: int | None = None,
-                      layout: str | None = None,
                       grid_order: str | None = None,
                       interpret: bool | None = None,
                       acc_dtype: str | None = None):
@@ -242,41 +256,40 @@ def nekbone_ax_powers(p: jnp.ndarray, r: jnp.ndarray, D: jnp.ndarray,
       D: (n, n); g3: diagonal (E, 3, ...) or verifiably-diagonal 6-component
          metric; theta: basis scale (``A' = A/theta``).
       s: powers per cycle (>= 1); sz: slabs per block (default: autotuned).
-      layout, grid_order: contraction layout / grid iteration order
-        (defaults: jointly autotuned with sz when all three are None,
-        see :func:`repro.kernels.autotune.pick_sstep_config`).
+      grid_order: grid iteration order (default: autotuned jointly with
+        sz when both are None, see
+        :func:`repro.kernels.autotune.pick_sstep_config`).
 
     Returns ``(basis, gram)``: basis ``(E, 2s-1, n, n, n)`` holding
     ``[A'p..A'^s p, A'r..A'^{s-1} r]`` and the summed ``(2s+1, 2s+1)``
     Gram matrix in the accumulation dtype.
     """
-    ex, ey, ez = grid = tuple(grid)
+    grid = tuple(grid)
     E = p.shape[0]
     n = p.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_sstep_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_sstep_config(
             grid, n, s, p.dtype, acc_dtype=acc_dtype)
     elif sz is None:
         sz = _autotune.pick_slab_sz_sstep(grid, n, s, p.dtype,
                                           acc_dtype=acc_dtype)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
-    n3 = n ** 3
     (mx, my, mz), (cx, cy, cz) = slab_axis_factors(grid, n, p.dtype)
     D = jnp.asarray(D, p.dtype)
-    g3 = diag_metric(jnp.asarray(g3, p.dtype), E, n)
+    g3 = _ax.metric_lanes(diag_metric(jnp.asarray(g3, p.dtype), E, n), n)
     acc = _ax._accum(p.dtype, acc_dtype)
-    pext = _ax.sstep_extend_field(p.reshape(E, n3), grid, sz, s)
-    rext = _ax.sstep_extend_field(r.reshape(E, n3), grid, sz, s)
+    pext = _ax.sstep_extend_field(_lanes(p), grid, sz, s)
+    rext = _ax.sstep_extend_field(_lanes(r), grid, sz, s)
     gext = _ax.sstep_extend_field(g3, grid, sz, s)
     mzext = _ax.sstep_extend_zfactor(mz, sz, s)
     inv_theta = jnp.full((1, 1), 1.0 / theta, acc)
     basis, gram_b = _ax.nekbone_ax_powers_pallas(
         pext, rext, D, D.T, gext, mx, my, mzext, cx, cy, cz, inv_theta,
         n=n, grid=grid, sz=sz, s=s, interpret=interpret, acc_dtype=acc_dtype,
-        layout=layout, grid_order=grid_order)
-    return (basis.reshape(E, 2 * s - 1, n, n, n), jnp.sum(gram_b, axis=0))
+        grid_order=grid_order)
+    basis = jnp.moveaxis(_natural(basis, (2 * s - 1,) + p.shape), 0, 1)
+    return basis, jnp.sum(gram_b, axis=0)
 
 
 def nekbone_sstep_update(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
@@ -298,23 +311,20 @@ def nekbone_sstep_update(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
 
     Returns ``(x_new, r_new, p_new, rcr)``.
     """
-    ex, ey, ez = grid = tuple(grid)
-    E = x.shape[0]
+    grid = tuple(grid)
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
     if sz is None:
         sz = _autotune.pick_slab_sz_sstep(grid, n, s, p.dtype,
                                           acc_dtype=acc_dtype)
-    n3 = n ** 3
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
     x2, r2, p2, rcr_b = _ax.nekbone_sstep_update_pallas(
-        x.reshape(E, n3), p.reshape(E, n3), r.reshape(E, n3),
-        basis.reshape(E, 2 * s - 1, n3), jnp.asarray(coef, acc),
-        cx, cy, cz, n=n, grid=grid, sz=sz, s=s, interpret=interpret,
-        acc_dtype=acc_dtype)
-    return (x2.reshape(x.shape), r2.reshape(x.shape), p2.reshape(x.shape),
-            jnp.sum(rcr_b))
+        _lanes(x), _lanes(p), _lanes(r), _lanes(jnp.moveaxis(basis, 1, 0)),
+        jnp.asarray(coef, acc), cx, cy, cz, n=n, grid=grid, sz=sz, s=s,
+        interpret=interpret, acc_dtype=acc_dtype)
+    return (_natural(x2, x.shape), _natural(r2, x.shape),
+            _natural(p2, x.shape), jnp.sum(rcr_b))
 
 
 def nekbone_cg_update(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
@@ -339,35 +349,28 @@ def nekbone_cg_update(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
     Returns ``(x_new, r_new, rtz_new)``.
     """
     ex, ey, ez = grid = tuple(grid)
-    E = x.shape[0]
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
     if sz is None:
         sz = _autotune.pick_slab_sz(grid, n, x.dtype, acc_dtype=acc_dtype)
-    n3 = n ** 3
     nblk = ez // sz
-    pln = ey * ex * n * n
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
-    if addb is None:
-        addb = jnp.zeros((nblk, pln), x.dtype)
-    if addt is None:
-        addt = jnp.zeros((nblk, pln), x.dtype)
     alpha_arr = jnp.full((1, 1), alpha, acc)
     x2, r2, rcr_b = _ax.nekbone_cg_update_pallas(
-        x.reshape(E, n3), p.reshape(E, n3), r.reshape(E, n3),
-        w.reshape(E, n3), addb.reshape(nblk, pln), addt.reshape(nblk, pln),
+        _lanes(x), _lanes(p), _lanes(r), _lanes(w),
+        _plane_lanes(addb, (), nblk, n, ex * ey).astype(x.dtype),
+        _plane_lanes(addt, (), nblk, n, ex * ey).astype(x.dtype),
         alpha_arr, cx, cy, cz, n=n, grid=grid, sz=sz, interpret=interpret,
         acc_dtype=acc_dtype)
-    return x2.reshape(x.shape), r2.reshape(x.shape), jnp.sum(rcr_b)
+    return _natural(x2, x.shape), _natural(r2, x.shape), jnp.sum(rcr_b)
 
 
 def nekbone_ax_dots_slab_block(p_prev: jnp.ndarray, r: jnp.ndarray,
                                D: jnp.ndarray, g3: jnp.ndarray,
                                grid: tuple[int, int, int], *,
                                beta=0.0, sz: int | None = None,
-                               layout: str | None = None,
-                               grid_order: str | None = None,
+                                     grid_order: str | None = None,
                                interpret: bool | None = None,
                                acc_dtype: str | None = None):
     """Batched v2 slab dots kernel on natural shapes (DESIGN.md §12).
@@ -381,20 +384,17 @@ def nekbone_ax_dots_slab_block(p_prev: jnp.ndarray, r: jnp.ndarray,
     Returns ``(p, w, pap)`` with ``pap`` a length-b vector of per-RHS
     ``p·c·(mask gs w_local)`` partial reductions.
     """
-    ex, ey, ez = grid = tuple(grid)
+    grid = tuple(grid)
     nrhs, E = p_prev.shape[0], p_prev.shape[1]
     n = p_prev.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_slab_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_slab_config(
             grid, n, p_prev.dtype, acc_dtype=acc_dtype, nrhs=nrhs)
     elif sz is None:
         sz = _autotune.pick_slab_sz(grid, n, p_prev.dtype,
                                     acc_dtype=acc_dtype, nrhs=nrhs)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
-    n3 = n ** 3
-    nblk = ez // sz
     (mx, my, mz), _ = slab_axis_factors(grid, n, p_prev.dtype)
     D = jnp.asarray(D, p_prev.dtype)
     g3 = diag_metric(jnp.asarray(g3, p_prev.dtype), E, n)
@@ -402,18 +402,11 @@ def nekbone_ax_dots_slab_block(p_prev: jnp.ndarray, r: jnp.ndarray,
     beta_arr = jnp.broadcast_to(jnp.asarray(beta, acc),
                                 (nrhs,)).reshape(1, nrhs)
     p3, w3, bot, top, pap_b = _ax.nekbone_ax_slab_block_pallas(
-        p_prev.reshape(nrhs, E, n3), r.reshape(nrhs, E, n3), D, D.T,
-        g3, mx, my, mz, beta_arr, n=n, grid=grid, sz=sz,
-        interpret=interpret, acc_dtype=acc_dtype, layout=layout,
-        grid_order=grid_order)
-    vb = w3.reshape(nrhs, nblk, sz, ey, ex, n, n, n)
-    plane = (nrhs, nblk - 1, ey, ex, n, n)
-    if nblk > 1:
-        vb = vb.at[:, 1:, 0, :, :, 0, :, :].add(
-            top[:, :-1].reshape(plane))
-        vb = vb.at[:, :-1, -1, :, :, -1, :, :].add(
-            bot[:, 1:].reshape(plane))
-    return (p3.reshape(p_prev.shape), vb.reshape(p_prev.shape),
+        _lanes(p_prev), _lanes(r), D, D.T, _ax.metric_lanes(g3, n),
+        mx, my, mz, beta_arr, n=n, grid=grid, sz=sz,
+        interpret=interpret, acc_dtype=acc_dtype, grid_order=grid_order)
+    w3 = _ax.stitch_planes(w3, bot, top, grid, sz)
+    return (_natural(p3, p_prev.shape), _natural(w3, p_prev.shape),
             jnp.sum(pap_b, axis=0))
 
 
@@ -435,30 +428,25 @@ def nekbone_cg_update_block(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
     vector of per-RHS weighted norms of the updated residual.
     """
     ex, ey, ez = grid = tuple(grid)
-    nrhs, E = x.shape[0], x.shape[1]
+    nrhs = x.shape[0]
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
     if sz is None:
         sz = _autotune.pick_slab_sz(grid, n, x.dtype, acc_dtype=acc_dtype,
                                     nrhs=nrhs)
-    n3 = n ** 3
     nblk = ez // sz
-    pln = ey * ex * n * n
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
-    if addb is None:
-        addb = jnp.zeros((nrhs, nblk, pln), x.dtype)
-    if addt is None:
-        addt = jnp.zeros((nrhs, nblk, pln), x.dtype)
     alpha_arr = jnp.broadcast_to(jnp.asarray(alpha, acc),
                                  (nrhs,)).reshape(1, nrhs)
     x3, r3, rcr_b = _ax.nekbone_cg_update_block_pallas(
-        x.reshape(nrhs, E, n3), p.reshape(nrhs, E, n3),
-        r.reshape(nrhs, E, n3), w.reshape(nrhs, E, n3),
-        addb.reshape(nrhs, nblk, pln), addt.reshape(nrhs, nblk, pln),
+        _lanes(x), _lanes(p), _lanes(r), _lanes(w),
+        _plane_lanes(addb, (nrhs,), nblk, n, ex * ey).astype(x.dtype),
+        _plane_lanes(addt, (nrhs,), nblk, n, ex * ey).astype(x.dtype),
         alpha_arr, cx, cy, cz, n=n, grid=grid, sz=sz, interpret=interpret,
         acc_dtype=acc_dtype)
-    return x3.reshape(x.shape), r3.reshape(x.shape), jnp.sum(rcr_b, axis=0)
+    return (_natural(x3, x.shape), _natural(r3, x.shape),
+            jnp.sum(rcr_b, axis=0))
 
 
 def nekbone_pcg_update(x: jnp.ndarray, p: jnp.ndarray, z: jnp.ndarray,
@@ -486,35 +474,28 @@ def nekbone_pcg_update(x: jnp.ndarray, p: jnp.ndarray, z: jnp.ndarray,
     Returns ``(x_new, z_new, rtz, rcr)``.
     """
     ex, ey, ez = grid = tuple(grid)
-    E = x.shape[0]
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
     if sz is None:
         sz = _autotune.pick_slab_sz(grid, n, x.dtype, acc_dtype=acc_dtype,
                                     precond="jacobi")
-    n3 = n ** 3
     nblk = ez // sz
-    pln = ey * ex * n * n
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
-    if addb is None:
-        addb = jnp.zeros((nblk, pln), x.dtype)
-    if addt is None:
-        addt = jnp.zeros((nblk, pln), x.dtype)
     alpha_arr = jnp.full((1, 1), alpha, acc)
     x2, z2, rtz_b, rcr_b = _ax.nekbone_pcg_update_pallas(
-        x.reshape(E, n3), p.reshape(E, n3), z.reshape(E, n3),
-        w.reshape(E, n3), addb.reshape(nblk, pln), addt.reshape(nblk, pln),
-        alpha_arr, invdiag.reshape(E, n3), cx, cy, cz, n=n, grid=grid,
-        sz=sz, interpret=interpret, acc_dtype=acc_dtype)
-    return (x2.reshape(x.shape), z2.reshape(x.shape), jnp.sum(rtz_b),
+        _lanes(x), _lanes(p), _lanes(z), _lanes(w),
+        _plane_lanes(addb, (), nblk, n, ex * ey).astype(x.dtype),
+        _plane_lanes(addt, (), nblk, n, ex * ey).astype(x.dtype),
+        alpha_arr, _lanes(jnp.asarray(invdiag).reshape(x.shape)), cx, cy,
+        cz, n=n, grid=grid, sz=sz, interpret=interpret, acc_dtype=acc_dtype)
+    return (_natural(x2, x.shape), _natural(z2, x.shape), jnp.sum(rtz_b),
             jnp.sum(rcr_b))
 
 
 def nekbone_cheb_precond(r: jnp.ndarray, D: jnp.ndarray, g3: jnp.ndarray,
                          coef: jnp.ndarray, grid: tuple[int, int, int], *,
                          k: int, sz: int | None = None,
-                         layout: str | None = None,
                          grid_order: str | None = None,
                          interpret: bool | None = None,
                          acc_dtype: str | None = None):
@@ -533,38 +514,35 @@ def nekbone_cheb_precond(r: jnp.ndarray, D: jnp.ndarray, g3: jnp.ndarray,
          (:func:`repro.core.precond.cheb_scalars`).
       k: polynomial degree (>= 1); sz: slabs per block (default:
          autotuned, :func:`repro.kernels.autotune.pick_slab_sz_cheb`).
-      layout, grid_order: contraction layout / grid iteration order
-        (defaults: jointly autotuned with sz when all three are None,
-        see :func:`repro.kernels.autotune.pick_cheb_config`).
+      grid_order: grid iteration order (default: autotuned jointly with
+        sz when both are None, see
+        :func:`repro.kernels.autotune.pick_cheb_config`).
 
     Returns ``(z, rtz)``.
     """
-    ex, ey, ez = grid = tuple(grid)
+    grid = tuple(grid)
     E = r.shape[0]
     n = r.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and layout is None and grid_order is None:
-        sz, layout, grid_order = _autotune.pick_cheb_config(
+    if sz is None and grid_order is None:
+        sz, grid_order = _autotune.pick_cheb_config(
             grid, n, k, r.dtype, acc_dtype=acc_dtype)
     elif sz is None:
         sz = _autotune.pick_slab_sz_cheb(grid, n, k, r.dtype,
                                          acc_dtype=acc_dtype)
-    layout = "fold" if layout is None else layout
     grid_order = "parallel" if grid_order is None else grid_order
-    n3 = n ** 3
     (mx, my, mz), (cx, cy, cz) = slab_axis_factors(grid, n, r.dtype)
     D = jnp.asarray(D, r.dtype)
-    g3 = diag_metric(jnp.asarray(g3, r.dtype), E, n)
+    g3 = _ax.metric_lanes(diag_metric(jnp.asarray(g3, r.dtype), E, n), n)
     acc = _ax._accum(r.dtype, acc_dtype)
-    rext = _ax.sstep_extend_field(r.reshape(E, n3), grid, sz, k)
+    rext = _ax.sstep_extend_field(_lanes(r), grid, sz, k)
     gext = _ax.sstep_extend_field(g3, grid, sz, k)
     mzext = _ax.sstep_extend_zfactor(mz, sz, k)
     z2, rtz_b = _ax.nekbone_cheb_apply_pallas(
         rext, D, D.T, gext, mx, my, mzext, cx, cy, cz,
         jnp.asarray(coef, acc), n=n, grid=grid, sz=sz, k=k,
-        interpret=interpret, acc_dtype=acc_dtype,
-        layout=layout, grid_order=grid_order)
-    return z2.reshape(r.shape), jnp.sum(rtz_b)
+        interpret=interpret, acc_dtype=acc_dtype, grid_order=grid_order)
+    return _natural(z2, r.shape), jnp.sum(rtz_b)
 
 
 def nekbone_interp(u: jnp.ndarray, M: jnp.ndarray,
@@ -593,10 +571,10 @@ def nekbone_interp(u: jnp.ndarray, M: jnp.ndarray,
         sz = _autotune.pick_slab_sz(grid, max(nin, nout), u.dtype,
                                     acc_dtype=acc_dtype,
                                     precond="pmg:interp")
-    v2 = _ax.nekbone_interp_pallas(
-        u.reshape(E, nin ** 3), M.T, nin=nin, nout=nout, grid=grid, sz=sz,
+    v = _ax.nekbone_interp_pallas(
+        _lanes(u), M.T, nin=nin, nout=nout, grid=grid, sz=sz,
         interpret=interpret, acc_dtype=acc_dtype)
-    return v2.reshape(E, nout, nout, nout)
+    return _natural(v, (E, nout, nout, nout))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,

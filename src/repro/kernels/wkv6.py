@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams as _CompilerParams
+from jax.experimental.pallas.tpu import CompilerParams as _CompilerParams
 
 __all__ = ["wkv6"]
 

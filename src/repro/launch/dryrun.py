@@ -33,7 +33,7 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -9,7 +9,6 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro import compat
 
 __all__ = ["make_production_mesh", "make_mesh_for"]
 
@@ -25,12 +24,14 @@ def make_production_mesh(*, multi_pod: bool = False):
             "the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512")
     # jax.make_mesh uses all devices by default; slice when we have extras
     # (the dry-run process exposes 512 but the single-pod mesh needs 256).
-    return compat.make_mesh(shape, axes, devices=devices[:ndev])
+    return jax.make_mesh(shape, axes, devices=devices[:ndev],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(n_devices: int, *, model_parallel: int = 1):
     """Small-scale mesh for tests/examples: (data, model) over what exists."""
     devices = jax.devices()[:n_devices]
     data = n_devices // model_parallel
-    return compat.make_mesh((data, model_parallel), ("data", "model"),
-                            devices=devices)
+    return jax.make_mesh((data, model_parallel), ("data", "model"),
+                         devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -329,6 +329,9 @@ def bench_service(*, nelt: int = 64, n: int | None = None,
 
 
 def main():
+    from repro.compile_cache import configure_caches
+
+    configure_caches()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--nelt", type=int, default=64)
     ap.add_argument("--n", type=int, default=None)

@@ -23,7 +23,13 @@ import jax.numpy as jnp
 
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import axis_size, make_mesh, set_mesh, shard_map  # noqa: E402
+from jax import set_mesh, shard_map  # noqa: E402
+from jax.lax import axis_size  # noqa: E402
+
+
+def make_mesh(shape, names):
+    return jax.make_mesh(shape, names, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(names))
 
 
 def check(name, cond):
@@ -524,6 +530,24 @@ def check_sstep_sharded_s4():
     _sstep_sharded_parity(4, (1, 2, 32), 2, 8, "sstep_sharded_s4")
 
 
+def check_chip_smoke_sharded():
+    """The ``--chips 4`` phase of chip_smoke.py on four of the host devices,
+    at a tiny size (n=4, 2x2x16 elements, f32, interpret mode)."""
+    import importlib.util
+    import pathlib
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    out = smoke.sharded_phase(4, grid=(2, 2, 16), n=4, s=4, niter=8)
+    check("chip_smoke_sharded_placement", len(set(out["devices"])) == 4)
+    check("chip_smoke_sharded_hist",
+          len(out["hist_rel_diff_first10"]) == 9
+          and out["hist_diff_over_bound_max"] <= 1.0)
+    check("chip_smoke_sharded_x", out["x_max_abs_diff"] <= out["x_bound"])
+
+
 def check_sstep_collective_counts():
     """The acceptance contract: exactly one stacked halo exchange
     (2 ppermutes) and one Gram psum per cycle; collective-free update.
@@ -685,6 +709,7 @@ CHECKS = {
     "sstep_sharded_s2": check_sstep_sharded_s2,
     "sstep_sharded_s4": check_sstep_sharded_s4,
     "sstep_collective_counts": check_sstep_collective_counts,
+    "chip_smoke_sharded": check_chip_smoke_sharded,
     "pcg_jacobi_sharded": check_pcg_jacobi_sharded,
     "pcg_cheb_sharded": check_pcg_cheb_sharded,
     "pcg_sharded_precision": check_pcg_sharded_precision,

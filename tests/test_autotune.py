@@ -1,5 +1,11 @@
 """block_e autotuner: heuristic bounds, measurement path, cache behavior,
-slab-mode candidates, and the JSON disk cache."""
+slab-mode candidates, and the JSON disk cache.
+
+Picks timed with an injected ``measure`` on ``backend="tpu"`` see the same
+candidate lists as a real TPU pick (128-lane element blocks fitting the
+VMEM model), so those tests use shapes that have such blocks: E=512 flat
+blocks and the 8x8x8 element grid (64-element slabs) at n=4.
+"""
 import json
 
 import pytest
@@ -18,12 +24,16 @@ def _fresh_cache(tmp_path, monkeypatch):
     autotune.clear_cache()
 
 
+TPU_E = 512                    # flat blocks 512, 256, 128 on TPU
+TPU_GRID = (8, 8, 8)           # slab blocks sz = 8, 4, 2 on TPU (n=4)
+
+
 def test_vmem_heuristic_fits_budget():
     for n in (4, 8, 10, 12, 16):
         be = autotune.vmem_block_e(1024, n)
-        n3p = -(-(n ** 3) // 128) * 128
-        assert be >= 1
-        assert 14 * n3p * 4 * be <= autotune.VMEM_BUDGET_BYTES
+        assert be >= 1 and 1024 % be == 0
+        assert (autotune.vmem_bytes("flat", n, be)
+                <= autotune.VMEM_LIMIT_BYTES)
 
 
 def test_candidates_divide_E():
@@ -41,20 +51,20 @@ def test_pick_is_cached_per_key():
         calls.append(be)
         return float(be)            # smaller block "faster": picks 1
 
-    be1 = autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+    be1 = autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
                                 measure=measure)
-    assert be1 == 1
+    assert be1 == 128
     n_calls = len(calls)
-    assert n_calls == len(autotune.candidate_blocks(8, 4))
+    assert n_calls == len(autotune.candidate_blocks(TPU_E, 4, tpu=True))
 
     # same key: served from cache, measure never re-runs
-    be2 = autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+    be2 = autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
                                 measure=measure)
     assert be2 == be1
     assert len(calls) == n_calls
 
     # different dtype / backend / shape are distinct cache keys
-    autotune.pick_block_e(8, 4, jnp.float64, backend="tpu", measure=measure)
+    autotune.pick_block_e(TPU_E, 4, jnp.float64, backend="tpu", measure=measure)
     assert len(calls) > n_calls
     assert len(autotune.cache_info()) == 2
 
@@ -71,14 +81,14 @@ def test_cpu_backend_uses_heuristic_without_measuring():
 
 def test_measured_winner_beats_heuristic_order():
     # fastest candidate in the middle of the ladder must win
-    target = {8: 3.0, 4: 1.0, 2: 2.0, 1: 5.0}
+    target = {512: 3.0, 256: 1.0, 128: 2.0}
 
     def measure(be):
         return target[be]
 
-    be = autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+    be = autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
                                measure=measure)
-    assert be == 4
+    assert be == 256
 
 
 # ---------------------------------------------------------------------------
@@ -102,7 +112,7 @@ def test_block_keys_distinct_per_dtype_pair():
     pairs = [("bfloat16", None), ("bfloat16", "float64"),
              ("float32", None), ("float32", "float64")]
     for i, (storage, acc) in enumerate(pairs):
-        autotune.pick_block_e(8, 4, jnp.dtype(storage), acc_dtype=acc,
+        autotune.pick_block_e(TPU_E, 4, jnp.dtype(storage), acc_dtype=acc,
                               backend="tpu", measure=measure_factory(i))
     # every pair measured independently (no cache hits across pairs) ...
     assert {t for t, _ in calls} == set(range(len(pairs)))
@@ -114,7 +124,7 @@ def test_block_keys_distinct_per_dtype_pair():
     def boom(be):
         raise AssertionError("resolved-identical pair must hit the cache")
 
-    autotune.pick_block_e(8, 4, jnp.bfloat16, acc_dtype="float32",
+    autotune.pick_block_e(TPU_E, 4, jnp.bfloat16, acc_dtype="float32",
                           backend="tpu", measure=boom)
 
 
@@ -125,15 +135,15 @@ def test_slab_keys_distinct_per_dtype_pair():
         seen.append(sz)
         return float(sz)
 
-    autotune.pick_slab_sz((2, 2, 8), 4, jnp.bfloat16, backend="tpu",
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.bfloat16, backend="tpu",
                           measure=measure)
     n1 = len(seen)
-    autotune.pick_slab_sz((2, 2, 8), 4, jnp.bfloat16, acc_dtype="float64",
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.bfloat16, acc_dtype="float64",
                           backend="tpu", measure=measure)
     assert len(seen) > n1              # distinct key -> re-measured
     keys = set(autotune.cache_info())
-    assert ("slab", 4, 2, 2, 8, "bfloat16", "float32", "tpu") in keys
-    assert ("slab", 4, 2, 2, 8, "bfloat16", "float64", "tpu") in keys
+    assert ("slab", 4, 8, 8, 8, "bfloat16", "float32", "tpu") in keys
+    assert ("slab", 4, 8, 8, 8, "bfloat16", "float64", "tpu") in keys
 
 
 # ---------------------------------------------------------------------------
@@ -143,18 +153,18 @@ def test_slab_keys_distinct_per_dtype_pair():
 def test_slab_candidates_divide_ez_and_fit_budget():
     for grid in ((2, 2, 8), (4, 8, 16), (1, 3, 5), (16, 16, 14)):
         for n in (4, 10):
-            cands = autotune.candidate_slab_sizes(grid, n)
-            assert cands, (grid, n)
-            assert all(grid[2] % sz == 0 for sz in cands)
-            assert cands == sorted(cands, reverse=True)
-            assert cands[-1] == 1          # one slab is always viable
-            ex, ey, _ = grid
-            n3p = -(-(n ** 3) // 128) * 128
-            # every candidate above the floor fits the working-set budget
-            for sz in cands:
-                if sz > 1:
-                    assert (autotune._LIVE_ARRAYS * n3p * 4 * sz * ex * ey
-                            <= autotune.VMEM_BUDGET_BYTES), (grid, n, sz)
+            for tpu in (False, True):
+                cands = autotune.candidate_slab_sizes(grid, n, tpu=tpu)
+                assert all(grid[2] % sz == 0 for sz in cands)
+                assert cands == sorted(cands, reverse=True)
+                ex, ey, _ = grid
+                # every candidate fits the one footprint model; compiled
+                # blocks are also 128-lane aligned
+                for sz in cands:
+                    assert (autotune.vmem_bytes("slab", n, sz * ex * ey)
+                            <= autotune.VMEM_LIMIT_BYTES), (grid, n, sz)
+                    assert not tpu or (sz * ex * ey) % 128 == 0
+            assert autotune.candidate_slab_sizes(grid, n), (grid, n)
 
 
 def test_pick_slab_sz_cached_per_grid():
@@ -164,25 +174,26 @@ def test_pick_slab_sz_cached_per_grid():
         calls.append(sz)
         return float(sz)               # smallest "fastest": picks 1
 
-    sz1 = autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
+    sz1 = autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                                 measure=measure)
-    assert sz1 == 1
+    assert sz1 == 2                    # the smallest 128-lane block
     n_calls = len(calls)
-    assert n_calls == len(autotune.candidate_slab_sizes((2, 2, 8), 4))
+    assert n_calls == len(autotune.candidate_slab_sizes(TPU_GRID, 4,
+                                                        tpu=True))
     # same key: cached; different grid: distinct key
-    autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                           measure=measure)
     assert len(calls) == n_calls
-    autotune.pick_slab_sz((2, 2, 4), 4, jnp.float32, backend="tpu",
+    autotune.pick_slab_sz((8, 8, 4), 4, jnp.float32, backend="tpu",
                           measure=measure)
     assert len(calls) > n_calls
-    assert (("slab", 4, 2, 2, 8, "float32", "float32", "tpu")
+    assert (("slab", 4, 8, 8, 8, "float32", "float32", "tpu")
             in autotune.cache_info())
 
 
 def test_slab_heuristic_on_cpu_prefers_largest():
-    sz = autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="cpu")
-    assert sz == autotune.candidate_slab_sizes((2, 2, 8), 4)[0]
+    sz = autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="cpu")
+    assert sz == autotune.candidate_slab_sizes(TPU_GRID, 4)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -191,11 +202,11 @@ def test_slab_heuristic_on_cpu_prefers_largest():
 
 def test_measured_pick_persists_and_reloads():
     def measure(be):
-        return {8: 3.0, 4: 1.0, 2: 2.0, 1: 5.0}[be]
+        return {512: 3.0, 256: 1.0, 128: 2.0}[be]
 
-    be = autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+    be = autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
                                measure=measure)
-    assert be == 4
+    assert be == 256
     assert autotune.cache_path().exists()
 
     # simulate a fresh process: drop memory but keep the file
@@ -205,9 +216,9 @@ def test_measured_pick_persists_and_reloads():
     def boom(be):
         raise AssertionError("disk-cached pick must not re-measure")
 
-    be2 = autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+    be2 = autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
                                 measure=boom)
-    assert be2 == 4
+    assert be2 == 256
 
 
 def test_heuristic_pick_does_not_write_disk():
@@ -220,11 +231,11 @@ def test_heuristic_picks_stay_out_of_measured_disk_cache():
     # alongside it — heuristic values recompute when the budget constants
     # change, so pinning them on disk would mask that.
     autotune.pick_block_e(64, 10, jnp.float32, backend="cpu")
-    autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+    autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
                           measure=lambda be: float(be))
     data = json.loads(autotune.cache_path().read_text())
     keys = {tuple(e["key"]) for e in data["entries"]}
-    assert keys == {(4, 8, "float32", "float32", "tpu")}
+    assert keys == {(4, TPU_E, "float32", "float32", "tpu")}
 
 
 def test_sstep_candidates_shrink_with_s():
@@ -252,25 +263,25 @@ def test_pick_slab_sz_sstep_keys_carry_s():
         calls.append(sz)
         return float(sz)
 
-    sz_a = autotune.pick_slab_sz_sstep((2, 2, 8), 4, 2, jnp.float32,
+    sz_a = autotune.pick_slab_sz_sstep(TPU_GRID, 4, 2, jnp.float32,
                                        backend="tpu", measure=measure)
-    assert sz_a == 1
+    assert sz_a == 2
     n_calls = len(calls)
     # same (grid, s): cached
-    autotune.pick_slab_sz_sstep((2, 2, 8), 4, 2, jnp.float32,
+    autotune.pick_slab_sz_sstep(TPU_GRID, 4, 2, jnp.float32,
                                 backend="tpu", measure=measure)
     assert len(calls) == n_calls
     # different s: distinct key, fresh sweep
-    autotune.pick_slab_sz_sstep((2, 2, 8), 4, 4, jnp.float32,
+    autotune.pick_slab_sz_sstep(TPU_GRID, 4, 4, jnp.float32,
                                 backend="tpu", measure=measure)
     assert len(calls) > n_calls
     info = autotune.cache_info()
-    assert ("sstep", 4, 2, 2, 8, 2, "float32", "float32", "tpu") in info
-    assert ("sstep", 4, 2, 2, 8, 4, "float32", "float32", "tpu") in info
+    assert ("sstep", 4, 8, 8, 8, 2, "float32", "float32", "tpu") in info
+    assert ("sstep", 4, 8, 8, 8, 4, "float32", "float32", "tpu") in info
     # and the sstep keys never collide with the plain slab keys
-    autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                           measure=measure)
-    assert ("slab", 4, 2, 2, 8, "float32", "float32", "tpu") \
+    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu") \
         in autotune.cache_info()
 
 
@@ -299,19 +310,19 @@ def test_pick_slab_sz_cheb_keys_carry_k():
         calls.append(sz)
         return float(sz)
 
-    sz_a = autotune.pick_slab_sz_cheb((2, 2, 8), 4, 2, jnp.float32,
+    sz_a = autotune.pick_slab_sz_cheb(TPU_GRID, 4, 2, jnp.float32,
                                       backend="tpu", measure=measure)
-    assert sz_a == 1
+    assert sz_a == 2
     n_calls = len(calls)
-    autotune.pick_slab_sz_cheb((2, 2, 8), 4, 2, jnp.float32,
+    autotune.pick_slab_sz_cheb(TPU_GRID, 4, 2, jnp.float32,
                                backend="tpu", measure=measure)
     assert len(calls) == n_calls       # same (grid, k): cached
-    autotune.pick_slab_sz_cheb((2, 2, 8), 4, 4, jnp.float32,
+    autotune.pick_slab_sz_cheb(TPU_GRID, 4, 4, jnp.float32,
                                backend="tpu", measure=measure)
     assert len(calls) > n_calls        # different k: fresh sweep
     info = autotune.cache_info()
-    assert ("cheb", 4, 2, 2, 8, 2, "float32", "float32", "tpu") in info
-    assert ("cheb", 4, 2, 2, 8, 4, "float32", "float32", "tpu") in info
+    assert ("cheb", 4, 8, 8, 8, 2, "float32", "float32", "tpu") in info
+    assert ("cheb", 4, 8, 8, 8, 4, "float32", "float32", "tpu") in info
 
 
 def test_pick_slab_sz_precond_key_dimension():
@@ -323,15 +334,15 @@ def test_pick_slab_sz_precond_key_dimension():
         calls.append(sz)
         return float(sz)
 
-    autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                           measure=measure)
     n_plain = len(calls)
-    autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                           precond="jacobi", measure=measure)
     assert len(calls) > n_plain        # distinct key -> re-measured
     info = autotune.cache_info()
-    assert ("slab", 4, 2, 2, 8, "float32", "float32", "tpu") in info
-    assert ("slab", 4, 2, 2, 8, "float32", "float32", "tpu",
+    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu") in info
+    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu",
             "pc:jacobi") in info
 
 
@@ -346,12 +357,12 @@ def test_corrupt_cache_file_is_tolerated():
         calls.append(be)
         return float(be)
 
-    be = autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+    be = autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
                                measure=measure)
-    assert be == 1 and calls           # re-measured, no crash
+    assert be == 128 and calls         # re-measured, no crash
     # and the rewritten file is valid JSON with the new entry
     data = json.loads(path.read_text())
-    assert any(tuple(e["key"]) == (4, 8, "float32", "float32", "tpu")
+    assert any(tuple(e["key"]) == (4, TPU_E, "float32", "float32", "tpu")
                for e in data["entries"])
 
 
@@ -359,7 +370,7 @@ def test_clear_cache_removes_disk():
     def measure(be):
         return float(be)
 
-    autotune.pick_block_e(8, 4, jnp.float32, backend="tpu", measure=measure)
+    autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu", measure=measure)
     assert autotune.cache_path().exists()
     autotune.clear_cache()
     assert not autotune.cache_path().exists()
@@ -367,51 +378,50 @@ def test_clear_cache_removes_disk():
 
 
 # ---------------------------------------------------------------------------
-# joint (sz x layout x grid_order) configs + pipeline dispatch (DESIGN.md §11)
+# joint (sz x grid_order) configs + pipeline dispatch (DESIGN.md §11)
 # ---------------------------------------------------------------------------
 
 def test_candidate_configs_cover_the_sweep_space():
-    from repro.kernels.nekbone_ax import GRID_ORDERS, LAYOUTS
+    from repro.kernels.nekbone_ax import GRID_ORDERS
 
     cands = autotune.candidate_configs([4, 2, 1])
-    assert len(cands) == 3 * len(LAYOUTS) * len(GRID_ORDERS)
+    assert len(cands) == 3 * len(GRID_ORDERS)
     assert len(set(cands)) == len(cands)
-    # sz-major with the historical (fold, parallel) point first per sz,
-    # so a measured tie keeps the established configuration
-    assert cands[0] == (4, "fold", "parallel")
-    assert cands[len(LAYOUTS) * len(GRID_ORDERS)] == (2, "fold", "parallel")
+    # sz-major with the parallel point first per sz, so a measured tie
+    # keeps the established configuration
+    assert cands[0] == (4, "parallel")
+    assert cands[len(GRID_ORDERS)] == (2, "parallel")
 
 
 def test_pick_slab_config_heuristic_is_pre_sweep_point():
-    def boom(sz, layout, grid_order):
+    def boom(sz, grid_order):
         raise AssertionError("must not measure on cpu")
 
-    cfg = autotune.pick_slab_config((2, 2, 8), 4, jnp.float32, backend="cpu")
-    assert cfg == (autotune.candidate_slab_sizes((2, 2, 8), 4)[0],
-                   "fold", "parallel")
+    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="cpu")
+    assert cfg == (autotune.candidate_slab_sizes(TPU_GRID, 4)[0],
+                   "parallel")
     # heuristic picks stay memory-only (like the sz-only picks)
     assert not autotune.cache_path().exists()
 
 
 def test_pick_slab_config_measured_winner_and_persistence():
-    def measure(sz, layout, grid_order):
-        # a non-default point must win: (2, dng, arbitrary)
-        return 0.0 if (sz, layout, grid_order) == (2, "dng", "arbitrary") \
-            else 1.0 + sz
+    def measure(sz, grid_order):
+        # a non-default point must win: (2, arbitrary)
+        return 0.0 if (sz, grid_order) == (2, "arbitrary") else 1.0 + sz
 
-    cfg = autotune.pick_slab_config((2, 2, 8), 4, jnp.float32,
+    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32,
                                     backend="tpu", measure=measure)
-    assert cfg == (2, "dng", "arbitrary")
+    assert cfg == (2, "arbitrary")
     assert autotune.cache_path().exists()
 
     # fresh process: reload from disk, tuple round-trips intact
     autotune._CACHE.clear()
     autotune._DISK_LOADED = False
 
-    def boom(sz, layout, grid_order):
+    def boom(sz, grid_order):
         raise AssertionError("disk-cached pick must not re-measure")
 
-    cfg2 = autotune.pick_slab_config((2, 2, 8), 4, jnp.float32,
+    cfg2 = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32,
                                      backend="tpu", measure=boom)
     assert cfg2 == cfg
     assert isinstance(cfg2, tuple)
@@ -421,38 +431,38 @@ def test_cfg_keys_never_alias_sz_only_keys():
     """The joint picks live under a ("cfg", kind, ...) namespace: a
     measured sz-only pick and a joint pick for the same case must coexist
     under distinct keys."""
-    autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                           measure=lambda sz: float(sz))
-    autotune.pick_slab_config((2, 2, 8), 4, jnp.float32, backend="tpu",
-                              measure=lambda sz, ly, go: float(sz))
+    autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
+                              measure=lambda sz, go: float(sz))
     info = autotune.cache_info()
-    assert ("slab", 4, 2, 2, 8, "float32", "float32", "tpu") in info
-    assert ("cfg", "slab", 4, 2, 2, 8, "float32", "float32", "tpu") in info
+    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu") in info
+    assert ("cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu") in info
 
 
 def test_cfg_keys_carry_s_k_and_precond_dimensions():
     calls = []
 
-    def measure(sz, layout, grid_order):
-        calls.append((sz, layout, grid_order))
+    def measure(sz, grid_order):
+        calls.append((sz, grid_order))
         return float(sz)
 
-    autotune.pick_sstep_config((2, 2, 8), 4, 2, jnp.float32,
+    autotune.pick_sstep_config(TPU_GRID, 4, 2, jnp.float32,
                                backend="tpu", measure=measure)
-    autotune.pick_sstep_config((2, 2, 8), 4, 4, jnp.float32,
+    autotune.pick_sstep_config(TPU_GRID, 4, 4, jnp.float32,
                                backend="tpu", measure=measure)
-    autotune.pick_cheb_config((2, 2, 8), 4, 2, jnp.float32,
+    autotune.pick_cheb_config(TPU_GRID, 4, 2, jnp.float32,
                               backend="tpu", measure=measure)
-    autotune.pick_slab_config((2, 2, 8), 4, jnp.float32, backend="tpu",
+    autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
                               precond="jacobi", measure=measure)
     info = autotune.cache_info()
-    assert ("cfg", "sstep", 4, 2, 2, 8, 2, "float32", "float32", "tpu") \
+    assert ("cfg", "sstep", 4, 8, 8, 8, 2, "float32", "float32", "tpu") \
         in info
-    assert ("cfg", "sstep", 4, 2, 2, 8, 4, "float32", "float32", "tpu") \
+    assert ("cfg", "sstep", 4, 8, 8, 8, 4, "float32", "float32", "tpu") \
         in info
-    assert ("cfg", "cheb", 4, 2, 2, 8, 2, "float32", "float32", "tpu") \
+    assert ("cfg", "cheb", 4, 8, 8, 8, 2, "float32", "float32", "tpu") \
         in info
-    assert ("cfg", "slab", 4, 2, 2, 8, "float32", "float32", "tpu",
+    assert ("cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu",
             "pc:jacobi") in info
 
 
@@ -518,3 +528,41 @@ def test_case_ax_impl_auto_resolves_and_records_request():
     pc = NekboneCase(n=3, grid=(2, 2, 2), dtype=jnp.float32,
                      ax_impl="auto", precond="jacobi")
     assert pc.ax_impl == "pallas_fused_cg_v2"
+
+
+def test_tpu_pick_sweeps_only_compiled_candidates():
+    """An injected timer sees exactly the TPU candidate list: the 128-lane
+    filter depends on the backend alone, never on who measures."""
+    seen = []
+
+    def measure(sz):
+        seen.append(sz)
+        return float(sz)
+
+    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
+                          measure=measure)
+    assert seen == autotune.candidate_slab_sizes(TPU_GRID, 4, tpu=True)
+    assert 1 not in seen               # 64 lanes: not a compiled block
+
+
+def test_tpu_pick_without_admissible_block_raises_even_when_measured():
+    with pytest.raises(ValueError, match="VMEM"):
+        autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
+                              measure=lambda sz: float(sz))
+    with pytest.raises(ValueError, match="VMEM"):
+        autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
+                              measure=lambda be: float(be))
+
+
+def test_stale_disk_schema_is_ignored():
+    """A cache file of an older schema (3-tuple configs) is not read back:
+    the pick re-measures and rewrites the file at the current version."""
+    path = autotune.cache_path()
+    path.parent.mkdir(parents=True, exist_ok=True)
+    key = ["cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu"]
+    path.write_text(json.dumps({"version": 1, "entries": [
+        {"key": key, "value": [2, "fold", "parallel"]}]}))
+    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
+                                    measure=lambda sz, go: float(sz))
+    assert cfg == (2, "parallel")
+    assert json.loads(path.read_text())["version"] == autotune._DISK_VERSION

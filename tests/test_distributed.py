@@ -35,6 +35,7 @@ CHECK_NAMES = [
     "sstep_sharded_s2",
     "sstep_sharded_s4",
     "sstep_collective_counts",
+    "chip_smoke_sharded",
     "pcg_jacobi_sharded",
     "pcg_cheb_sharded",
     "pcg_sharded_precision",

@@ -25,23 +25,28 @@ CASES = [(1, 1), (2, 2), (1, 2)]          # (sz, halo); halo <= min ez_local
 def test_extend_field_shard_invariant(shards, sz, halo):
     rng = np.random.default_rng(11)
     eyex = EY * EX
-    f = rng.normal(size=(EZ, eyex, N3)).astype(np.float32)
+    # kernel layout: elements (slab-major) on the last axis
+    f = rng.normal(size=(N3, EZ, eyex)).astype(np.float32)
     want = np.asarray(sstep_extend_field(
-        f.reshape(EZ * eyex, N3), (EX, EY, EZ), sz, halo))
+        f.reshape(N3, EZ * eyex), (EX, EY, EZ), sz, halo))
 
     ez_l = EZ // shards
     # ghosts from the zero-padded global field: shard k's below/above are
     # the neighbour's edge slabs, exact zeros past the domain ends (the
     # padding gs.halo_exchange_z delivers there).
-    fp = np.concatenate([np.zeros((halo, eyex, N3), f.dtype), f,
-                         np.zeros((halo, eyex, N3), f.dtype)])
+    pad = np.zeros((N3, halo, eyex), f.dtype)
+    fp = np.concatenate([pad, f, pad], axis=1)
+
+    def ghost(a, b):
+        return fp[:, a:b].reshape(N3, halo * eyex)
+
     got = np.concatenate([
         np.asarray(sstep_extend_field(
-            f[k * ez_l:(k + 1) * ez_l].reshape(ez_l * eyex, N3),
+            f[:, k * ez_l:(k + 1) * ez_l].reshape(N3, ez_l * eyex),
             (EX, EY, ez_l), sz, halo,
-            below=fp[k * ez_l:k * ez_l + halo],
-            above=fp[(k + 1) * ez_l + halo:(k + 1) * ez_l + 2 * halo]))
-        for k in range(shards)])
+            below=ghost(k * ez_l, k * ez_l + halo),
+            above=ghost((k + 1) * ez_l + halo, (k + 1) * ez_l + 2 * halo)))
+        for k in range(shards)], axis=-1)
     assert np.array_equal(got, want)
 
 
@@ -70,8 +75,8 @@ def test_extend_field_default_pad_matches_explicit_zeros():
     forms the end shards may use are interchangeable."""
     rng = np.random.default_rng(13)
     eyex = EY * EX
-    f2 = rng.normal(size=(EZ * eyex, N3)).astype(np.float32)
-    z = np.zeros((2, eyex, N3), np.float32)
+    f2 = rng.normal(size=(N3, EZ * eyex)).astype(np.float32)
+    z = np.zeros((N3, 2 * eyex), np.float32)
     a = np.asarray(sstep_extend_field(f2, (EX, EY, EZ), 2, 2))
     b = np.asarray(sstep_extend_field(f2, (EX, EY, EZ), 2, 2,
                                       below=z, above=z))

@@ -67,13 +67,30 @@ def test_ax_autotuned_block(rng):
     from repro.kernels.ops import _pick_block_e
 
     for n in (4, 8, 10, 12, 16):
+        from repro.kernels import autotune
+
         be = _pick_block_e(1024, n)
-        n3p = -(-(n ** 3) // 128) * 128
         assert be >= 1
-        assert 14 * n3p * 4 * be <= 8 * 2 ** 20
+        assert (autotune.vmem_bytes("flat", n, be)
+                <= autotune.VMEM_LIMIT_BYTES)
     n, E = 10, 16
     u, D, g = _data(rng, E, n, jnp.float32)
     w_k = ops.nekbone_ax(u, D, g, interpret=True)   # autotuned path
     w_r = ref.nekbone_ax_ref(u, D, g)
     tol = 1e-5 * max(1.0, float(jnp.abs(w_r).max()))
     np.testing.assert_allclose(np.asarray(w_k), np.asarray(w_r), atol=tol)
+
+
+@pytest.mark.parametrize("storage,acc", [("float64", None),
+                                         ("float32", "float64")])
+def test_compiled_kernel_refuses_f64(x64, rng, storage, acc):
+    """Mosaic has no float64: a compiled (non-interpret) call with f64
+    storage or f64 accumulation raises before it reaches the compiler."""
+    from repro.kernels import nekbone_ax as K
+
+    E, n = 8, 4
+    u, D, g = _data(rng, E, n, jnp.dtype(storage))
+    with pytest.raises(TypeError, match="float64 is not supported"):
+        K.nekbone_ax_pallas(K.to_lanes(u.reshape(E, n ** 3), n), D, D.T,
+                            K.metric_lanes(g.reshape(E, 6, n ** 3), n), n=n,
+                            block_e=E, interpret=False, acc_dtype=acc)

@@ -117,3 +117,15 @@ def test_sstep_collective_contract():
     assert row.ok, row.detail
     assert row.measured["cycle"] == {"ppermute": 2, "psum": 1}
     assert row.measured["update"] == {}
+
+
+# ---------------------------------------------------------------------------
+# the books still describe the compiled pipelines (tracing only)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("pipeline", drift.DEFAULT_PIPELINES)
+def test_pipeline_bytes_within_calibrated_band(pipeline):
+    """The v2 loop bodies and the s-step cycle carry their state in the
+    kernel layout: no per-iteration transpose pushes them out of band."""
+    row = drift.check_bytes(pipeline)
+    assert row.ok, row.detail

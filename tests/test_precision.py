@@ -125,9 +125,10 @@ def test_kernel_partials_in_accum_dtype(rng, x64):
 
     outs = {}
     for acc in ("float32", "float64"):
-        w, pap = _ax.nekbone_ax_pap_pallas(u, D, D.T, g, mask, n=n,
-                                           block_e=2, interpret=True,
-                                           acc_dtype=acc)
+        w, pap = _ax.nekbone_ax_pap_pallas(
+            _ax.to_lanes(u, n), D, D.T, _ax.metric_lanes(g, n),
+            _ax.to_lanes(mask, n), n=n, block_e=2, interpret=True,
+            acc_dtype=acc)
         assert w.dtype == jnp.bfloat16
         assert pap.dtype == jnp.dtype(acc)
         outs[acc] = float(jnp.sum(pap))
