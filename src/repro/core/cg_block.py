@@ -218,9 +218,7 @@ def cg_block_fixed_iters(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     # boundary is this dispatch, recorded as a single span when on.
     from repro.obs import trace as _trace
 
-    rec = _trace.active()
-    with (rec.span("block.dispatch", b=nrhs, niter=niter)
-          if rec is not None else _trace.NULL_SPAN):
+    with _trace.span("block.dispatch", b=nrhs, niter=niter):
         res = _cg_block(B.reshape(nrhs, B.shape[1], n ** 3), D_op,
                         D_op.T, g3, mx, my, mz, cx, cy, cz, n=n,
                         grid=grid, niter=niter, sz=sz,
@@ -255,9 +253,7 @@ def cg_block_tol(B: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     nrhs = B.shape[0]
     from repro.obs import trace as _trace
 
-    rec = _trace.active()
-    with (rec.span("block.dispatch", b=nrhs, tol=tol)
-          if rec is not None else _trace.NULL_SPAN):
+    with _trace.span("block.dispatch", b=nrhs, tol=tol):
         res = _cg_block_tol(B.reshape(nrhs, B.shape[1], n ** 3), D_op,
                             D_op.T, g3, mx, my, mz, cx, cy, cz,
                             float(tol) ** 2, n=n, grid=grid,

@@ -66,6 +66,7 @@ from repro.core.geom import box_axis_factors, box_outer
 from repro.core.precision import resolve_policy
 from repro.kernels import autotune as _autotune
 from repro.kernels import nekbone_ax as _ax
+from repro.obs import trace as _trace
 
 __all__ = ["cg_fused_fixed_iters", "cg_fused_v2_fixed_iters",
            "cg_fused_sharded_fixed_iters", "cg_ir_fixed_iters"]
@@ -162,26 +163,28 @@ def cg_fused_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     """
     from repro.kernels import ops as kernel_ops
 
-    policy = resolve_policy(precision, b.dtype)
-    b = jnp.asarray(b, policy.storage_dtype)
-    E = b.shape[0]
-    n = b.shape[-1]
-    if interpret is None:
-        interpret = kernel_ops.default_interpret()
-    if block_e is None:
-        block_e = _autotune.pick_block_e(E, n, b.dtype,
-                                         acc_dtype=policy.accum)
-    while E % block_e:
-        block_e //= 2                  # fused path avoids padding: divisor
-    block_e = max(block_e, 1)
+    with _trace.span("driver.prepare"):
+        policy = resolve_policy(precision, b.dtype)
+        b = jnp.asarray(b, policy.storage_dtype)
+        E = b.shape[0]
+        n = b.shape[-1]
+        if interpret is None:
+            interpret = kernel_ops.default_interpret()
+        if block_e is None:
+            block_e = _autotune.pick_block_e(E, n, b.dtype,
+                                             acc_dtype=policy.accum)
+        while E % block_e:
+            block_e //= 2              # fused path avoids padding: divisor
+        block_e = max(block_e, 1)
 
-    n3 = n ** 3
-    # operator data (D, metric) in the policy's op-storage dtype: refined
-    # policies keep it wide — rounding A itself floors the refinement.
-    D = jnp.asarray(D, policy.op_storage_dtype)
-    g2 = jnp.asarray(g, policy.op_storage_dtype).reshape(E, 6, n3)
-    mask2 = jnp.asarray(mask, b.dtype).reshape(E, n3)
-    c = jnp.asarray(c, b.dtype)
+        n3 = n ** 3
+        # operator data (D, metric) in the policy's op-storage dtype:
+        # refined policies keep it wide — rounding A itself floors the
+        # refinement.
+        D = jnp.asarray(D, policy.op_storage_dtype)
+        g2 = jnp.asarray(g, policy.op_storage_dtype).reshape(E, 6, n3)
+        mask2 = jnp.asarray(mask, b.dtype).reshape(E, n3)
+        c = jnp.asarray(c, b.dtype)
     return SolveResult.from_cg(
         _cg_fused(b, D, D.T, g2, mask2, c, n=n, grid=tuple(grid),
                   niter=niter, block_e=block_e, interpret=interpret,
@@ -200,22 +203,24 @@ def _check_box_fields(grid, n, mask, c) -> None:
     The v2 kernels *rebuild* both from per-axis factors
     (``geom.box_axis_factors``), so silently accepting a different mask or
     weight would compute a different problem.  Skipped under tracing
-    (concrete mesh fields are checked at build time).
+    (concrete mesh fields are checked at build time).  Host work of every
+    call: the ``driver.validate`` span.
     """
-    (mx, my, mz), (cx, cy, cz) = box_axis_factors(grid, n)
-    for name, field, want in (
-            ("mask", mask, box_outer(mz, my, mx).reshape(-1, n, n, n)),
-            ("c", c, box_outer(cz, cy, cx).reshape(-1, n, n, n))):
-        if field is None:
-            continue
-        try:
-            got = np.asarray(field, np.float64)
-        except jax.errors.TracerArrayConversionError:
-            continue
-        if got.shape != want.shape or not np.array_equal(got, want):
-            raise ValueError(
-                f"pallas_fused_cg_v2 requires the structured box {name} "
-                "(per-axis factorizable); supplied field differs")
+    with _trace.span("driver.validate"):
+        (mx, my, mz), (cx, cy, cz) = box_axis_factors(grid, n)
+        for name, field, want in (
+                ("mask", mask, box_outer(mz, my, mx).reshape(-1, n, n, n)),
+                ("c", c, box_outer(cz, cy, cx).reshape(-1, n, n, n))):
+            if field is None:
+                continue
+            try:
+                got = np.asarray(field, np.float64)
+            except jax.errors.TracerArrayConversionError:
+                continue
+            if got.shape != want.shape or not np.array_equal(got, want):
+                raise ValueError(
+                    f"pallas_fused_cg_v2 requires the structured box {name}"
+                    " (per-axis factorizable); supplied field differs")
 
 
 def _v2_iter(x2, r2, p2, rtz, beta, *, D, Dt, g3, mx, my, mz, cx, cy, cz,
@@ -344,29 +349,31 @@ def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
     """
     from repro.kernels import ops as kernel_ops
 
-    policy = resolve_policy(precision, b.dtype)
-    b = jnp.asarray(b, policy.storage_dtype)
-    E = b.shape[0]
-    n = b.shape[-1]
-    grid = tuple(grid)
-    if interpret is None:
-        interpret = kernel_ops.default_interpret()
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_slab_config(
-            grid, n, b.dtype, acc_dtype=policy.accum)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, b.dtype,
-                                    acc_dtype=policy.accum)
-    grid_order = "parallel" if grid_order is None else grid_order
+    with _trace.span("driver.prepare"):
+        policy = resolve_policy(precision, b.dtype)
+        b = jnp.asarray(b, policy.storage_dtype)
+        E = b.shape[0]
+        n = b.shape[-1]
+        grid = tuple(grid)
+        if interpret is None:
+            interpret = kernel_ops.default_interpret()
+        if sz is None and grid_order is None:
+            sz, grid_order = _autotune.pick_slab_config(
+                grid, n, b.dtype, acc_dtype=policy.accum)
+        elif sz is None:
+            sz = _autotune.pick_slab_sz(grid, n, b.dtype,
+                                        acc_dtype=policy.accum)
+        grid_order = "parallel" if grid_order is None else grid_order
 
-    _check_box_fields(grid, n, mask, c)
-    (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(grid, n,
-                                                             b.dtype)
-    # operator data (D, metric) in the policy's op-storage dtype: refined
-    # policies keep it wide — rounding A itself floors the refinement.
-    D = jnp.asarray(D, policy.op_storage_dtype)
-    g3 = kernel_ops.diag_metric(
-        jnp.asarray(g, policy.op_storage_dtype), E, n)
+        _check_box_fields(grid, n, mask, c)
+        (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(
+            grid, n, b.dtype)
+        # operator data (D, metric) in the policy's op-storage dtype:
+        # refined policies keep it wide — rounding A itself floors the
+        # refinement.
+        D = jnp.asarray(D, policy.op_storage_dtype)
+        g3 = kernel_ops.diag_metric(
+            jnp.asarray(g, policy.op_storage_dtype), E, n)
     return SolveResult.from_cg(
         _cg_fused_v2(b, D, D.T, g3, mx, my, mz, cx, cy, cz, n=n,
                      grid=grid, niter=niter, sz=sz, interpret=interpret,
@@ -603,15 +610,12 @@ def cg_ir_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     x = jnp.zeros_like(b)
     r = b
     norms = [jnp.sqrt(jnp.abs(jnp.sum(b * c_hi * b)))]
-    # tracing: recorder read once per solve; one `is None` test per
-    # sweep when off, a timed "ir.sweep" span per refinement when on.
+    # tracing: a timed "ir.sweep" span per refinement when on.
     from repro.obs import trace as _trace
 
-    rec = _trace.active()
     for sweep in range(outer_iters):
-        with (rec.span("ir.sweep", sweep=sweep, variant=variant,
-                       inner_iters=inner_iters)
-              if rec is not None else _trace.NULL_SPAN):
+        with _trace.span("ir.sweep", sweep=sweep, variant=variant,
+                         inner_iters=inner_iters):
             # inf-norm scaling: the downcast spends the narrow mantissa
             # on the digits that are still wrong, not on the
             # already-converged scale.

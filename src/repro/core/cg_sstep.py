@@ -368,11 +368,8 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     hist: list[float] = []
     rcr_last = None
     it = 0
-    # tracing: the recorder is read once per solve; when off the loop
-    # pays one local `is None` test per cycle and allocates nothing.
     from repro.obs import trace as _trace
 
-    rec = _trace.active()
     while it < niter:
         # per-cycle tolerance check on the previous update kernel's stored-
         # residual reduction — the same quantity the next cycle's Gram
@@ -382,9 +379,8 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                 and abs(float(rcr_last)) <= tol2:
             break
         m = min(s, niter - it)
-        with (rec.span("sstep.cycle", it=it, s=s)
-              if rec is not None else _trace.NULL_SPAN):
-            with _trace.profiler_annotation("nekbone.sstep_powers"):
+        with _trace.span("sstep.cycle", it=it, s=s):
+            with _trace.span("sstep.powers"):
                 basis, gram_b = _powers_call(
                     p2, r2, D_op, D_op.T, gext, mx, my, mzext, cx, cy,
                     cz, inv_theta, n=n, grid=grid, sz=sz, s=s,
@@ -400,7 +396,7 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                 break
             hist.extend(np.sqrt(np.abs(v)) for v in rtzs)
             coef = jnp.asarray(coef_np, acc)
-            with _trace.profiler_annotation("nekbone.sstep_update"):
+            with _trace.span("sstep.update"):
                 x2, r2, p2, rcr_b = _ax.nekbone_sstep_update_pallas(
                     x2, p2, r2, basis, coef, cx, cy, cz, n=n, grid=grid,
                     sz=sz, s=s, interpret=interpret,

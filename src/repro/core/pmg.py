@@ -375,9 +375,7 @@ def pmg_vcycle_reference(spec: PMGPrecond, *, D, g,
         # only exposes its levels at setup (precond._dispatch).
         from repro.obs import trace as _trace
 
-        rec = _trace.active()
-        with (rec.span("pmg.vcycle", level=lev, n=ns[lev])
-              if rec is not None else _trace.NULL_SPAN):
+        with _trace.span("pmg.vcycle", level=lev, n=ns[lev]):
             if lev == L - 1:
                 Dc, gc, mc, cc = levels[lev]
                 return coarse_solve_fixed(r, Dc, gc, grid, mc, cc,

@@ -70,6 +70,7 @@ from repro.core.geom import box_axis_factors, box_outer
 from repro.core.precision import resolve_policy
 from repro.kernels import autotune as _autotune
 from repro.kernels import nekbone_ax as _ax
+from repro.obs import trace as _trace
 
 __all__ = ["CHEB_DEFAULT_K", "PMG_DEFAULT_K", "JacobiPrecond",
            "ChebyshevPrecond", "PMGPrecond",
@@ -772,9 +773,6 @@ def _dispatch(b, precond, tol2, max_iter, *, policy, n, grid, sz, interpret,
         return _pcg_cheb(b, D_op, D_op.T, g3, mx, my, mz, cx, cy, cz,
                          coef, tol2, sz_c=sz_c, k=precond.k, **common)
     if isinstance(precond, _pmg.PMGPrecond):
-        from repro.obs import trace as _trace
-
-        rec = _trace.active()
         ns_t = precond.ns
         # per-level slab splits: the Az/interp kernels at each degree get
         # their own ``pmg:<level>`` autotune key; the level-0 smoother may
@@ -786,9 +784,8 @@ def _dispatch(b, precond, tol2, max_iter, *, policy, n, grid, sz, interpret,
         szs = []
         cheb_szs = []
         for lev in range(len(ns_t) - 1):
-            with (rec.span("pmg.vcycle.level", level=lev, n=ns_t[lev],
-                           k=precond.k)
-                  if rec is not None else _trace.NULL_SPAN):
+            with _trace.span("pmg.vcycle.level", level=lev, n=ns_t[lev],
+                             k=precond.k):
                 szs.append(_autotune.pick_slab_sz(
                     grid, ns_t[lev], b.dtype, acc_dtype=policy.accum,
                     precond=f"pmg:{lev}"))
@@ -801,15 +798,12 @@ def _dispatch(b, precond, tol2, max_iter, *, policy, n, grid, sz, interpret,
         levels = _pmg.pmg_level_pytree(precond, grid,
                                        policy.op_storage_dtype.name,
                                        policy.accum)
-        with (rec.span("pmg.dispatch", levels=len(ns_t),
-                       coarse_n=ns_t[-1])
-              if rec is not None else _trace.NULL_SPAN):
-            with _trace.profiler_annotation("nekbone.pcg_pmg"):
-                return _pcg_pmg(b, D_op, D_op.T, g3, mx, my, mz, cx, cy,
-                                cz, levels, tol2, ns=ns_t, szs=szs,
-                                cheb_szs=cheb_szs, k=precond.k,
-                                coarse_iters=precond.coarse_iters,
-                                **common)
+        with _trace.span("pmg.dispatch", levels=len(ns_t),
+                         coarse_n=ns_t[-1]):
+            return _pcg_pmg(b, D_op, D_op.T, g3, mx, my, mz, cx, cy, cz,
+                            levels, tol2, ns=ns_t, szs=szs,
+                            cheb_szs=cheb_szs, k=precond.k,
+                            coarse_iters=precond.coarse_iters, **common)
     raise TypeError(f"unsupported preconditioner {precond!r}")
 
 
@@ -841,12 +835,16 @@ def pcg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
     apply kernel's (defaults: autotuned — deeper polynomials want larger
     ``cheb_sz``, the halo is ``8k/sz`` streams, cost.cheb_halo_streams).
     """
-    (policy, b, n, grid, sz, grid_order, interpret, m_factors,
-     c_factors, D_op, g3) = _prepare(b, D, g, grid, mask, c, sz, interpret,
-                                     precision, precond, grid_order)
-    # specs built by name use the caller's (full-precision) operator data;
-    # the drivers cast the resulting fields to the policy's op-storage.
-    precond = _resolve_precond(precond, D=D, g=g, grid=grid, mask=mask, c=c)
+    with _trace.span("driver.prepare"):
+        (policy, b, n, grid, sz, grid_order, interpret, m_factors,
+         c_factors, D_op, g3) = _prepare(b, D, g, grid, mask, c, sz,
+                                         interpret, precision, precond,
+                                         grid_order)
+        # specs built by name use the caller's (full-precision) operator
+        # data; the drivers cast the resulting fields to the policy's
+        # op-storage.
+        precond = _resolve_precond(precond, D=D, g=g, grid=grid, mask=mask,
+                                   c=c)
     # tol2 = -1 sentinel: |rtz| > -1 always holds, so exactly ``niter``
     # iterations run — the tol-driven path's trajectory continued.
     return SolveResult.from_cg(
@@ -879,10 +877,13 @@ def cg_fused_tol(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     Args are :func:`pcg_fused_v2_fixed_iters`'s with ``tol``/``max_iter``
     replacing ``niter``; ``precond=None`` runs the plain v2 pipeline.
     """
-    (policy, b, n, grid, sz, grid_order, interpret, m_factors,
-     c_factors, D_op, g3) = _prepare(b, D, g, grid, mask, c, sz, interpret,
-                                     precision, precond, grid_order)
-    precond = _resolve_precond(precond, D=D, g=g, grid=grid, mask=mask, c=c)
+    with _trace.span("driver.prepare"):
+        (policy, b, n, grid, sz, grid_order, interpret, m_factors,
+         c_factors, D_op, g3) = _prepare(b, D, g, grid, mask, c, sz,
+                                         interpret, precision, precond,
+                                         grid_order)
+        precond = _resolve_precond(precond, D=D, g=g, grid=grid, mask=mask,
+                                   c=c)
     return SolveResult.from_cg(
         _dispatch(b, precond, float(tol) ** 2, max_iter, policy=policy,
                   n=n, grid=grid, sz=sz, interpret=interpret,

@@ -233,13 +233,14 @@ def solve_case(case, f: jnp.ndarray, *, b: int | None = None,
     f_in = f[0] if (batched and b == 1) else f
     from repro.obs import trace as _trace
 
-    rec = _trace.active()
-    if rec is None:            # tracing off: the plain dispatch, nothing else
-        res = _solve_resolved(case, f_in, b=b, niter=niter, tol=tol,
-                              max_iter=max_iter, pc_name=pc_name)
-    else:
-        res = _traced_solve(rec, case, f_in, b=b, niter=niter, tol=tol,
-                            max_iter=max_iter, pc_name=pc_name)
+    route = route_name(case, b=b, niter=niter, pc_name=pc_name)
+    kw = dict(b=b, niter=niter, tol=tol, max_iter=max_iter, pc_name=pc_name)
+    with _trace.span("solve", route=route, b=b, niter=niter,
+                     precond=pc_name, ax_impl=getattr(case, "ax_impl", None)):
+        if _trace.active() is None:    # no recorder: the plain dispatch
+            res = REGISTRY[route](case, f_in, **kw)
+        else:
+            res = _recorded_solve(case, f_in, route, **kw)
     # a batched rhs always comes back batched, even at b=1 through a
     # single-RHS route (callers index res.x[j] uniformly).
     if batched and b == 1 and res.x.ndim == 4:
@@ -257,13 +258,13 @@ def _solve_resolved(case, f, *, b, niter, tol, max_iter, pc_name):
                           max_iter=max_iter, pc_name=pc_name)
 
 
-def _traced_solve(rec, case, f, *, b, niter, tol, max_iter, pc_name):
-    """The tracing-on dispatch: same :func:`_solve_resolved` call (so
-    the solve output is bitwise identical), wrapped in a ``solve`` span
-    with a :class:`~repro.obs.metrics.SolveTelemetry` attached to the
-    result's non-pytree ``telemetry`` field.  The ``block_until_ready``
-    and the iters/rtol device reads in ``capture_solve`` are syncs the
-    tracing-off path never pays."""
+def _recorded_solve(case, f, route, *, b, niter, tol, max_iter, pc_name):
+    """The dispatch under a recorder: the same registry call (so the
+    solve output is bitwise identical), with a
+    :class:`~repro.obs.metrics.SolveTelemetry` attached to the result's
+    non-pytree ``telemetry`` field.  The ``block_until_ready`` and the
+    iters/rtol device reads in ``capture_solve`` are syncs the path
+    without a recorder never pays."""
     import dataclasses
 
     import jax
@@ -271,18 +272,16 @@ def _traced_solve(rec, case, f, *, b, niter, tol, max_iter, pc_name):
     from repro.kernels import autotune as _autotune
     from repro.kernels.timing import stopwatch
     from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as _trace
 
-    route = route_name(case, b=b, niter=niter, pc_name=pc_name)
     at0 = _autotune.cache_stats()
     sw = stopwatch()
-    with rec.span("solve", route=route, b=b, niter=niter,
-                  precond=pc_name, ax_impl=getattr(case, "ax_impl", None)):
-        res = _solve_resolved(case, f, b=b, niter=niter, tol=tol,
-                              max_iter=max_iter, pc_name=pc_name)
-        jax.block_until_ready(res.x)
+    res = REGISTRY[route](case, f, b=b, niter=niter, tol=tol,
+                          max_iter=max_iter, pc_name=pc_name)
+    jax.block_until_ready(res.x)
     wall = sw.us()
     at1 = _autotune.cache_stats()
-    rec.count("solves")
+    _trace.count("solves")
     tel = obs_metrics.capture_solve(
         res, route=route, b=b, niter=niter,
         tol=None if niter is not None else tol, wall_us=wall,

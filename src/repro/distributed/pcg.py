@@ -311,13 +311,11 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
     # boundary is this dispatch, recorded as a single span.
     from repro.obs import trace as _trace
 
-    rec = _trace.active()
     if isinstance(precond, JacobiPrecond):
         invd2 = lanes(jnp.asarray(precond.invdiag,
                                   policy.op_storage_dtype).reshape(E, n3))
-        with (rec.span("pcg.sharded_dispatch", precond="jacobi",
-                       ndev=ndev)
-              if rec is not None else _trace.NULL_SPAN):
+        with _trace.span("pcg.sharded_dispatch", precond="jacobi",
+                         ndev=ndev):
             x2, kk, hist = _jacobi_call(b2, invd2, *common, tol2,
                                         **statics)
     elif isinstance(precond, ChebyshevPrecond):
@@ -340,9 +338,8 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
                 mesh, P(None, None, None, axis_name)))(g3l)
         mzext = shard(_ax.sstep_extend_zfactor(mz, sz_c, k))
         coef = rep(jnp.asarray(precond.scalars(), policy.accum_dtype))
-        with (rec.span("pcg.sharded_dispatch", precond=f"cheb{k}",
-                       ndev=ndev)
-              if rec is not None else _trace.NULL_SPAN):
+        with _trace.span("pcg.sharded_dispatch", precond=f"cheb{k}",
+                         ndev=ndev):
             x2, kk, hist = _cheb_call(b2, *common, gext, mzext, coef,
                                       tol2, sz_c=sz_c, k=k, **statics)
     else:

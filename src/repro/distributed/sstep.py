@@ -356,11 +356,8 @@ def cg_sstep_sharded_fixed_iters(
     rcr_parts = None
     rcr_last = None
     it = 0
-    # tracing: recorder read once per solve; one `is None` test per
-    # sharded cycle when off.
     from repro.obs import trace as _trace
 
-    rec = _trace.active()
     while it < niter:
         if rcr_parts is not None:
             # the update kernel's rcr partials come back per-shard (no
@@ -370,8 +367,7 @@ def cg_sstep_sharded_fixed_iters(
             if tol2 is not None and abs(rcr_last) <= tol2:
                 break
         m = min(s, niter - it)
-        with (rec.span("sstep.sharded_cycle", it=it, s=s, ndev=ndev)
-              if rec is not None else _trace.NULL_SPAN):
+        with _trace.span("sstep.sharded_cycle", it=it, s=s, ndev=ndev):
             basis, G = _cycle_call(p2, r2, D_op, Dt_op, gext, mzext, mx,
                                    my, cx, cy, cz, inv_theta, **statics)
             Gh = np.asarray(G, np.dtype(policy.gram))

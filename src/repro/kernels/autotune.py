@@ -26,13 +26,20 @@ Selection strategy (all modes):
 * **Measurement** (on a TPU backend, or with an injected ``measure``):
   times the real kernel over the candidates and keeps the fastest — the
   empirical analog of the paper's per-architecture tuning sweep (its
-  Table 1 re-tunes the CUDA kernel per GPU generation).
+  Table 1 re-tunes the CUDA kernel per GPU generation).  The estimator
+  (:func:`_sweep`): :data:`SWEEP_ROUNDS` rounds interleaved across the
+  candidates, each round one chain of back-to-back kernel calls lasting
+  at least :data:`SWEEP_CHAIN_S` and ended by one sync, the median round
+  per candidate; a candidate displaces the first in the fixed order only
+  if it is faster by more than :data:`SWEEP_MARGIN`.
 
 Results are memoized in a process-wide cache and — for *measured*
 selections — persisted as JSON under ``$REPRO_CACHE_DIR`` (default
-``~/.cache/repro``), so repeated benchmark runs skip the re-measuring
-sweep entirely.  The disk cache is corrupt-file tolerant: an unreadable or
-malformed file is ignored and overwritten on the next measured pick.
+``~/.cache/repro``), together with every candidate's measured seconds,
+so repeated benchmark runs skip the re-measuring sweep entirely and the
+margin that decided a pick can be read back from the file.  The disk
+cache is corrupt-file tolerant: an unreadable or malformed file is
+ignored and overwritten on the next measured pick.
 ``clear_cache`` wipes both layers (pass ``disk=False`` to keep the file).
 """
 from __future__ import annotations
@@ -54,12 +61,15 @@ __all__ = ["vmem_block_e", "pick_block_e", "candidate_blocks",
            "candidate_slab_sizes_cheb", "pick_slab_sz_cheb",
            "candidate_configs", "pick_slab_config", "pick_sstep_config",
            "pick_cheb_config", "pick_pipeline", "AUTO_V2_MIN_E",
+           "SWEEP_ROUNDS", "SWEEP_CHAIN_S", "SWEEP_MARGIN",
            "clear_cache", "cache_info", "cache_path", "cache_stats"]
 
 _CACHE: dict[tuple, object] = {}
-# Disk-cache schema: 2 = joint configs are (sz, grid_order) pairs.
-_DISK_VERSION = 2
-_MEASURED: set[tuple] = set()     # keys whose value came from a timing sweep
+# Disk-cache schema: 2 = joint configs are (sz, grid_order) pairs;
+# 3 = each entry also holds every candidate's measured seconds.
+_DISK_VERSION = 3
+# keys whose value came from a timing sweep -> {config name: seconds}
+_SECONDS: dict[tuple, dict[str, float]] = {}
 _LOCK = threading.Lock()
 _DISK_LOADED = False
 
@@ -173,7 +183,9 @@ def _load_disk_locked() -> None:
                 ok = val >= 1
             if ok:
                 _CACHE.setdefault(key, val)
-                _MEASURED.add(key)     # the file only ever holds measured picks
+                # the file only ever holds measured picks
+                _SECONDS.setdefault(key, {str(c): float(t) for c, t in
+                                          item["seconds"].items()})
     except Exception:
         pass
 
@@ -181,16 +193,18 @@ def _load_disk_locked() -> None:
 def _save_disk_locked() -> None:
     """Atomically rewrite the disk cache (caller holds lock).
 
-    Only *measured* selections are written: heuristic picks are a pure
-    function of the budget constants and must recompute when those change.
+    Only *measured* selections are written, each with its candidates'
+    seconds: heuristic picks are a pure function of the budget constants
+    and must recompute when those change.
     """
     try:
         path = cache_path()
         path.parent.mkdir(parents=True, exist_ok=True)
         entries = [{"key": list(k),
-                    "value": list(v) if isinstance(v, tuple) else v}
+                    "value": list(v) if isinstance(v, tuple) else v,
+                    "seconds": _SECONDS[k]}
                    for k, v in sorted(_CACHE.items(), key=lambda kv: str(kv[0]))
-                   if k in _MEASURED]
+                   if k in _SECONDS]
         payload = {"version": _DISK_VERSION, "entries": entries}
         tmp = path.with_name(path.name + ".tmp")
         tmp.write_text(json.dumps(payload, indent=1))
@@ -215,7 +229,8 @@ def _cached_pick(key: tuple, pick: Callable[[], tuple]):
 
     ``pick`` runs only on a cache miss — it may build an expensive measure
     closure (synthetic operands, device transfers), so the warm path must
-    never touch it — and returns ``(best, measured)``.
+    never touch it — and returns ``(best, seconds)``: every candidate's
+    measured seconds, or ``None`` for a heuristic pick.
     """
     from repro.obs import trace
 
@@ -228,14 +243,111 @@ def _cached_pick(key: tuple, pick: Callable[[], tuple]):
         _STATS["misses"] += 1
     trace.count("autotune.cache_misses")
 
-    best, measured = pick()
+    best, seconds = pick()
 
     with _LOCK:
         _CACHE.setdefault(key, best)
-        if measured:
-            _MEASURED.add(key)
+        if seconds is not None:
+            _SECONDS.setdefault(key, seconds)
             _save_disk_locked()
         return _CACHE[key]
+
+
+# ---------------------------------------------------------------------------
+# the measured sweep's estimator
+# ---------------------------------------------------------------------------
+
+SWEEP_ROUNDS = 5        # timed rounds per candidate, interleaved
+SWEEP_CHAIN_S = 0.02    # a round's chain of kernel calls lasts at least this
+SWEEP_MARGIN = 0.02     # a candidate must beat the first by more than this
+
+
+def _config_name(cfg) -> str:
+    """``4``, ``4/arbitrary``, ``pallas_fused_cg``: a candidate as the
+    disk cache and the spans name it."""
+    return "/".join(map(str, cfg)) if isinstance(cfg, tuple) else str(cfg)
+
+
+def _sweep(key: tuple, cands: list, timed) -> tuple:
+    """Pick among ``cands`` (the fixed order, established choice first)
+    by ``timed(*config) -> seconds``, one reading per call.
+
+    :data:`SWEEP_ROUNDS` rounds, each timing every candidate once, so slow
+    drift of the machine falls on all candidates alike; the median round
+    per candidate is its time.  The first candidate is kept unless the
+    fastest beats it by more than :data:`SWEEP_MARGIN`, so a tie within
+    the noise does not move the pick between processes.  Returns
+    ``(best, {config name: seconds})``.
+    """
+    from repro.obs import trace
+
+    rounds = [[] for _ in cands]
+    with trace.span("autotune.sweep", key=_config_name(key),
+                    candidates=len(cands)):
+        for _ in range(SWEEP_ROUNDS):
+            for cfg, ts in zip(cands, rounds):
+                with trace.span("autotune.measure",
+                                config=_config_name(cfg)) as sp:
+                    t = timed(*cfg) if isinstance(cfg, tuple) else timed(cfg)
+                    sp.set(seconds=t)
+                ts.append(t)
+    med = [_timing.median(ts) for ts in rounds]
+    best = min(range(len(cands)), key=med.__getitem__)
+    if not med[best] < med[0] * (1.0 - SWEEP_MARGIN):
+        best = 0
+    return cands[best], {_config_name(c): t for c, t in zip(cands, med)}
+
+
+# kernel calls per dispatch of a sweep's device loop: enough that the
+# device, not the host's dispatch of the next loop, sets the pace (a lone
+# jitted call took about 0.6 ms to dispatch on a v5e host, longer than a
+# 0.35-ms slab kernel runs).
+_LOOP_CALLS = 16
+
+
+def _device_loop(fn, args: tuple):
+    """``(f, calls)``: ``f()`` runs ``fn(*args)`` ``calls`` =
+    :data:`_LOOP_CALLS` times back to back in one jitted loop on the
+    device.  Each call's smallest operand
+    takes a zero computed from the previous call's smallest output, so no
+    call can be hoisted out of the loop or merged with another."""
+    import jax.lax as lax
+
+    i = min(range(len(args)), key=lambda j: args[j].size)
+
+    @jax.jit
+    def loop(*a):
+        def body(_, t):
+            b = list(a)
+            b[i] = b[i] + t.astype(b[i].dtype)
+            out = min(jax.tree_util.tree_leaves(fn(*b)), key=lambda x: x.size)
+            return (jnp.sum(out) * 0).astype(t.dtype)
+
+        return lax.fori_loop(0, _LOOP_CALLS, body,
+                             jnp.zeros((), jnp.float32))
+
+    return (lambda: loop(*args)), _LOOP_CALLS
+
+
+def _chained(make, *, timer=None, sync=None):
+    """A sweep's ``timed`` over real kernel calls: ``make(*config)`` gives
+    ``(f, calls)``, a zero-argument dispatch of ``calls`` kernel calls of
+    one config (:func:`_device_loop`).  A config's first round compiles it
+    and sizes a chain of back-to-back dispatches
+    (:func:`kernels.timing.chain_length`) to :data:`SWEEP_CHAIN_S`; every
+    round times one chain, per kernel call."""
+    chains = {}
+
+    def timed(*cfg):
+        if cfg not in chains:
+            f, calls = make(*cfg)
+            chains[cfg] = (f, calls, _timing.chain_length(
+                f, seconds=SWEEP_CHAIN_S, timer=timer, sync=sync))
+        f, calls, chain = chains[cfg]
+        return _timing.measure(f, reps=1, warmup=0, timer=timer, sync=sync,
+                               chain=chain) / calls
+
+    return timed
 
 
 # ---------------------------------------------------------------------------
@@ -281,15 +393,12 @@ def _default_measure(E: int, n: int, dtype,
     D = jnp.asarray(derivative_matrix(n), dtype)
     Dt = D.T
 
-    def measure(block_e: int) -> float:
-        def f():
-            return _ax.nekbone_ax_pallas(u, D, Dt, g, n=n,
-                                         block_e=block_e, interpret=False,
-                                         acc_dtype=acc_dtype)
+    def make(block_e: int):
+        return _device_loop(lambda *a: _ax.nekbone_ax_pallas(
+            *a, n=n, block_e=block_e, interpret=False, acc_dtype=acc_dtype),
+            (u, D, Dt, g))
 
-        return _timing.measure(f, reps=3, warmup=1)
-
-    return measure
+    return _chained(make)
 
 
 def _acc_name(dtype, acc_dtype) -> str:
@@ -335,8 +444,8 @@ def pick_block_e(E: int, n: int, dtype=jnp.float32, *,
         if m is None and backend == "tpu":
             m = _default_measure(E, n, dtype, acc_dtype)
         if m is None:
-            return cands[0], False
-        return min(cands, key=m), True
+            return cands[0], None
+        return _sweep(key, cands, m)
 
     return _cached_pick(key, pick)
 
@@ -383,16 +492,14 @@ def _default_measure_slab(grid: tuple[int, int, int], n: int, dtype,
     my = jnp.asarray(axis_mask_factor(ey, n), dtype)
     mz = jnp.asarray(axis_mask_factor(ez, n), dtype)
     beta = jnp.zeros((1, 1), _ax._accum(jnp.dtype(dtype), acc_dtype))
+    Dt = D.T
 
-    def measure(sz: int, grid_order: str = "parallel") -> float:
-        def f():
-            return _ax.nekbone_ax_slab_pallas(
-                p, r, D, D.T, g3, mx, my, mz, beta, n=n, grid=grid, sz=sz,
-                interpret=False, acc_dtype=acc_dtype, grid_order=grid_order)
+    def make(sz: int, grid_order: str = "parallel"):
+        return _device_loop(lambda *a: _ax.nekbone_ax_slab_pallas(
+            *a, n=n, grid=grid, sz=sz, interpret=False, acc_dtype=acc_dtype,
+            grid_order=grid_order), (p, r, D, Dt, g3, mx, my, mz, beta))
 
-        return _timing.measure(f, reps=3, warmup=1)
-
-    return measure
+    return _chained(make)
 
 
 def _default_measure_slab_block(grid: tuple[int, int, int], n: int, dtype,
@@ -416,17 +523,14 @@ def _default_measure_slab_block(grid: tuple[int, int, int], n: int, dtype,
     my = jnp.asarray(axis_mask_factor(ey, n), dtype)
     mz = jnp.asarray(axis_mask_factor(ez, n), dtype)
     beta = jnp.zeros((1, nrhs), _ax._accum(jnp.dtype(dtype), acc_dtype))
+    Dt = D.T
 
-    def measure(sz: int, grid_order: str = "parallel") -> float:
-        def f():
-            return _ax.nekbone_ax_slab_block_pallas(
-                p3, r3, D, D.T, g3, mx, my, mz, beta, n=n, grid=grid,
-                sz=sz, interpret=False, acc_dtype=acc_dtype,
-                grid_order=grid_order)
+    def make(sz: int, grid_order: str = "parallel"):
+        return _device_loop(lambda *a: _ax.nekbone_ax_slab_block_pallas(
+            *a, n=n, grid=grid, sz=sz, interpret=False, acc_dtype=acc_dtype,
+            grid_order=grid_order), (p3, r3, D, Dt, g3, mx, my, mz, beta))
 
-        return _timing.measure(f, reps=3, warmup=1)
-
-    return measure
+    return _chained(make)
 
 
 def pick_slab_sz(grid: tuple[int, int, int], n: int, dtype=jnp.float32, *,
@@ -473,8 +577,8 @@ def pick_slab_sz(grid: tuple[int, int, int], n: int, dtype=jnp.float32, *,
             else:
                 m = _default_measure_slab(grid, n, dtype, acc_dtype)
         if m is None:
-            return cands[0], False
-        return min(cands, key=m), True
+            return cands[0], None
+        return _sweep(key, cands, m)
 
     return _cached_pick(key, pick)
 
@@ -522,22 +626,19 @@ def _default_measure_sstep(grid: tuple[int, int, int], n: int, s: int,
     cz = jnp.asarray(cz, dtype)
     acc = _ax._accum(jnp.dtype(dtype), acc_dtype)
     inv_theta = jnp.ones((1, 1), acc)
+    Dt = D.T
 
-    def measure(sz: int, grid_order: str = "parallel") -> float:
+    def make(sz: int, grid_order: str = "parallel"):
         pext = _ax.sstep_extend_field(p2, grid, sz, s)
         rext = _ax.sstep_extend_field(r2, grid, sz, s)
         gext = _ax.sstep_extend_field(g3, grid, sz, s)
         mzext = _ax.sstep_extend_zfactor(jnp.asarray(mz, dtype), sz, s)
+        return _device_loop(lambda *a: _ax.nekbone_ax_powers_pallas(
+            *a, n=n, grid=grid, sz=sz, s=s, interpret=False,
+            acc_dtype=acc_dtype, grid_order=grid_order),
+            (pext, rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, inv_theta))
 
-        def f():
-            return _ax.nekbone_ax_powers_pallas(
-                pext, rext, D, D.T, gext, mx, my, mzext, cx, cy, cz,
-                inv_theta, n=n, grid=grid, sz=sz, s=s, interpret=False,
-                acc_dtype=acc_dtype, grid_order=grid_order)
-
-        return _timing.measure(f, reps=3, warmup=1)
-
-    return measure
+    return _chained(make)
 
 
 def pick_slab_sz_sstep(grid: tuple[int, int, int], n: int, s: int,
@@ -567,8 +668,8 @@ def pick_slab_sz_sstep(grid: tuple[int, int, int], n: int, s: int,
         if m is None and backend == "tpu":
             m = _default_measure_sstep(grid, n, s, dtype, acc_dtype)
         if m is None:
-            return cands[0], False
-        return min(cands, key=m), True
+            return cands[0], None
+        return _sweep(key, cands, m)
 
     return _cached_pick(key, pick)
 
@@ -616,21 +717,18 @@ def _default_measure_cheb(grid: tuple[int, int, int], n: int, k: int,
     cz = jnp.asarray(cz, dtype)
     acc = _ax._accum(jnp.dtype(dtype), acc_dtype)
     coef = jnp.ones((k + 1, 2), acc)
+    Dt = D.T
 
-    def measure(sz: int, grid_order: str = "parallel") -> float:
+    def make(sz: int, grid_order: str = "parallel"):
         rext = _ax.sstep_extend_field(r2, grid, sz, k)
         gext = _ax.sstep_extend_field(g3, grid, sz, k)
         mzext = _ax.sstep_extend_zfactor(jnp.asarray(mz, dtype), sz, k)
+        return _device_loop(lambda *a: _ax.nekbone_cheb_apply_pallas(
+            *a, n=n, grid=grid, sz=sz, k=k, interpret=False,
+            acc_dtype=acc_dtype, grid_order=grid_order),
+            (rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, coef))
 
-        def f():
-            return _ax.nekbone_cheb_apply_pallas(
-                rext, D, D.T, gext, mx, my, mzext, cx, cy, cz, coef,
-                n=n, grid=grid, sz=sz, k=k, interpret=False,
-                acc_dtype=acc_dtype, grid_order=grid_order)
-
-        return _timing.measure(f, reps=3, warmup=1)
-
-    return measure
+    return _chained(make)
 
 
 def pick_slab_sz_cheb(grid: tuple[int, int, int], n: int, k: int,
@@ -660,8 +758,8 @@ def pick_slab_sz_cheb(grid: tuple[int, int, int], n: int, k: int,
         if m is None and backend == "tpu":
             m = _default_measure_cheb(grid, n, k, dtype, acc_dtype)
         if m is None:
-            return cands[0], False
-        return min(cands, key=m), True
+            return cands[0], None
+        return _sweep(key, cands, m)
 
     return _cached_pick(key, pick)
 
@@ -695,9 +793,8 @@ def _pick_config(key: tuple, sz_cands: list[int], measure,
         if m is None and backend == "tpu":
             m = default_measure_factory()
         if m is None:
-            return (sz_cands[0], "parallel"), False
-        cands = candidate_configs(sz_cands)
-        return min(cands, key=lambda c: m(*c)), True
+            return (sz_cands[0], "parallel"), None
+        return _sweep(key, candidate_configs(sz_cands), m)
 
     return _cached_pick(key, pick)
 
@@ -847,9 +944,9 @@ def pick_pipeline(grid: tuple[int, int, int], n: int, dtype=jnp.float32, *,
         if m is None:
             small = ex * ey * ez < AUTO_V2_MIN_E
             return ("pallas_fused_cg" if small
-                    else "pallas_fused_cg_v2"), False
-        cands = ("pallas_fused_cg", "pallas_fused_cg_v2")
-        return min(cands, key=m), True
+                    else "pallas_fused_cg_v2"), None
+        seconds = {c: m(c) for c in ("pallas_fused_cg", "pallas_fused_cg_v2")}
+        return min(seconds, key=seconds.get), seconds
 
     return _cached_pick(key, pick)
 
@@ -864,7 +961,7 @@ def clear_cache(*, disk: bool = True) -> None:
     global _DISK_LOADED
     with _LOCK:
         _CACHE.clear()
-        _MEASURED.clear()
+        _SECONDS.clear()
         _DISK_LOADED = False           # next pick re-merges the file, if any
         if disk:
             try:
@@ -877,3 +974,4 @@ def cache_info() -> dict[tuple, int]:
     """Snapshot of the memoized selections (for tests / diagnostics)."""
     with _LOCK:
         return dict(_CACHE)
+

@@ -193,11 +193,9 @@ class SolverService:
         case = self._case_for(chunk[0].config)
         first = chunk[0]
         f = jnp.stack([jnp.asarray(r.f) for r in chunk])
-        rec = _trace.active()
         sw = stopwatch()
-        with (rec.span("service.dispatch", batch=len(chunk),
-                       max_b=self.max_b)
-              if rec is not None else _trace.NULL_SPAN):
+        with _trace.span("service.dispatch", batch=len(chunk),
+                         max_b=self.max_b):
             res: SolveResult = solvers_mod.solve_case(
                 case, f, b=len(chunk), niter=first.niter, tol=first.tol,
                 max_iter=first.max_iter, precond=first.precond)

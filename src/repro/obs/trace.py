@@ -1,23 +1,29 @@
 """Structured solver traces: spans, events, counters -> JSONL files.
 
-The recording surface of the telemetry subsystem (DESIGN.md §14).  A
-:class:`Recorder` collects *host-side* spans and events — instrumentation
-sits only at the host boundaries of the pipelines (the s-step cycle loop,
-the refinement sweep loop, driver dispatch, service drain); nothing is
+The recording surface of the telemetry subsystem (DESIGN.md §14).
+Instrumentation sits only at the host boundaries of the pipelines (the
+drivers' per-solve preparation, the s-step cycle loop, the refinement
+sweep loop, driver dispatch, autotune sweeps, service drain); nothing is
 ever recorded from inside a jitted computation, so the compiled programs
 are byte-for-byte the same with tracing on or off.
 
-Zero-overhead-when-off contract:
+:func:`span` is the one span call, ``with trace.span(name, **attrs):``.
+It feeds whichever consumers are on:
 
-* the active recorder is a context-local (``contextvars``) slot, read
-  once per solve at the host boundary — hot loops hold the local and
-  skip every span with a single ``is None`` test;
-* :func:`span` with no active recorder returns the shared
-  :data:`NULL_SPAN` singleton without evaluating span attributes (the
-  instrumented sites spell ``rec.span(...) if rec is not None else
-  NULL_SPAN`` so even the attrs dict is never allocated);
-* solve *output* is bitwise identical either way — pinned by
-  tests/test_obs_trace.py and the ``obs-smoke`` CI leg.
+* a ``jax.profiler`` session (``jax.profiler.start_trace``, or a
+  profiler server capturing): the span enters
+  ``jax.profiler.TraceAnnotation(name)``, so it lies on the host plane of
+  the device trace, on the profiler's clock, and its count and host
+  nanoseconds add to a process-wide total per name
+  (:func:`span_totals`);
+* a :class:`Recorder` (:func:`recording`): the span is recorded with its
+  attributes;
+* neither: the shared :data:`NULL_SPAN` singleton is returned.
+
+Overhead when off: one ``TraceAnnotation.is_enabled()`` test (none at
+all before ``jax`` is imported) and one context-local (``contextvars``)
+read per span; solve *output* is bitwise identical either way — pinned
+by tests/test_obs_trace.py and the ``obs-smoke`` CI leg.
 
 Trace files are JSON Lines with a versioned schema
 (:data:`TRACE_SCHEMA`): a ``header`` record first (schema + provenance),
@@ -25,10 +31,8 @@ then ``span``/``event`` records in completion order, then one closing
 ``summary`` record (counters, gauges).  :func:`validate_trace_lines` is
 the schema check the obs-smoke leg and the tests share.
 
-Opt-in ``jax.profiler`` hooks: :func:`profiler_annotation` wraps kernel
-launches in ``jax.profiler.TraceAnnotation`` when ``$REPRO_PROFILE`` is
-set (otherwise it is the no-op span), and :func:`profiling` wires
-``start_trace``/``stop_trace`` around a bench when a log dir is given.
+:func:`profiling` wires ``start_trace``/``stop_trace`` around a bench
+when a log dir is given.
 """
 from __future__ import annotations
 
@@ -38,13 +42,15 @@ import json
 import os
 import pathlib
 import platform
+import sys
+import threading
 import time
 from typing import Any
 
 __all__ = ["TRACE_SCHEMA", "TRACE_SCHEMA_VERSION", "NULL_SPAN", "Recorder",
-           "recording", "active", "span", "event", "count", "gauge",
-           "provenance", "machine_tag", "validate_trace_lines",
-           "validate_trace_file", "profiler_annotation", "profiling"]
+           "recording", "active", "span", "span_totals", "event", "count",
+           "gauge", "provenance", "machine_tag", "validate_trace_lines",
+           "validate_trace_file", "profiling"]
 
 TRACE_SCHEMA = "repro-trace/1"
 TRACE_SCHEMA_VERSION = 1
@@ -64,6 +70,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **attrs) -> None:
+        """Attributes known only inside the span (none kept when off)."""
 
 
 NULL_SPAN = _NullSpan()
@@ -101,6 +110,43 @@ class _Span:
             ev["attrs"] = self.attrs
         rec.records.append(ev)
         return False
+
+    def set(self, **attrs) -> None:
+        """Add attributes known only inside the span (recorded on exit)."""
+        self.attrs.update(attrs)
+
+
+class _ProfiledSpan:
+    """A span under a ``jax.profiler`` session: a ``TraceAnnotation`` on
+    the host plane around the region, its host time added to
+    :func:`span_totals`, and the recorder's span (or :data:`NULL_SPAN`)
+    inside it."""
+
+    __slots__ = ("name", "_ann", "_inner", "_t0")
+
+    def __init__(self, name: str, inner):
+        self.name = name
+        self._ann = _ANNOTATION(name)
+        self._inner = inner
+        self._t0 = 0
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._inner.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        ns = time.perf_counter_ns() - self._t0
+        self._inner.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        with _TOTALS_LOCK:
+            count, total = _TOTALS.get(self.name, (0, 0))
+            _TOTALS[self.name] = (count + 1, total + ns)
+        return False
+
+    def set(self, **attrs) -> None:
+        self._inner.set(**attrs)
 
 
 class Recorder:
@@ -190,11 +236,10 @@ def _jsonable(x):
 # ---------------------------------------------------------------------------
 
 def active() -> Recorder | None:
-    """The context's active recorder, or None when tracing is off.
+    """The context's active recorder, or None when none is on.
 
-    Host boundaries call this **once per solve** and thread the result
-    through their loops — the per-iteration cost when off is one local
-    ``is None`` test, no allocation.
+    Spans do not need it (:func:`span` reads it); code that does work
+    only for a recorder (the per-solve telemetry) asks here.
     """
     return _RECORDER.get()
 
@@ -222,11 +267,49 @@ def recording(path=None, *, meta: dict | None = None,
             rec.write(path)
 
 
+# jax.profiler.TraceAnnotation, looked up once jax has been imported (a
+# process without jax runs no profiler session).
+_ANNOTATION = None
+# span name -> (count, host ns) of the spans closed under a profiler session
+_TOTALS: dict[str, tuple[int, int]] = {}
+_TOTALS_LOCK = threading.Lock()
+
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` session is collecting host events."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return False
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # noqa: BLE001 — tracing must never sink a solve
+            return False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION.is_enabled()
+
+
 def span(name: str, /, **attrs):
-    """Module-level span: records under the active recorder, or returns
-    the shared no-op singleton when tracing is off."""
+    """The one span call: ``with trace.span("driver.prepare"):``.
+
+    Under a ``jax.profiler`` session a ``TraceAnnotation(name)`` on the
+    host plane, counted in :func:`span_totals`; under a recorder a
+    recorded span with ``attrs``; both when both are on; else the shared
+    no-op :data:`NULL_SPAN`.  Each form has ``set(**attrs)`` for
+    attributes known only inside the span."""
     rec = _RECORDER.get()
-    return rec.span(name, **attrs) if rec is not None else NULL_SPAN
+    inner = rec.span(name, **attrs) if rec is not None else NULL_SPAN
+    return _ProfiledSpan(name, inner) if _profiling() else inner
+
+
+def span_totals() -> dict[str, dict[str, int]]:
+    """Per span name, ``{"count", "ns"}``: how many spans of that name
+    closed while a ``jax.profiler`` session was on, and their host
+    nanoseconds (``time.perf_counter_ns``), over the process's life.
+    Nested spans count in their own name and in their parent's time."""
+    with _TOTALS_LOCK:
+        return {name: {"count": c, "ns": ns}
+                for name, (c, ns) in _TOTALS.items()}
 
 
 def event(name: str, /, **attrs) -> None:
@@ -357,30 +440,15 @@ def validate_trace_file(path) -> list[str]:
 
 
 # ---------------------------------------------------------------------------
-# opt-in jax.profiler hooks
+# a profiler session around a bench
 # ---------------------------------------------------------------------------
-
-def profiler_annotation(name: str):
-    """``jax.profiler.TraceAnnotation(name)`` when ``$REPRO_PROFILE`` is
-    set — the kernel launch shows up named on the profiler timeline —
-    else the shared no-op span.  Opt-in by env var so the default path
-    never imports ``jax.profiler``."""
-    if not os.environ.get("REPRO_PROFILE"):
-        return NULL_SPAN
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiling must never sink a solve
-        return NULL_SPAN
-
 
 @contextlib.contextmanager
 def profiling(logdir=None):
     """``jax.profiler.start_trace(logdir)`` .. ``stop_trace()`` around a
     block; a no-op when ``logdir`` is falsy.  The benches pass
     ``$REPRO_PROFILE_DIR`` here, so profiling is one env var away without
-    touching bench code."""
+    touching bench code; every :func:`span` inside lands in the trace."""
     if not logdir:
         yield None
         return

@@ -55,7 +55,9 @@ def test_pick_is_cached_per_key():
                                 measure=measure)
     assert be1 == 128
     n_calls = len(calls)
-    assert n_calls == len(autotune.candidate_blocks(TPU_E, 4, tpu=True))
+    # one reading per candidate in each of the sweep's rounds
+    assert n_calls == autotune.SWEEP_ROUNDS * len(
+        autotune.candidate_blocks(TPU_E, 4, tpu=True))
 
     # same key: served from cache, measure never re-runs
     be2 = autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
@@ -178,8 +180,8 @@ def test_pick_slab_sz_cached_per_grid():
                                 measure=measure)
     assert sz1 == 2                    # the smallest 128-lane block
     n_calls = len(calls)
-    assert n_calls == len(autotune.candidate_slab_sizes(TPU_GRID, 4,
-                                                        tpu=True))
+    assert n_calls == autotune.SWEEP_ROUNDS * len(
+        autotune.candidate_slab_sizes(TPU_GRID, 4, tpu=True))
     # same key: cached; different grid: distinct key
     autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                           measure=measure)
@@ -541,7 +543,9 @@ def test_tpu_pick_sweeps_only_compiled_candidates():
 
     autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
                           measure=measure)
-    assert seen == autotune.candidate_slab_sizes(TPU_GRID, 4, tpu=True)
+    # the rounds interleave the candidates
+    assert seen == (autotune.candidate_slab_sizes(TPU_GRID, 4, tpu=True)
+                    * autotune.SWEEP_ROUNDS)
     assert 1 not in seen               # 64 lanes: not a compiled block
 
 
@@ -566,3 +570,145 @@ def test_stale_disk_schema_is_ignored():
                                     measure=lambda sz, go: float(sz))
     assert cfg == (2, "parallel")
     assert json.loads(path.read_text())["version"] == autotune._DISK_VERSION
+
+
+# ---------------------------------------------------------------------------
+# the sweep's estimator: interleaved rounds of chained calls, median, and a
+# margin before the established (first) config is displaced
+# ---------------------------------------------------------------------------
+
+class _NoisyDevice:
+    """A fake device and its wall clock.  A kernel call of a config costs
+    the config's true seconds with 2% jitter; a sync waits up to 0.3 ms
+    more (the dispatch-and-sync latency a lone sub-millisecond call is
+    dominated by); one sync in twenty is stalled by 5 ms (preemption)."""
+
+    def __init__(self, costs: dict, seed: int):
+        import numpy as np
+
+        self.costs = costs
+        self.rng = np.random.default_rng(seed)
+        self.now = 0.0
+
+    def timer(self):
+        return self.now
+
+    def sync(self, x):
+        self.now += self.rng.uniform(0.0, 3e-4)
+        if self.rng.random() < 0.05:
+            self.now += 5e-3
+        return x
+
+    def make(self, *cfg):
+        cost = self.costs[cfg]
+
+        def call():
+            self.now += cost * (1.0 + self.rng.normal(0.0, 0.02))
+
+        return call, 1
+
+
+def _noisy_sweep(costs: dict, seed: int):
+    dev = _NoisyDevice(costs, seed)
+    timed = autotune._chained(dev.make, timer=dev.timer, sync=dev.sync)
+    return autotune._sweep(("cfg", "slab", "test"), list(costs), timed)
+
+
+def test_chained_sweep_picks_the_truly_faster_config_under_noise():
+    # the second config is 5% faster: under the noise above a lone synced
+    # call cannot tell them apart, a 20-ms chain can
+    costs = {(2, "parallel"): 0.35e-3, (1, "arbitrary"): 0.3325e-3}
+    for seed in range(20):
+        best, seconds = _noisy_sweep(costs, seed)
+        assert best == (1, "arbitrary"), (seed, seconds)
+        assert set(seconds) == {"2/parallel", "1/arbitrary"}
+        assert seconds["1/arbitrary"] == pytest.approx(0.3325e-3, rel=0.03)
+
+
+def test_chained_sweep_keeps_the_first_config_under_a_tie():
+    costs = {(2, "parallel"): 0.35e-3, (1, "arbitrary"): 0.35e-3}
+    for seed in range(20):
+        best, seconds = _noisy_sweep(costs, seed)
+        assert best == (2, "parallel"), (seed, seconds)
+
+
+def test_sweep_margin_keeps_the_first_candidate_within_two_percent():
+    near = {512: 1.0, 256: 0.99, 128: 1.5}
+    assert autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
+                                 measure=near.get) == 512
+    autotune.clear_cache()
+    far = {512: 1.0, 256: 0.97, 128: 1.5}
+    assert autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
+                                 measure=far.get) == 256
+
+
+def test_sweep_spans_name_the_key_and_each_timing():
+    from repro.obs import trace
+
+    with trace.recording() as rec:
+        autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
+                              measure=lambda be: float(be))
+    spans = [r for r in rec.records if r["type"] == "span"]
+    sweeps = [r for r in spans if r["name"] == "autotune.sweep"]
+    measures = [r for r in spans if r["name"] == "autotune.measure"]
+    n_cands = len(autotune.candidate_blocks(TPU_E, 4, tpu=True))
+    assert len(sweeps) == 1
+    assert sweeps[0]["attrs"] == {"key": f"4/{TPU_E}/float32/float32/tpu",
+                                  "candidates": n_cands}
+    assert len(measures) == autotune.SWEEP_ROUNDS * n_cands
+    assert {(m["attrs"]["config"], m["attrs"]["seconds"])
+            for m in measures} == {("512", 512.0), ("256", 256.0),
+                                   ("128", 128.0)}
+
+
+def test_disk_cache_round_trips_candidate_seconds():
+    times = {512: 3.0, 256: 1.0, 128: 2.0}
+    assert autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
+                                 measure=times.get) == 256
+    key = (4, TPU_E, "float32", "float32", "tpu")
+    want = {"512": 3.0, "256": 1.0, "128": 2.0}
+    (entry,) = json.loads(autotune.cache_path().read_text())["entries"]
+    assert entry["seconds"] == want
+
+    # a fresh process reads the pick and its seconds back ...
+    autotune.clear_cache(disk=False)
+
+    def boom(*_):
+        raise AssertionError("disk-cached pick must not re-measure")
+
+    assert autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
+                                 measure=boom) == 256
+    assert autotune._SECONDS == {key: want}
+    # ... and keeps them when a later measured pick rewrites the file
+    autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
+                              measure=lambda sz, go: float(sz))
+    data = json.loads(autotune.cache_path().read_text())
+    assert data["version"] == autotune._DISK_VERSION == 3
+    seconds = {tuple(e["key"]): e["seconds"] for e in data["entries"]}
+    assert seconds[key] == want
+    assert seconds[("cfg", "slab", 4, 8, 8, 8, "float32", "float32",
+                    "tpu")] == {"8/parallel": 8.0, "8/arbitrary": 8.0,
+                                "4/parallel": 4.0, "4/arbitrary": 4.0,
+                                "2/parallel": 2.0, "2/arbitrary": 2.0}
+
+
+def test_schema_2_cache_file_loads_as_a_miss():
+    """A schema-2 file (picks without their seconds) is not read back: the
+    pick re-measures and the file is rewritten at schema 3."""
+    path = autotune.cache_path()
+    path.parent.mkdir(parents=True, exist_ok=True)
+    key = ["cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu"]
+    path.write_text(json.dumps({"version": 2, "entries": [
+        {"key": key, "value": [8, "arbitrary"]}]}))
+    calls = []
+
+    def measure(sz, grid_order):
+        calls.append((sz, grid_order))
+        return float(sz)
+
+    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
+                                    measure=measure)
+    assert calls and cfg == (2, "parallel")
+    (entry,) = json.loads(path.read_text())["entries"]
+    assert entry["key"] == key and entry["value"] == [2, "parallel"]
+    assert entry["seconds"]["2/parallel"] == 2.0

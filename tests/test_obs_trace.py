@@ -33,11 +33,6 @@ def test_module_count_gauge_event_noop_when_off():
     trace.event("e", k=1)  # nothing to assert beyond "does not raise"
 
 
-def test_profiler_annotation_null_without_env(monkeypatch):
-    monkeypatch.delenv("REPRO_PROFILE", raising=False)
-    assert trace.profiler_annotation("x") is trace.NULL_SPAN
-
-
 # ---------------------------------------------------------------------------
 # recording
 # ---------------------------------------------------------------------------

@@ -85,6 +85,50 @@ def test_measure_validates_reps_and_warmup():
         timing.measure(lambda: None, warmup=-1)
 
 
+def test_measure_chain_times_back_to_back_calls_with_one_sync():
+    calls, synced = [], []
+
+    def fn():
+        calls.append(1)
+        return len(calls)
+
+    # 2 reps of a 4-call chain: intervals 8.0 and 12.0 -> 2.0, 3.0 per call
+    clock = _Clock([0.0, 8.0, 10.0, 22.0])
+    t = timing.measure(fn, reps=2, warmup=0, timer=clock,
+                       sync=synced.append, chain=4)
+    assert t == 3.0                     # upper median of 2.0 and 3.0
+    assert len(calls) == 8
+    assert synced == [4, 8]             # one sync per chain, on its last call
+    with pytest.raises(ValueError):
+        timing.measure(fn, chain=0)
+
+
+class _CostClock:
+    """A clock that each call of ``fn`` advances by ``cost`` seconds."""
+
+    def __init__(self, cost):
+        self.now, self.cost = 0.0, cost
+
+    def __call__(self):
+        return self.now
+
+    def fn(self):
+        self.now += self.cost
+
+
+def test_chain_length_reaches_the_asked_wall_time():
+    clk = _CostClock(0.001)
+    # chains of 1, 2, 4, 8, 16 ms fall short of 20 ms; 32 ms does not
+    assert timing.chain_length(clk.fn, seconds=0.02, timer=clk,
+                               sync=lambda x: x) == 32
+    slow = _CostClock(0.05)
+    assert timing.chain_length(slow.fn, seconds=0.02, timer=slow,
+                               sync=lambda x: x) == 1
+    stuck = _CostClock(0.0)
+    assert timing.chain_length(stuck.fn, seconds=0.02, timer=stuck,
+                               sync=lambda x: x, limit=64) == 64
+
+
 def test_measure_default_sync_blocks_jax_values():
     import jax.numpy as jnp
 
