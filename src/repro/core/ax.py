@@ -51,7 +51,13 @@ def local_grad3_t(ur: jnp.ndarray, us: jnp.ndarray, ut: jnp.ndarray,
 
 
 def apply_metric(wr, ws, wt, g):
-    """Apply the 6-entry symmetric metric: (ur, us, ut) = G @ (wr, ws, wt)."""
+    """Apply the 6-entry symmetric metric: (ur, us, ut) = G @ (wr, ws, wt).
+
+    A 3-component ``g`` is the packed (rr, ss, tt) diagonal of an
+    axis-aligned mesh (``NekboneCase.box_fields``).
+    """
+    if g.shape[1] == 3:
+        return g[:, 0] * wr, g[:, 1] * ws, g[:, 2] * wt
     grr, grs, grt, gss, gst, gtt = (g[:, m] for m in range(6))
     ur = grr * wr + grs * ws + grt * wt
     us = grs * wr + gss * ws + gst * wt

@@ -203,9 +203,14 @@ def _check_box_fields(grid, n, mask, c) -> None:
     The v2 kernels *rebuild* both from per-axis factors
     (``geom.box_axis_factors``), so silently accepting a different mask or
     weight would compute a different problem.  Skipped under tracing
-    (concrete mesh fields are checked at build time).  Host work of every
-    call: the ``driver.validate`` span.
+    (concrete mesh fields are checked at build time).  A direct driver call
+    that passes the fields pays this host work (the ``driver.validate``
+    span) on every call; the case routes (core/solvers.py) check once per
+    case through ``NekboneCase.box_fields`` and pass ``mask=c=None``, which
+    opens no span.
     """
+    if mask is None and c is None:
+        return
     with _trace.span("driver.validate"):
         (mx, my, mz), (cx, cy, cz) = box_axis_factors(grid, n)
         for name, field, want in (
@@ -521,7 +526,8 @@ def cg_ir_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
       b:       (E, n, n, n) assembled, masked right-hand side, in the
                precision the refined residuals should reach (f64 under
                ``JAX_ENABLE_X64`` — the oracle; f32 on TPU).
-      D, g, grid: as :func:`cg_fused_v2_fixed_iters`.
+      D, g, grid: as :func:`cg_fused_v2_fixed_iters` (the packed
+               ``(E, 3, n, n, n)`` diagonal only with ``variant="v2"``).
       niter:   inner iterations per refinement sweep (the paper's fixed-
                iteration protocol runs 100).
       precision: refinement policy (default ``bf16_ir``); the policy's
@@ -530,7 +536,8 @@ def cg_ir_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
                2 otherwise).
       inner_iters: override the per-sweep inner count (default ``niter``).
       mask/c:  optional structural fields; rebuilt from the box's per-axis
-               factors when omitted.
+               factors when omitted (the v2 inner solves then have no
+               field to check).
       variant: inner pipeline — ``"v2"`` (two slab kernels), ``"v1"``, or
                ``"sstep"`` (the v3 s-step matrix-powers pipeline,
                core/cg_sstep.py — its f64 Gram recurrence composes with
@@ -559,6 +566,7 @@ def cg_ir_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     if inner_iters is None:
         inner_iters = niter
 
+    mask_in, c_in = mask, c
     if mask is None or c is None:
         (mxf, myf, mzf), (cxf, cyf, czf) = box_axis_factors(grid, n)
         if mask is None:
@@ -600,7 +608,7 @@ def cg_ir_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
             # operator than the inner pipeline solves.
             return cg_fused_v2_fixed_iters(
                 r_scaled, D=D, g=g, grid=grid, niter=inner_iters,
-                mask=mask, c=c, sz=sz, interpret=interpret,
+                mask=mask_in, c=c_in, sz=sz, interpret=interpret,
                 precision=policy)
         return cg_fused_fixed_iters(
             r_scaled, D=D, g=g, mask=mask, c=c, grid=grid,

@@ -215,6 +215,36 @@ class NekboneCase:
             cache[name] = spec
         return spec
 
+    def box_fields(self) -> jnp.ndarray:
+        """The case's metric diagonal, checked and packed for the v2 family.
+
+        The v2-family kernels rebuild ``mask`` and ``c`` from per-axis
+        factors and read only the metric's diagonal, so both fields and
+        the zero off-diagonal metric must be the structured box's.  Both
+        checks (``cg_fused._check_box_fields``, ``kernels/ops.diag_metric``)
+        run on the first call; later calls return the same packed
+        ``(E, 3, n, n, n)`` diagonal in the case dtype, counted as
+        ``driver.box_fields_reused``.  The cache is keyed on the identity of
+        ``(g, mask, c)``: assigning another array to any of them checks
+        again on the next call.
+        """
+        from repro.kernels import ops as kernel_ops
+        from repro.obs import trace
+
+        fields = (self.g, self.mask, self.c)
+        cached = getattr(self, "_box_fields", None)
+        if cached is not None and all(
+                a is b for a, b in zip(cached[0], fields)):
+            trace.count("driver.box_fields_reused")
+            return cached[1]
+        with trace.span("driver.prepare"):
+            cg_fused_mod._check_box_fields(self.grid, self.n, self.mask,
+                                           self.c)
+            E, n = self.g.shape[0], self.n
+            g3 = kernel_ops.diag_metric(self.g, E, n).reshape(E, 3, n, n, n)
+        self._box_fields = (fields, g3)
+        return g3
+
     def _reference_preconditioner(self, name: str | None):
         """The XLA-composed ``M(r)`` for the non-fused solver paths."""
         from repro.core import precond as precond_mod

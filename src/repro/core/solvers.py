@@ -46,7 +46,9 @@ _SSTEP_BLOCK_WARNED = False
 # ---------------------------------------------------------------------------
 # drivers — uniform signature: (case, f, *, b, niter, tol, max_iter,
 # pc_name) -> SolveResult.  ``pc_name`` is the already-resolved registry
-# preconditioner name (None = unpreconditioned).
+# preconditioner name (None = unpreconditioned).  The v2-family routes pass
+# ``case.box_fields()`` (checked once per case) as ``g`` and no mask/c, so
+# the drivers' per-call field checks have nothing left to check.
 # ---------------------------------------------------------------------------
 
 def _drive_block(case, f, *, b, niter, tol, max_iter, pc_name):
@@ -54,11 +56,11 @@ def _drive_block(case, f, *, b, niter, tol, max_iter, pc_name):
 
     if niter is not None:
         return cg_block_fixed_iters(
-            f, D=case.D, g=case.g, grid=case.grid, niter=niter,
-            mask=case.mask, c=case.c, precision=case.precision)
+            f, D=case.D, g=case.box_fields(), grid=case.grid, niter=niter,
+            precision=case.precision)
     return cg_block_tol(
-        f, D=case.D, g=case.g, grid=case.grid, tol=tol, max_iter=max_iter,
-        mask=case.mask, c=case.c, precision=case.precision)
+        f, D=case.D, g=case.box_fields(), grid=case.grid, tol=tol,
+        max_iter=max_iter, precision=case.precision)
 
 
 def _drive_block_loop(case, f, *, b, niter, tol, max_iter, pc_name):
@@ -80,6 +82,12 @@ def _drive_block_loop(case, f, *, b, niter, tol, max_iter, pc_name):
 def _drive_ir(case, f, *, b, niter, tol, max_iter, pc_name):
     variant = {"pallas_fused_cg_v2": "v2",
                "pallas_sstep_v3": "sstep"}.get(case.ax_impl, "v1")
+    if variant == "v2":
+        # the outer residual applies the packed diagonal and rebuilds the
+        # box mask/c from their factors.
+        return cg_fused_mod.cg_ir_fixed_iters(
+            f, D=case.D, g=case.box_fields(), grid=case.grid, niter=niter,
+            precision=case.precision, variant=variant)
     return cg_fused_mod.cg_ir_fixed_iters(
         f, D=case.D, g=case.g, grid=case.grid, niter=niter,
         precision=case.precision, mask=case.mask, c=case.c,
@@ -95,18 +103,17 @@ def _drive_sstep(case, f, *, b, niter, tol, max_iter, pc_name):
     if theta is None:
         theta = estimate_theta(case.D, case.g, case.grid, case.mask)
         case._sstep_theta = theta
+    g3 = case.box_fields()
     if niter is not None:
         return cg_sstep_fixed_iters(
-            f, D=case.D, g=case.g, grid=case.grid, niter=niter, s=case.s,
-            mask=case.mask, c=case.c, theta=theta,
-            precision=case.precision)
+            f, D=case.D, g=g3, grid=case.grid, niter=niter, s=case.s,
+            theta=theta, precision=case.precision)
     # tolerance-driven: the per-cycle host sync checks the stored-residual
     # reduction and the f64 Gram recurrence resolves the stopping point to
     # iteration granularity (DESIGN.md §9.4).
     return cg_sstep_fixed_iters(
-        f, D=case.D, g=case.g, grid=case.grid, niter=max_iter, s=case.s,
-        mask=case.mask, c=case.c, theta=theta, tol=tol,
-        precision=case.precision)
+        f, D=case.D, g=g3, grid=case.grid, niter=max_iter, s=case.s,
+        theta=theta, tol=tol, precision=case.precision)
 
 
 def _drive_v2(case, f, *, b, niter, tol, max_iter, pc_name):
@@ -115,11 +122,11 @@ def _drive_v2(case, f, *, b, niter, tol, max_iter, pc_name):
     spec = case.precond_spec(pc_name) if pc_name else None
     if spec is None:
         return cg_fused_mod.cg_fused_v2_fixed_iters(
-            f, D=case.D, g=case.g, grid=case.grid, niter=niter,
-            mask=case.mask, c=case.c, precision=case.precision)
+            f, D=case.D, g=case.box_fields(), grid=case.grid, niter=niter,
+            precision=case.precision)
     return precond_mod.pcg_fused_v2_fixed_iters(
-        f, D=case.D, g=case.g, grid=case.grid, niter=niter, precond=spec,
-        mask=case.mask, c=case.c, precision=case.precision)
+        f, D=case.D, g=case.box_fields(), grid=case.grid, niter=niter,
+        precond=spec, precision=case.precision)
 
 
 def _drive_v2_tol(case, f, *, b, niter, tol, max_iter, pc_name):
@@ -127,8 +134,8 @@ def _drive_v2_tol(case, f, *, b, niter, tol, max_iter, pc_name):
 
     spec = case.precond_spec(pc_name) if pc_name else None
     return precond_mod.cg_fused_tol(
-        f, D=case.D, g=case.g, grid=case.grid, tol=tol, max_iter=max_iter,
-        precond=spec, mask=case.mask, c=case.c, precision=case.precision)
+        f, D=case.D, g=case.box_fields(), grid=case.grid, tol=tol,
+        max_iter=max_iter, precond=spec, precision=case.precision)
 
 
 def _drive_v1(case, f, *, b, niter, tol, max_iter, pc_name):

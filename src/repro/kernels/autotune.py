@@ -891,25 +891,24 @@ AUTO_V2_MIN_E = 16
 
 def _default_measure_pipeline(grid: tuple[int, int, int], n: int, dtype,
                               acc_dtype=None) -> Callable[[str], float]:
-    """Times one fixed CG iteration of a full pipeline on the real case
-    shape (manufactured solution, same setup as the benches)."""
-    from repro.core import cg_fused as _cg
+    """Times a one-iteration solve of each pipeline on the real case shape
+    (manufactured right-hand side), through the :data:`repro.core.solvers.
+    REGISTRY` row ``solve_case`` runs for it, so each candidate pays the
+    per-solve host work its route pays: the case's one-time field check
+    falls in the warm-up call."""
+    from repro.core import solvers
     from repro.core.nekbone import NekboneCase
 
     case = NekboneCase(n=n, grid=grid, dtype=dtype)
     _, b = case.manufactured()
 
     def measure(pipeline: str) -> float:
-        if pipeline == "pallas_fused_cg_v2":
-            def f():
-                return _cg.cg_fused_v2_fixed_iters(
-                    b, D=case.D, g=case.g, grid=grid, niter=1,
-                    mask=case.mask, c=case.c).x
-        else:
-            def f():
-                return _cg.cg_fused_fixed_iters(
-                    b, D=case.D, g=case.g, mask=case.mask, c=case.c,
-                    grid=grid, niter=1).x
+        case.ax_impl = pipeline
+        drive = solvers.REGISTRY[solvers.route_name(case, niter=1)]
+
+        def f():
+            return drive(case, b, b=1, niter=1, tol=None, max_iter=1,
+                         pc_name=None).x
 
         return _timing.measure(f, reps=3, warmup=1)
 

@@ -144,13 +144,25 @@ def slab_axis_factors(grid: tuple[int, int, int], n: int, dtype):
     Thin dtype-casting wrapper over :func:`repro.core.geom.box_axis_factors`
     (the single source of the factorization); the factor values (0, 1, 1/2)
     are exact in every supported dtype, so the in-kernel outer products
-    reproduce the full fields bitwise.
+    reproduce the full fields bitwise.  Made once per shape and dtype and
+    shared (jax arrays are immutable): every v2-family solve asks for
+    them, and six host-to-device copies per solve are host work no solve
+    needs.
     """
+    return _slab_axis_factors(tuple(grid), int(n), jnp.dtype(dtype).name,
+                              bool(jax.config.jax_enable_x64))
+
+
+@functools.lru_cache(maxsize=64)
+def _slab_axis_factors(grid, n, dtype_name, x64):
     from repro.core.geom import box_axis_factors
 
     masks, cs = box_axis_factors(grid, n)
-    return (tuple(jnp.asarray(m, dtype) for m in masks),
-            tuple(jnp.asarray(c, dtype) for c in cs))
+    # concrete even when the first call comes from inside a jit trace:
+    # a cached tracer would leak into every later call.
+    with jax.ensure_compile_time_eval():
+        return (tuple(jnp.asarray(m, dtype_name) for m in masks),
+                tuple(jnp.asarray(c, dtype_name) for c in cs))
 
 
 def diag_metric(g: jnp.ndarray, E: int, n: int) -> jnp.ndarray:

@@ -513,6 +513,20 @@ def test_pick_pipeline_measured_winner_persists():
                                              backend="tpu"), str)
 
 
+def test_pipeline_measure_checks_the_v2_fields_once():
+    """The pick times each pipeline through its case route: v2's one-time
+    box-field check falls in the warm-up, not in every timed solve."""
+    from repro.obs import trace
+
+    m = autotune._default_measure_pipeline((2, 2, 2), 4, jnp.float32)
+    for pipeline, checks in (("pallas_fused_cg", 0),
+                             ("pallas_fused_cg_v2", 1)):
+        with trace.recording() as rec:
+            assert m(pipeline) > 0
+        assert sum(1 for r in rec.records
+                   if r["name"] == "driver.validate") == checks, pipeline
+
+
 def test_case_ax_impl_auto_resolves_and_records_request():
     from repro.core.nekbone import NekboneCase
 

@@ -229,6 +229,114 @@ def test_cg_fused_v2_rejects_nondiagonal_metric(rng):
                                 niter=2, interpret=True)
 
 
+# ---------------------------------------------------------------------------
+# Case routes: the box fields are checked once per case, not per solve
+# ---------------------------------------------------------------------------
+
+def _validate_spans(rec) -> int:
+    return sum(1 for r in rec.records if r["type"] == "span"
+               and r["name"] == "driver.validate")
+
+
+def test_case_checks_its_box_fields_once():
+    from repro.obs import trace
+
+    case = NekboneCase(n=4, grid=(2, 2, 2), dtype=jnp.float32,
+                       ax_impl="pallas_fused_cg_v2", precond="jacobi")
+    _, f = case.manufactured()
+    with trace.recording() as first:
+        case.solve(f, tol=1e-3, max_iter=4)
+    with trace.recording() as second:
+        case.solve(f, tol=1e-3, max_iter=4)
+    assert [r["attrs"]["route"] for r in second.records
+            if r["name"] == "solve"] == ["v2_tol"]
+    assert _validate_spans(first) == 1
+    assert "driver.box_fields_reused" not in first.counters
+    assert _validate_spans(second) == 0
+    assert second.counters["driver.box_fields_reused"] == 1
+
+
+@pytest.mark.parametrize("field", ["mask", "g"])
+def test_case_checks_a_reassigned_field_on_the_next_solve(rng, field):
+    from repro.core.geom import random_spd_metric
+
+    case = NekboneCase(n=4, grid=(2, 2, 2), dtype=jnp.float32,
+                       ax_impl="pallas_fused_cg_v2")
+    _, f = case.manufactured()
+    case.solve(f, niter=2)
+    if field == "mask":
+        case.mask = case.mask.at[0, 1, 1, 1].set(0.0)  # interior node masked
+        match = "structured box mask"
+    else:
+        case.g = jnp.asarray(random_spd_metric(rng, case.mesh.nelt, 4),
+                             jnp.float32)
+        match = "axis-aligned"
+    with pytest.raises(ValueError, match=match):
+        case.solve(f, niter=2)
+
+
+def test_slab_axis_factors_are_made_once_per_shape_and_dtype():
+    f32 = ops.slab_axis_factors((2, 2, 3), 4, jnp.float32)
+    assert ops.slab_axis_factors([2, 2, 3], 4, "float32") is f32
+    bf16 = ops.slab_axis_factors((2, 2, 3), 4, jnp.bfloat16)
+    assert bf16 is not f32
+    assert {a.dtype for group in bf16 for a in group} == {
+        jnp.dtype(jnp.bfloat16)}
+    assert [a.shape for a in f32[0]] == [(2, 4), (2, 4), (3, 4)]
+    # first asked for inside a jit trace: still concrete afterwards
+    import jax
+
+    jax.jit(lambda: ops.slab_axis_factors((5, 1, 3), 4, jnp.float32)[0][0]
+            .sum())()
+    mask_x = ops.slab_axis_factors((5, 1, 3), 4, jnp.float32)[0][0]
+    assert not isinstance(mask_x, jax.core.Tracer)
+    np.testing.assert_array_equal(np.asarray(mask_x)[0], [0, 1, 1, 1])
+
+
+@pytest.mark.parametrize("route", ["v2", "v2_tol", "block", "ir", "sstep"])
+def test_case_route_matches_the_direct_driver_bitwise(route):
+    from repro.core import precond as precond_mod
+    from repro.core import solvers
+    from repro.core.cg_block import cg_block_fixed_iters
+    from repro.core.cg_fused import cg_ir_fixed_iters
+    from repro.core.cg_sstep import cg_sstep_fixed_iters
+
+    case = NekboneCase(
+        n=4, grid=(2, 2, 2), dtype=jnp.float32,
+        precision="bf16_ir" if route == "ir" else None,
+        ax_impl="pallas_sstep_v3" if route == "sstep"
+        else "pallas_fused_cg_v2")
+    _, f = case.manufactured()
+    full = dict(D=case.D, g=case.g, grid=case.grid, mask=case.mask,
+                c=case.c)
+    kw = dict(niter=5)
+    if route == "block":
+        f = jnp.stack([f, 0.5 * f[::-1]])
+    elif route == "v2_tol":
+        kw = dict(tol=1e-3, max_iter=20, precond="jacobi")
+    assert solvers.route_name(case, b=f.shape[0] if f.ndim == 5 else 1,
+                              niter=kw.get("niter"),
+                              pc_name=kw.get("precond")) == route
+    res = case.solve(f, **kw)
+    if route == "v2":
+        direct = cg_fused_v2_fixed_iters(f, niter=5, **full)
+    elif route == "v2_tol":
+        direct = precond_mod.cg_fused_tol(
+            f, tol=1e-3, max_iter=20, precond=case.precond_spec("jacobi"),
+            **full)
+    elif route == "block":
+        direct = cg_block_fixed_iters(f, niter=5, **full)
+    elif route == "ir":
+        direct = cg_ir_fixed_iters(f, niter=5, precision="bf16_ir",
+                                   variant="v2", **full)
+    else:
+        direct = cg_sstep_fixed_iters(f, niter=5, s=case.s,
+                                      theta=case._sstep_theta, **full)
+    np.testing.assert_array_equal(np.asarray(res.x), np.asarray(direct.x))
+    np.testing.assert_array_equal(np.asarray(res.iters_taken),
+                                  np.asarray(direct.iters_taken))
+
+
 def test_cg_fused_v2_tol_and_precond_stay_fused():
     """tol-driven and preconditioned v2 solves route to the fused drivers
     (core/precond.py, DESIGN.md §9) — no fall-back to the XLA path."""
