@@ -53,6 +53,10 @@ class NekboneConfig:
     # requests by (grid, n, precision, precond) and solves them at b up
     # to this value per dispatch.
     b: int = 1
+    # Components of the solved field (core/nekbone.NekboneCase): 1 for a
+    # scalar Poisson solve, 3 for a vector one that shares the scalar
+    # operator (CEED BP6), solved as one coupled system.
+    components: int = 1
 
     @property
     def nelt(self) -> int:
@@ -71,7 +75,8 @@ class NekboneConfig:
         kwargs = dict(n=self.n, grid=self.grid,
                       dtype=jnp_dtype(self.dtype), ax_impl=self.ax_impl,
                       precision=self.precision, s=self.s,
-                      precond=self.precond, cheb_k=self.cheb_k, b=self.b)
+                      precond=self.precond, cheb_k=self.cheb_k, b=self.b,
+                      components=self.components)
         kwargs.update(overrides)
         return NekboneCase(**kwargs)
 

@@ -29,8 +29,9 @@ __all__ = ["flops_per_dof", "cg_iter_flops", "cg_iter_bytes", "intensity",
            "ir_overhead_streams", "SSTEP_DEFAULT_S", "sstep_cycle_streams",
            "sstep_streams", "sstep_halo_streams", "sstep_effective_streams",
            "sstep_intensity", "JACOBI_V2_READ_STREAMS",
-           "JACOBI_V2_WRITE_STREAMS", "CHEB_V2_READ_STREAMS",
-           "CHEB_V2_WRITE_STREAMS", "CHEB_DEFAULT_K", "cheb_halo_streams",
+           "JACOBI_V2_WRITE_STREAMS", "JACOBI_V2_SHARED_STREAMS",
+           "CHEB_V2_READ_STREAMS", "CHEB_V2_WRITE_STREAMS", "CHEB_DEFAULT_K",
+           "cheb_halo_streams",
            "cheb_effective_streams", "cheb_flops_per_dof",
            "sstep_collective_streams", "cheb_collective_streams",
            "v2_plane_collective_streams",
@@ -190,6 +191,10 @@ def sstep_intensity(n: int, s: int, itemsize: int = 8) -> float:
 # = 10R + 4W = 14 streams/iter, one more than unpreconditioned v2.
 JACOBI_V2_READ_STREAMS = 10
 JACOBI_V2_WRITE_STREAMS = 4
+# Of those reads, the operator-side ones a b-component block solve shares
+# (core/cg_block.py coupled mode, CEED BP6): the 3 metric diagonals of the
+# slab kernel and the diagonal of the update kernel.
+JACOBI_V2_SHARED_STREAMS = 4.0
 
 # Chebyshev(k): one extra kernel per iteration evaluates z = q_k(A) r in a
 # single halo'd slab residency (the §8 matrix-powers machinery):
@@ -480,6 +485,14 @@ def multi_rhs_streams(b: int, pipeline: str = "fused_v2", *,
     which recovers the 6.25-stream s=4 rung exactly at b=1 and drops
     below it for every b > 1 (5.59375 at b=8) — the pinned
     ``streams_per_rhs`` trajectory.
+
+    ``fused_v2_jacobi``: the block slab kernel and the batched Jacobi
+    update (``nekbone_pcg_update_block_pallas``) as laid out — per RHS
+    p, z (slab) and x, p, z, w (update) read, p, w, x, z written; the 3
+    metric diagonals and the Jacobi diagonal read once for the batch:
+
+        reads = 6 + 4/b,  writes = 4        (6b + 4 and 4b in all; the
+                                             10 + 4 rung at b=1)
     """
     b = float(b)
     if b < 1:
@@ -488,6 +501,10 @@ def multi_rhs_streams(b: int, pipeline: str = "fused_v2", *,
         reads = (FUSED_V2_READ_STREAMS - MULTI_RHS_SHARED_STREAMS
                  + MULTI_RHS_SHARED_STREAMS / b)
         return reads, float(FUSED_V2_WRITE_STREAMS)
+    if pipeline == "fused_v2_jacobi":
+        reads = (JACOBI_V2_READ_STREAMS - JACOBI_V2_SHARED_STREAMS
+                 + JACOBI_V2_SHARED_STREAMS / b)
+        return reads, float(JACOBI_V2_WRITE_STREAMS)
     if pipeline == "sstep_v3":
         cr, cw = sstep_cycle_streams(s)
         reads = ((cr - MULTI_RHS_SHARED_STREAMS) / float(s)

@@ -53,7 +53,9 @@ class NekboneCase:
                element counts cannot amortize v2's second kernel
                dispatch; preconditioned cases always select v2, the only
                pipeline with fused PCG drivers).  The requested value is
-               kept in ``ax_impl_requested``.
+               kept in ``ax_impl_requested``.  A vector case
+               (``components`` = 3) resolves to 'pallas_fused_cg_v2', the
+               pipeline its coupled route runs.
                The fused_cg variants select the step-fused CG pipelines
                (core/cg_fused.py): v1 runs one multi-output Pallas call per
                iteration plus XLA assembly/vector passes (DESIGN.md §3.3);
@@ -88,6 +90,11 @@ class NekboneCase:
                routes unpreconditioned v2-family solves through the
                multi-RHS block kernels (core/cg_block.py), amortizing the
                operator streams across the batch.
+      components: 1 (a scalar field) or 3 (a vector field, CEED BP6):
+               with 3, ``solve`` takes one ``(3, E, n, n, n)`` field as
+               one linear system under the shared scalar operator; its
+               dots, residual measure and stopping rule run over the
+               whole field (the ``vector`` route of core/solvers.py).
     """
 
     n: int = 10
@@ -100,8 +107,12 @@ class NekboneCase:
     precond: str | None = None
     cheb_k: int = 4
     b: int = 1
+    components: int = 1
 
     def __post_init__(self):
+        if self.components not in (1, 3):
+            raise ValueError(f"components must be 1 (scalar) or 3 "
+                             f"(vector); got {self.components!r}")
         policy = None
         if self.precision is not None:
             from repro.core.precision import resolve_policy
@@ -112,7 +123,10 @@ class NekboneCase:
                 # the solver all live in it (Eq.-2 streams are billed here).
                 self.dtype = policy.storage_dtype
         self.ax_impl_requested = self.ax_impl
-        if self.ax_impl == "auto":
+        if self.ax_impl == "auto" and self.components > 1:
+            # the coupled vector route runs on the v2 kernels alone
+            self.ax_impl = "pallas_fused_cg_v2"
+        elif self.ax_impl == "auto":
             from repro.kernels import autotune as _autotune
 
             self.ax_impl = _autotune.pick_pipeline(

@@ -15,6 +15,9 @@ Routes (every driver returns :class:`repro.core.cg.SolveResult`):
                    an explicitly batched RHS, unpreconditioned
 ``block_loop``     b > 1 with a preconditioner or a non-v2 pipeline:
                    per-RHS solves through this table, stacked
+``vector``         a vector case (``components`` > 1) on the v2 pipeline,
+                   plain or Jacobi: the coupled block driver
+                   (cg_block_coupled_*), one Krylov solve over the field
 ``ir``             refined-precision fixed-iters (cg_ir_fixed_iters)
 ``sstep``          v3 matrix-powers cycles (cg_sstep_fixed_iters;
                    tol-driven via the per-cycle host sync)
@@ -22,8 +25,14 @@ Routes (every driver returns :class:`repro.core.cg.SolveResult`):
 ``v2_tol``         tolerance-driven fused v2 (P)CG (cg_fused_tol)
 ``v1``             fused v1 fixed-iters
 ``reference``      XLA reference CG (cg / cg_fixed_iters), optional
-                   reference preconditioner
+                   reference preconditioner; coupled over the components
+                   of a vector case
 =================  ======================================================
+
+A vector case (``NekboneCase.components`` = 3, CEED BP6) takes one
+``(3, E, n, n, n)`` field per solve: one linear system whose dots,
+residual measure and stopping rule run over the whole field.  A batch of
+vector fields is refused.
 """
 from __future__ import annotations
 
@@ -77,6 +86,20 @@ def _drive_block_loop(case, f, *, b, niter, tol, max_iter, pc_name):
         achieved_rtol=jnp.stack([p.achieved_rtol for p in parts]),
         rnorm=jnp.stack([p.rnorm for p in parts]),
         pipeline=parts[0].pipeline, precond=parts[0].precond)
+
+
+def _drive_vector(case, f, *, b, niter, tol, max_iter, pc_name):
+    from repro.core.cg_block import (cg_block_coupled_fixed_iters,
+                                     cg_block_coupled_tol)
+
+    spec = case.precond_spec(pc_name) if pc_name else None
+    if niter is not None:
+        return cg_block_coupled_fixed_iters(
+            f, D=case.D, g=case.box_fields(), grid=case.grid, niter=niter,
+            precond=spec, precision=case.precision)
+    return cg_block_coupled_tol(
+        f, D=case.D, g=case.box_fields(), grid=case.grid, tol=tol,
+        max_iter=max_iter, precond=spec, precision=case.precision)
 
 
 def _drive_ir(case, f, *, b, niter, tol, max_iter, pc_name):
@@ -145,17 +168,28 @@ def _drive_v1(case, f, *, b, niter, tol, max_iter, pc_name):
 
 
 def _drive_reference(case, f, *, b, niter, tol, max_iter, pc_name):
+    A = case.ax_full
     M = case._reference_preconditioner(pc_name)
+    if case.components > 1:
+        # one system over the components: the operator and the
+        # preconditioner act per component, the dots over the whole field.
+        A = _per_component(A)
+        M = None if M is None else _per_component(M)
     if niter is not None:
-        return cg_mod.cg_fixed_iters(case.ax_full, f, niter=niter,
-                                     dot=case.dot(), precond=M)
-    return cg_mod.cg(case.ax_full, f, tol=tol, max_iter=max_iter,
-                     dot=case.dot(), precond=M)
+        return cg_mod.cg_fixed_iters(A, f, niter=niter, dot=case.dot(),
+                                     precond=M)
+    return cg_mod.cg(A, f, tol=tol, max_iter=max_iter, dot=case.dot(),
+                     precond=M)
+
+
+def _per_component(op: Callable) -> Callable:
+    return lambda u: jnp.stack([op(u[j]) for j in range(u.shape[0])])
 
 
 REGISTRY: dict[str, Callable] = {
     "block": _drive_block,
     "block_loop": _drive_block_loop,
+    "vector": _drive_vector,
     "ir": _drive_ir,
     "sstep": _drive_sstep,
     "v2": _drive_v2,
@@ -179,6 +213,13 @@ def route_name(case, *, b: int = 1, niter: int | None = None,
         refined = resolve_policy(case.precision).refine
     fused_v2_family = case.ax_impl in ("pallas_fused_cg_v2",
                                        "pallas_sstep_v3")
+    if case.components > 1:
+        if b > 1:
+            raise ValueError(_VECTOR_BATCH)
+        if case.ax_impl == "pallas_fused_cg_v2" and not refined \
+                and pc_name in (None, "jacobi"):
+            return "vector"
+        return "reference"
     if b > 1:
         # the batched kernels are the (unpreconditioned, non-refined) v2
         # pipeline; everything else solves per RHS through this table.
@@ -214,6 +255,10 @@ def route_name(case, *, b: int = 1, niter: int | None = None,
     return "reference"
 
 
+_VECTOR_BATCH = ("a vector case solves one (components, E, n, n, n) field "
+                 "per call; batches of vector fields are not supported")
+
+
 def solve_case(case, f: jnp.ndarray, *, b: int | None = None,
                niter: int | None = None, tol: float = 1e-8,
                max_iter: int = 1000,
@@ -229,7 +274,16 @@ def solve_case(case, f: jnp.ndarray, *, b: int | None = None,
     """
     pc_name = case._precond_name(precond)
     f = jnp.asarray(f)
-    batched = f.ndim == 5
+    comps = case.components
+    if comps > 1:
+        if b not in (None, 1) or f.ndim > 5:
+            raise ValueError(_VECTOR_BATCH)
+        want = (comps,) + tuple(case.mask.shape)
+        if f.shape != want:
+            raise ValueError(f"a {comps}-component case needs a field of "
+                             f"shape {want}; got {f.shape}")
+        b = 1
+    batched = f.ndim == 5 and comps == 1
     if b is None:
         b = f.shape[0] if batched else 1
     if batched and f.shape[0] != b:
@@ -242,8 +296,9 @@ def solve_case(case, f: jnp.ndarray, *, b: int | None = None,
 
     route = route_name(case, b=b, niter=niter, pc_name=pc_name)
     kw = dict(b=b, niter=niter, tol=tol, max_iter=max_iter, pc_name=pc_name)
-    with _trace.span("solve", route=route, b=b, niter=niter,
-                     precond=pc_name, ax_impl=getattr(case, "ax_impl", None)):
+    with _trace.span("solve", route=route, b=b, components=comps,
+                     niter=niter, precond=pc_name,
+                     ax_impl=getattr(case, "ax_impl", None)):
         if _trace.active() is None:    # no recorder: the plain dispatch
             res = REGISTRY[route](case, f_in, **kw)
         else:
@@ -329,6 +384,8 @@ def solve(case_or_config, f: jnp.ndarray | None = None, *,
         b = getattr(case, "b", 1)
     if f is None:
         _, f1 = case.manufactured()
+        if case.components > 1:
+            f1 = jnp.stack([f1] * case.components)
         f = f1 if (b is None or b == 1) else jnp.stack([f1] * b)
     return solve_case(case, f, b=b, niter=niter,
                       tol=1e-8 if tol is None else tol,

@@ -55,6 +55,8 @@ __all__ = ["GRID_ORDERS", "to_lanes", "from_lanes", "metric_lanes",
            "nekbone_sstep_update_kernel", "nekbone_sstep_update_pallas",
            "sstep_extend_field", "sstep_extend_zfactor",
            "nekbone_pcg_update_kernel", "nekbone_pcg_update_pallas",
+           "nekbone_pcg_update_block_kernel",
+           "nekbone_pcg_update_block_pallas",
            "nekbone_cheb_apply_kernel", "nekbone_cheb_apply_pallas",
            "nekbone_interp_kernel", "nekbone_interp_pallas"]
 
@@ -585,12 +587,12 @@ def nekbone_ax_pap_pallas(p: jnp.ndarray, D: jnp.ndarray, Dt: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def _slab_front(src, dst, kf_ref, kb_ref, d_ref, g, mask, pm_ref, fl, scr,
-                f, *, ex, ey, nz):
+                f, *, ex, ey, nz, lead: tuple = ()):
     """Masked operator, the pre-assembly ``sum(src * w)`` partial, and the
-    in-block assembly of one block of ``nz`` slabs into ``dst``; returns
-    the partial as ``(1, 1)``.  ``mask(k)`` is layer k's Dirichlet mask;
-    ``scr`` four scratch fields in the accumulation dtype (the last holds
-    the output until its assembly is complete)."""
+    in-block assembly of one block of ``nz`` slabs into ``dst[lead]``;
+    returns the partial as ``(1, 1)``.  ``mask(k)`` is layer k's Dirichlet
+    mask; ``scr`` four scratch fields in the accumulation dtype (the last
+    holds the output until its assembly is complete)."""
     def post(k, w, acc):
         v = w * mask(k)
         # continuity identity (DESIGN.md §3.2): the partial must see the
@@ -599,27 +601,31 @@ def _slab_front(src, dst, kf_ref, kb_ref, d_ref, g, mask, pm_ref, fl, scr,
 
     ur, us, ut, wacc = scr
     acc = _apply(src, wacc, kf_ref, kb_ref, d_ref, g, (ur, us, ut), f,
-                 post=post, carry=jnp.zeros(dst.shape[1:], f))
+                 post=post, carry=jnp.zeros(wacc.shape[1:], f))
     _ds_z(wacc, fl, slab=ex * ey, nz=nz)
 
     def store(k, c):
-        dst[k] = wacc[k].astype(dst.dtype)
+        dst[lead + (k,)] = wacc[k].astype(dst.dtype)
         return c
 
-    jax.lax.fori_loop(0, dst.shape[0], store, 0)
+    jax.lax.fori_loop(0, wacc.shape[0], store, 0)
     return _total(acc)
 
 
-def _direction(p_out, r_ref, p_ref, beta, f):
-    """``p_out = r + beta * p_prev``, rounded through the storage dtype: the
-    update kernel applies alpha to the stored p, so w must be A of exactly
-    that vector (identity for f32/f64, load-bearing for bf16)."""
+def _direction(p_out, r_ref, p_ref, beta, f, *lead):
+    """``p_out = r + beta * p_prev`` at ``lead``, rounded through the
+    storage dtype: the update kernel applies alpha to the stored p, so w
+    must be A of exactly that vector (identity for f32/f64, load-bearing
+    for bf16).  The RHS index goes into the load and store index, never
+    into a ref view: Mosaic refuses a view of a ref whose n^2 rows are not
+    a multiple of 8 (n = 10) once its block spans more than 128 lanes."""
     def body(k, c):
-        p_out[k] = (r_ref[k].astype(f)
-                    + beta * p_ref[k].astype(f)).astype(p_out.dtype)
+        i = lead + (k,)
+        p_out[i] = (r_ref[i].astype(f)
+                    + beta * p_ref[i].astype(f)).astype(p_out.dtype)
         return c
 
-    jax.lax.fori_loop(0, p_out.shape[0], body, 0)
+    jax.lax.fori_loop(0, p_out.shape[len(lead)], body, 0)
 
 
 def nekbone_ax_slab_kernel(p_ref, r_ref, kf_ref, kb_ref, d_ref, g_ref,
@@ -862,13 +868,12 @@ def nekbone_ax_slab_block_kernel(p_ref, r_ref, kf_ref, kb_ref, d_ref, g_ref,
     fl = fl_ref[...]
     paps = []
     for j in range(nrhs):
-        pj, wj = p_out.at[j], w_ref.at[j]
-        _direction(pj, r_ref.at[j], p_ref.at[j], beta_ref[0, j], f)
+        _direction(p_out, r_ref, p_ref, beta_ref[0, j], f, j)
         paps.append(_slab_front(
-            lambda k, pj=pj: pj[k].astype(f), wj, kf_ref, kb_ref, d_ref,
-            lambda m, k: g_ref[m, k].astype(f),
+            lambda k, j=j: p_out[j, k].astype(f), w_ref, kf_ref, kb_ref,
+            d_ref, lambda m, k: g_ref[m, k].astype(f),
             lambda k: mz_ref[k].astype(f) * mxy, pm_ref, fl, scr, f,
-            ex=ex, ey=ey, nz=sz))
+            ex=ex, ey=ey, nz=sz, lead=(j,)))
         _boundary_planes(w_ref, bot_ref, top_ref, ex * ey, j)
     pap_ref[0] = _lane_row(paps).astype(pap_ref.dtype)
 
@@ -1317,6 +1322,20 @@ def nekbone_sstep_update_pallas(x: jnp.ndarray, p: jnp.ndarray,
 # v3 halo machinery (k ghost slabs per block side) in one slab residency.
 # ---------------------------------------------------------------------------
 
+def _pcg_update_one(x, p, z, w, addb, addt, alpha, invd, diag, c, z_dtype):
+    """Stitch + both axpys + the ``r·c·z`` and ``r·c·r`` partials of one
+    Jacobi-PCG right-hand side (``diag = 1 / invd``, shared by a batch)."""
+    v = _stitch(w, addb, addt)
+    x = [a + alpha * b for a, b in zip(x, p)]
+    # both partials see the *stored* z (§7 rule 2).
+    z = [(a - alpha * (i * b)).astype(z_dtype).astype(a.dtype)
+         for a, i, b in zip(z, invd, v)]
+    zcz = [a * ck * a for a, ck in zip(z, c)]
+    rtz = _total(sum(t * d for t, d in zip(zcz, diag)))
+    rcr = _total(sum(t * d * d for t, d in zip(zcz, diag)))
+    return x, z, rtz, rcr
+
+
 def nekbone_pcg_update_kernel(x_ref, p_ref, z_ref, w_ref, addb_ref,
                               addt_ref, alpha_ref, invd_ref, cxy_ref,
                               cz_ref, x_out, z_out, rtz_ref, rcr_ref, *,
@@ -1334,21 +1353,15 @@ def nekbone_pcg_update_kernel(x_ref, p_ref, z_ref, w_ref, addb_ref,
     of ``r`` plus the ``invd`` field and two ``(1, 1, 1)`` partials.
     """
     f = _accum(x_ref.dtype, acc_dtype)
-    alpha = alpha_ref[0, 0]
     c = _box_layers(cz_ref, cxy_ref[...].astype(f), f)
-    v = _stitch(_load(w_ref, f), addb_ref[0].astype(f),
-                addt_ref[0].astype(f))
     invd = _load(invd_ref, f)
-    x = [a + alpha * b for a, b in zip(_load(x_ref, f), _load(p_ref, f))]
-    # both partials see the *stored* z (§7 rule 2).
-    z = [(a - alpha * (i * b)).astype(z_out.dtype).astype(f)
-         for a, i, b in zip(_load(z_ref, f), invd, v)]
     diag = [1.0 / i for i in invd]          # exact where invd == 1 (masked)
-    zcz = [a * ck * a for a, ck in zip(z, c)]
-    rtz_ref[0] = _total(sum(t * d for t, d in zip(zcz, diag))
-                        ).astype(rtz_ref.dtype)
-    rcr_ref[0] = _total(sum(t * d * d for t, d in zip(zcz, diag))
-                        ).astype(rcr_ref.dtype)
+    x, z, rtz, rcr = _pcg_update_one(
+        _load(x_ref, f), _load(p_ref, f), _load(z_ref, f), _load(w_ref, f),
+        addb_ref[0].astype(f), addt_ref[0].astype(f), alpha_ref[0, 0],
+        invd, diag, c, z_out.dtype)
+    rtz_ref[0] = rtz.astype(rtz_ref.dtype)
+    rcr_ref[0] = rcr.astype(rcr_ref.dtype)
     _store(x_out, x)
     _store(z_out, z)
 
@@ -1393,6 +1406,84 @@ def nekbone_pcg_update_pallas(x: jnp.ndarray, p: jnp.ndarray,
         interpret=interpret,
         operands=(x, p, z, w, addb, addt,
                   jnp.asarray(alpha, f).reshape(1, 1), invd,
+                  _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
+    return x, z, _part(rtz), _part(rcr)
+
+
+def nekbone_pcg_update_block_kernel(x_ref, p_ref, z_ref, w_ref, addb_ref,
+                                    addt_ref, alpha_ref, invd_ref, cxy_ref,
+                                    cz_ref, x_out, z_out, rtz_ref, rcr_ref,
+                                    *, nrhs: int,
+                                    acc_dtype: str | None = None):
+    """Batched Jacobi-PCG back-half: ``nekbone_pcg_update_kernel`` over
+    ``nrhs`` right-hand sides that share one operator.
+
+    The weight ``c`` and the diagonal ``invd`` (with its inverse) are read
+    once per slab residency and shared by every RHS; stitch, axpys and the
+    two partials run per RHS (``alpha_ref`` (1, b) SMEM, ``rtz_ref`` and
+    ``rcr_ref`` (1, 1, b)).  Vector refs carry a leading ``nrhs`` axis.
+    """
+    f = _accum(x_ref.dtype, acc_dtype)
+    c = _box_layers(cz_ref, cxy_ref[...].astype(f), f)
+    invd = _load(invd_ref, f)
+    diag = [1.0 / i for i in invd]
+    rtzs, rcrs = [], []
+    for j in range(nrhs):
+        x, z, rtz, rcr = _pcg_update_one(
+            _load(x_ref, f, j), _load(p_ref, f, j), _load(z_ref, f, j),
+            _load(w_ref, f, j), addb_ref[j, 0].astype(f),
+            addt_ref[j, 0].astype(f), alpha_ref[0, j], invd, diag, c,
+            z_out.dtype)
+        rtzs.append(rtz)
+        rcrs.append(rcr)
+        _store(x_out, x, j)
+        _store(z_out, z, j)
+    rtz_ref[0] = _lane_row(rtzs).astype(rtz_ref.dtype)
+    rcr_ref[0] = _lane_row(rcrs).astype(rcr_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "grid", "sz", "interpret",
+                                             "acc_dtype"))
+def nekbone_pcg_update_block_pallas(x: jnp.ndarray, p: jnp.ndarray,
+                                    z: jnp.ndarray, w: jnp.ndarray,
+                                    addb: jnp.ndarray, addt: jnp.ndarray,
+                                    alpha: jnp.ndarray, invd: jnp.ndarray,
+                                    cx: jnp.ndarray, cy: jnp.ndarray,
+                                    cz: jnp.ndarray, *, n: int,
+                                    grid: tuple[int, int, int], sz: int,
+                                    interpret: bool = False,
+                                    acc_dtype: str | None = None):
+    """Multi-output pallas_call for the batched Jacobi-PCG update kernel.
+
+    Args mirror :func:`nekbone_pcg_update_pallas` with a leading RHS axis
+    on the vector fields ((b, n, n^2, E)) and shifted planes ((b, EZ//sz,
+    n^2, EX*EY)), a (1, b) ``alpha``, and one shared (n, n^2, E)
+    ``invd``.  Returns ``(x_new, z_new, rtz_parts, rcr_parts)`` with
+    partials (EZ//sz, b).
+    """
+    ex, ey, ez = grid
+    nrhs, E = x.shape[0], x.shape[-1]
+    assert E == ex * ey * ez and ez % sz == 0, (grid, sz, E)
+    slab = ex * ey
+    be = sz * slab
+    nblk = ez // sz
+    f = _accum(x.dtype, acc_dtype)
+    field = _field_spec(n, be, (nrhs,))
+    plane = _plane_spec(n, slab, (nrhs,))
+    x, z, rtz, rcr = _call(
+        functools.partial(nekbone_pcg_update_block_kernel, nrhs=nrhs,
+                          acc_dtype=acc_dtype),
+        name=f"nekbone_pcg_update_b{nrhs}_n{n}_sz{sz}{_acc_tag(acc_dtype)}",
+        grid=(nblk,),
+        in_specs=[field] * 4 + [plane, plane, _SMEM, _field_spec(n, be),
+                                _full_spec((n * n, be)), _lane_spec(n, be)],
+        out_specs=(field, field, _part_spec(nrhs), _part_spec(nrhs)),
+        out_shape=(jax.ShapeDtypeStruct((nrhs, n, n * n, E), x.dtype),
+                   jax.ShapeDtypeStruct((nrhs, n, n * n, E), z.dtype),
+                   _part_shape(nblk, f, nrhs), _part_shape(nblk, f, nrhs)),
+        interpret=interpret,
+        operands=(x, p, z, w, addb, addt,
+                  jnp.asarray(alpha, f).reshape(1, nrhs), invd,
                   _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
     return x, z, _part(rtz), _part(rcr)
 

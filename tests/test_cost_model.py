@@ -132,6 +132,27 @@ def test_pcg_stream_budgets():
     assert PIPELINE_STREAMS["fused_v2_cheb"] == (13, 5)
 
 
+def test_multi_rhs_jacobi_stream_books():
+    """The coupled b-component Jacobi solve (CEED BP6) as its kernels are
+    laid out: 6b + 4 reads and 4b writes per iteration, the 10 + 4 rung at
+    b = 1; the metric and Jacobi diagonals are the shared streams."""
+    import pytest
+
+    from repro.core.cost import (JACOBI_V2_SHARED_STREAMS,
+                                 PIPELINE_STREAMS, multi_rhs_streams)
+
+    assert JACOBI_V2_SHARED_STREAMS == 4.0
+    assert multi_rhs_streams(1, "fused_v2_jacobi") == \
+        PIPELINE_STREAMS["fused_v2_jacobi"]
+    for b in (1, 2, 3, 4, 8):
+        r, w = multi_rhs_streams(b, "fused_v2_jacobi")
+        assert b * r == pytest.approx(6 * b + 4)
+        assert b * w == 4 * b
+    # 34 streams for three components, against 3 x 14 = 42 solved apart
+    assert 3 * sum(multi_rhs_streams(3, "fused_v2_jacobi")) == \
+        pytest.approx(34)
+
+
 def test_cheb_halo_and_flops_scaling():
     from repro.core.cost import (cheb_effective_streams, cheb_flops_per_dof,
                                  cheb_halo_streams)

@@ -6,6 +6,7 @@ hand-counted programs and the gate semantics on one cheap pipeline.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.obs import drift
@@ -129,3 +130,68 @@ def test_pipeline_bytes_within_calibrated_band(pipeline):
     kernel layout: no per-iteration transpose pushes them out of band."""
     row = drift.check_bytes(pipeline)
     assert row.ok, row.detail
+
+
+# ---------------------------------------------------------------------------
+# the multi-RHS Jacobi books against what the block kernels' specs move
+# ---------------------------------------------------------------------------
+
+def _kernel_field_streams(fn, arg, n, E):
+    """(reads, writes) in whole ``(n, n^2, E)`` fields of the
+    ``pallas_call`` operands and results in ``fn``'s iteration loop: what
+    the kernels' block specs stream from and to HBM."""
+    closed = jax.make_jaxpr(fn)(arg)
+    body = max((drift._loop_body(e) for e in drift._loops(closed.jaxpr,
+                                                          [])),
+               key=lambda b: sum(drift.charge_streams(b)))
+
+    def fields(vs):
+        out = 0
+        for v in vs:
+            shape = tuple(v.aval.shape)
+            if shape[-3:] == (n, n * n, E):
+                out += int(np.prod(shape[:-3], dtype=int))
+        return out
+
+    def pallas_calls(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            else:
+                for sub in drift._subjaxprs(e):
+                    yield from pallas_calls(sub)
+
+    calls = list(pallas_calls(body))
+    assert len(calls) == 2              # the slab and the update kernel
+    return (sum(fields(e.invars) for e in calls),
+            sum(fields(e.outvars) for e in calls))
+
+
+@pytest.mark.parametrize("b", [1, 3])
+def test_block_jacobi_kernels_move_the_booked_streams(b):
+    """The coupled driver's two kernels per iteration read and write
+    exactly the fields ``cost.multi_rhs_streams(b, "fused_v2_jacobi")``
+    books (per RHS, so times b), and at b = 1 the single-RHS Jacobi
+    driver's kernels move the same."""
+    from repro.core import cost
+    from repro.core.cg_block import cg_block_coupled_fixed_iters
+    from repro.core.nekbone import NekboneCase
+    from repro.core.precond import pcg_fused_v2_fixed_iters
+
+    # E = 24 elements: no operator constant of shape (n, n^2, n^2) can pass
+    # for a field
+    case = NekboneCase(n=4, grid=(2, 2, 6), ax_impl="pallas_fused_cg_v2",
+                       precond="jacobi")
+    n, E = case.n, case.mask.shape[0]
+    spec = case.precond_spec("jacobi")
+    kw = dict(D=case.D, g=case.box_fields(), grid=case.grid, niter=2,
+              precond=spec, sz=2, interpret=True)
+    f = jnp.zeros((b,) + case.mask.shape, jnp.float32)
+    r, w = _kernel_field_streams(
+        lambda f: cg_block_coupled_fixed_iters(f, **kw).x, f, n, E)
+    rb, wb = cost.multi_rhs_streams(b, "fused_v2_jacobi")
+    assert (r, w) == (pytest.approx(b * rb), b * wb)
+    if b == 1:
+        assert _kernel_field_streams(
+            lambda f: pcg_fused_v2_fixed_iters(f, **kw).x, f[0], n, E) == \
+            (r, w)

@@ -2,7 +2,8 @@
 
 No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached (``jax.experimental.topologies``).  Each case
-lowers one kernel wrapper at n=10 on the paper grids with every block size
+lowers one kernel wrapper at n=10 on the paper grids (the block kernels
+also at n=8 on CEED BP6's 16x16x32) with every block size
 the autotuner admits on a TPU backend (``autotune.candidate_*(tpu=True)``),
 compiles it with Mosaic, and checks that the kernel is in the executable.
 A compile proves nothing about results or times.
@@ -26,6 +27,7 @@ N2 = N * N
 GRID_1024 = (8, 8, 16)
 GRID_4096 = (16, 16, 16)
 GRID_4096_SLIM = (8, 8, 64)     # one chip's share of the 4-chip s-step path
+GRID_BP6 = (16, 16, 32)         # CEED BP5/BP6's mesh, at n=8
 S = 4                           # s-step depth / Chebyshev order
 
 
@@ -104,6 +106,15 @@ for grid in (GRID_1024, GRID_4096):
 for sz in autotune.candidate_slab_sizes(GRID_1024, N, nrhs=8, tpu=True):
     CASES.append(("v2_slab_block_b8", GRID_1024, sz))
     CASES.append(("v2_cg_update_block_b8", GRID_1024, sz))
+# the multi-RHS block kernels at the benchmark's grids: the coupled BP6
+# solve (n=8, b=3, block slab + batched Jacobi update) and a service drain
+# of b=2-4 at the paper's n=10 on a 16x16 cross-section
+for b in (2, 3, 4):
+    for sz in autotune.candidate_slab_sizes(GRID_4096, N, nrhs=b, tpu=True):
+        CASES.append(("slab_block", (GRID_4096, N, b), sz))
+for sz in autotune.candidate_slab_sizes(GRID_BP6, 8, nrhs=3, tpu=True):
+    CASES.append(("slab_block", (GRID_BP6, 8, 3), sz))
+    CASES.append(("pcg_update_block", (GRID_BP6, 8, 3), sz))
 for grid in (GRID_1024, GRID_4096_SLIM):
     for sz in autotune.candidate_slab_sizes_sstep(grid, N, S, tpu=True):
         CASES.append(("sstep_powers", grid, sz))
@@ -124,6 +135,8 @@ def _build(kind, where, size):
         return (lambda u, D, g: K.nekbone_ax_pallas(u, D, D.T, g, n=N,
                                                     block_e=be),
                 [(N, N2, E), (N, N), (6, N, N2, E)])
+    if kind in ("slab_block", "pcg_update_block"):
+        return _build_block(kind, *where, size)
     grid, sz = where, size
     ex, ey, ez = grid
     E = ex * ey * ez
@@ -184,6 +197,23 @@ def _build(kind, where, size):
                 [win, (N, N), gwin, (ex, N), (ey, N), mzwin, (ex, N),
                  (ey, N), (ez, N), (S + 1, 2)])
     raise ValueError(kind)
+
+
+def _build_block(kind, grid, n, b, sz):
+    """(fn, operand shapes) of a multi-RHS block kernel at degree n-1."""
+    ex, ey, ez = grid
+    E, n2 = ex * ey * ez, n * n
+    f = (b, n, n2, E)
+    axes = [(ex, n), (ey, n), (ez, n)]
+    if kind == "slab_block":
+        return (lambda p, r, D, g, mx, my, mz, beta:
+                K.nekbone_ax_slab_block_pallas(p, r, D, D.T, g, mx, my, mz,
+                                               beta, n=n, grid=grid, sz=sz),
+                [f, f, (n, n), (3, n, n2, E)] + axes + [(1, b)])
+    planes = (b, ez // sz, n2, ex * ey)
+    return (lambda *a: K.nekbone_pcg_update_block_pallas(
+        *a, n=n, grid=grid, sz=sz),
+        [f] * 4 + [planes, planes, (1, b), (n, n2, E)] + axes)
 
 
 @pytest.mark.parametrize("kind,where,size", CASES,
