@@ -305,26 +305,40 @@ def _sweep(key: tuple, cands: list, timed) -> tuple:
 _LOOP_CALLS = 16
 
 
-def _device_loop(fn, args: tuple):
+def _device_loop(fn, args: tuple, aliases: dict | None = None):
     """``(f, calls)``: ``f()`` runs ``fn(*args)`` ``calls`` =
     :data:`_LOOP_CALLS` times back to back in one jitted loop on the
     device.  Each call's smallest operand
     takes a zero computed from the previous call's smallest output, so no
-    call can be hoisted out of the loop or merged with another."""
+    call can be hoisted out of the loop or merged with another.
+
+    ``aliases`` (operand index -> result index, the kernel's in-place map,
+    e.g. ``nekbone_ax.SLAB_ALIASES``) threads those operands through the
+    loop: call k+1 takes call k's result in that slot, as a solve's loop
+    carries it, so the kernel is timed updating in place.  An operand
+    that stayed the same across calls would make XLA copy it before
+    every aliased call."""
     import jax.lax as lax
 
+    aliases = aliases or {}
     i = min(range(len(args)), key=lambda j: args[j].size)
 
     @jax.jit
     def loop(*a):
-        def body(_, t):
+        def body(_, c):
+            t, carried = c
             b = list(a)
+            for j, v in zip(aliases, carried):
+                b[j] = v
             b[i] = b[i] + t.astype(b[i].dtype)
-            out = min(jax.tree_util.tree_leaves(fn(*b)), key=lambda x: x.size)
-            return (jnp.sum(out) * 0).astype(t.dtype)
+            res = fn(*b)
+            out = min(jax.tree_util.tree_leaves(res), key=lambda x: x.size)
+            return ((jnp.sum(out) * 0).astype(t.dtype),
+                    tuple(res[k] for k in aliases.values()))
 
         return lax.fori_loop(0, _LOOP_CALLS, body,
-                             jnp.zeros((), jnp.float32))
+                             (jnp.zeros((), jnp.float32),
+                              tuple(a[j] for j in aliases)))[0]
 
     return (lambda: loop(*args)), _LOOP_CALLS
 
@@ -491,13 +505,15 @@ def _default_measure_slab(grid: tuple[int, int, int], n: int, dtype,
     mx = jnp.asarray(axis_mask_factor(ex, n), dtype)
     my = jnp.asarray(axis_mask_factor(ey, n), dtype)
     mz = jnp.asarray(axis_mask_factor(ez, n), dtype)
+    # beta = 0: each call's p is r, so the threaded p stays bounded
     beta = jnp.zeros((1, 1), _ax._accum(jnp.dtype(dtype), acc_dtype))
     Dt = D.T
 
     def make(sz: int, grid_order: str = "parallel"):
         return _device_loop(lambda *a: _ax.nekbone_ax_slab_pallas(
             *a, n=n, grid=grid, sz=sz, interpret=False, acc_dtype=acc_dtype,
-            grid_order=grid_order), (p, r, D, Dt, g3, mx, my, mz, beta))
+            grid_order=grid_order), (p, r, D, Dt, g3, mx, my, mz, beta),
+            _ax.SLAB_ALIASES)
 
     return _chained(make)
 
@@ -528,7 +544,8 @@ def _default_measure_slab_block(grid: tuple[int, int, int], n: int, dtype,
     def make(sz: int, grid_order: str = "parallel"):
         return _device_loop(lambda *a: _ax.nekbone_ax_slab_block_pallas(
             *a, n=n, grid=grid, sz=sz, interpret=False, acc_dtype=acc_dtype,
-            grid_order=grid_order), (p3, r3, D, Dt, g3, mx, my, mz, beta))
+            grid_order=grid_order), (p3, r3, D, Dt, g3, mx, my, mz, beta),
+            _ax.SLAB_ALIASES)
 
     return _chained(make)
 

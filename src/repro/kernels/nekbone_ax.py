@@ -65,6 +65,15 @@ __all__ = ["GRID_ORDERS", "to_lanes", "from_lanes", "metric_lanes",
 # sequential issue order.  Swept jointly with sz by autotune.
 GRID_ORDERS = ("parallel", "arbitrary")
 
+# In-place contract of the v2 loop kernels (DESIGN.md §3.4): operand index
+# -> output index of ``input_output_aliases``, the same at the wrapper's
+# arguments and results.  The slab kernels write p over p, the update
+# kernels x over x and r (or z) over r (or z), so a CG loop carries its
+# fields without a copy.  Each grid step reads and writes only its own
+# block of these fields; what crosses blocks leaves through bot/top.
+SLAB_ALIASES = {0: 0}
+UPDATE_ALIASES = {0: 0, 2: 1}
+
 _HI = jax.lax.Precision.HIGHEST
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
@@ -96,11 +105,14 @@ def _order_tag(grid_order: str = "parallel") -> str:
 
 def _call(kernel, *, name: str, grid, in_specs, out_specs, out_shape,
           interpret: bool, operands, grid_order: str = "parallel",
-          scratch: tuple | list = ()):
+          scratch: tuple | list = (), aliases: dict | None = None):
     """``pallas_call`` with the repo-wide VMEM limit and the f64 guard.
 
     Compiled (non-interpret) calls refuse float64 operands before they
-    reach Mosaic, which has no f64 type.
+    reach Mosaic, which has no f64 type.  ``aliases`` maps operand index
+    to output index (``input_output_aliases``): that output is written
+    over that input's buffer (the in-place contract of
+    :data:`SLAB_ALIASES` and :data:`UPDATE_ALIASES`).
     """
     if not interpret:
         f64 = [jnp.dtype(x.dtype).name for x in operands
@@ -115,7 +127,7 @@ def _call(kernel, *, name: str, grid, in_specs, out_specs, out_shape,
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret, name=name,
-        scratch_shapes=list(scratch),
+        scratch_shapes=list(scratch), input_output_aliases=aliases or {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(grid_order,),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
@@ -734,7 +746,7 @@ def nekbone_ax_slab_pallas(p: jnp.ndarray, r: jnp.ndarray, D: jnp.ndarray,
                    jax.ShapeDtypeStruct((nblk, n * n, slab), sdt),
                    _part_shape(nblk, f)),
         interpret=interpret, grid_order=grid_order,
-        scratch=_scratch(n, be, f, 4),
+        scratch=_scratch(n, be, f, 4), aliases=SLAB_ALIASES,
         operands=(p, r, kf, kb, d, g3,
                   *_slab_structure(grid, n, sz, mx, my, mz, f),
                   jnp.asarray(beta, f).reshape(1, 1)))
@@ -824,7 +836,7 @@ def nekbone_cg_update_pallas(x: jnp.ndarray, p: jnp.ndarray,
         out_shape=(jax.ShapeDtypeStruct((n, n * n, E), x.dtype),
                    jax.ShapeDtypeStruct((n, n * n, E), r.dtype),
                    _part_shape(nblk, f)),
-        interpret=interpret,
+        interpret=interpret, aliases=UPDATE_ALIASES,
         operands=(x, p, r, w, addb, addt,
                   jnp.asarray(alpha, f).reshape(1, 1),
                   _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
@@ -922,7 +934,7 @@ def nekbone_ax_slab_block_pallas(p: jnp.ndarray, r: jnp.ndarray,
                    jax.ShapeDtypeStruct((nrhs, nblk, n * n, slab), sdt),
                    _part_shape(nblk, f, nrhs)),
         interpret=interpret, grid_order=grid_order,
-        scratch=_scratch(n, be, f, 4),
+        scratch=_scratch(n, be, f, 4), aliases=SLAB_ALIASES,
         operands=(p, r, kf, kb, d, g3,
                   *_slab_structure(grid, n, sz, mx, my, mz, f),
                   jnp.asarray(beta, f).reshape(1, nrhs)))
@@ -990,7 +1002,7 @@ def nekbone_cg_update_block_pallas(x: jnp.ndarray, p: jnp.ndarray,
         out_shape=(jax.ShapeDtypeStruct((nrhs, n, n * n, E), x.dtype),
                    jax.ShapeDtypeStruct((nrhs, n, n * n, E), r.dtype),
                    _part_shape(nblk, f, nrhs)),
-        interpret=interpret,
+        interpret=interpret, aliases=UPDATE_ALIASES,
         operands=(x, p, r, w, addb, addt,
                   jnp.asarray(alpha, f).reshape(1, nrhs),
                   _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
@@ -1403,7 +1415,7 @@ def nekbone_pcg_update_pallas(x: jnp.ndarray, p: jnp.ndarray,
         out_shape=(jax.ShapeDtypeStruct((n, n * n, E), x.dtype),
                    jax.ShapeDtypeStruct((n, n * n, E), z.dtype),
                    _part_shape(nblk, f), _part_shape(nblk, f)),
-        interpret=interpret,
+        interpret=interpret, aliases=UPDATE_ALIASES,
         operands=(x, p, z, w, addb, addt,
                   jnp.asarray(alpha, f).reshape(1, 1), invd,
                   _plane_factor(cx, cy, be), _z_lanes(cz, slab)))
@@ -1481,7 +1493,7 @@ def nekbone_pcg_update_block_pallas(x: jnp.ndarray, p: jnp.ndarray,
         out_shape=(jax.ShapeDtypeStruct((nrhs, n, n * n, E), x.dtype),
                    jax.ShapeDtypeStruct((nrhs, n, n * n, E), z.dtype),
                    _part_shape(nblk, f, nrhs), _part_shape(nblk, f, nrhs)),
-        interpret=interpret,
+        interpret=interpret, aliases=UPDATE_ALIASES,
         operands=(x, p, z, w, addb, addt,
                   jnp.asarray(alpha, f).reshape(1, nrhs), invd,
                   _plane_factor(cx, cy, be), _z_lanes(cz, slab)))

@@ -350,3 +350,124 @@ def test_cg_fused_v2_tol_and_precond_stay_fused():
     assert res_pc.rnorm_history.shape == (11,)
     assert np.isfinite(np.asarray(res_pc.rnorm_history,
                                   np.float64)).all()
+
+
+# ---------------------------------------------------------------------------
+# In-place contract: the v2 loop kernels alias their carried fields
+# (kernels/nekbone_ax.SLAB_ALIASES / UPDATE_ALIASES, DESIGN.md §3.4)
+# ---------------------------------------------------------------------------
+
+_AL_GRID, _AL_N, _AL_SZ, _AL_B = (2, 2, 4), 4, 2, 3
+ALIASED = {
+    "nekbone_ax_slab_pallas": "slab",
+    "nekbone_ax_slab_block_pallas": "slab",
+    "nekbone_cg_update_pallas": "update",
+    "nekbone_cg_update_block_pallas": "update",
+    "nekbone_pcg_update_pallas": "update",
+    "nekbone_pcg_update_block_pallas": "update",
+}
+
+
+def _aliased_call(name, rng):
+    """(fn, operands) of one aliased wrapper on a small grid, interpret
+    mode, random f32 operands of the wrapper's documented shapes."""
+    from repro.kernels import nekbone_ax as K
+
+    ex, ey, ez = _AL_GRID
+    n, sz, E = _AL_N, _AL_SZ, ex * ey * ez
+    lead = (_AL_B,) if "block" in name else ()
+    b = _AL_B if lead else 1
+
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    field = lead + (n, n * n, E)
+    axes = (arr(ex, n), arr(ey, n), arr(ez, n))
+    if "slab" in name:
+        D = arr(n, n)
+        args = (arr(*field), arr(*field), D, D.T, arr(3, n, n * n, E),
+                *axes, arr(1, b))
+    else:
+        planes = lead + (ez // sz, n * n, ex * ey)
+        args = (arr(*field), arr(*field), arr(*field), arr(*field),
+                arr(*planes), arr(*planes), arr(1, b))
+        if "pcg" in name:
+            args += (arr(n, n * n, E),)
+        args += axes
+    fn = getattr(K, name)
+    return (lambda *a: fn(*a, n=n, grid=_AL_GRID, sz=sz, interpret=True),
+            args)
+
+
+@pytest.mark.parametrize("name", sorted(ALIASED))
+def test_aliased_wrapper_leaves_its_inputs_unchanged(name):
+    fn, args = _aliased_call(name, np.random.default_rng(7))
+    before = [np.array(a) for a in args]
+    first = [np.array(o) for o in fn(*args)]
+    second = [np.array(o) for o in fn(*args)]
+    for a, b in zip(args, before):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    for a, b in zip(first, second):
+        np.testing.assert_array_equal(a, b)
+
+
+def _pallas_eqns(jaxpr):
+    """Every ``pallas_call`` equation of a (closed) jaxpr, nested ones too."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_eqns(sub)
+
+
+@pytest.mark.parametrize("name", sorted(ALIASED))
+def test_wrapper_pallas_call_carries_the_alias_map(name):
+    import jax
+
+    from repro.kernels import nekbone_ax as K
+
+    fn, args = _aliased_call(name, np.random.default_rng(0))
+    (eqn,) = list(_pallas_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    want = K.SLAB_ALIASES if ALIASED[name] == "slab" else K.UPDATE_ALIASES
+    assert dict(eqn.params["input_output_aliases"]) == want
+
+
+@pytest.mark.parametrize("driver", ["jacobi", "coupled"])
+def test_drivers_match_a_run_with_the_aliases_stripped(driver, monkeypatch):
+    """In place or not, the loop computes the same iterates bitwise."""
+    import jax
+
+    from repro.core.cg_block import cg_block_coupled_tol
+    from repro.core.precond import cg_fused_tol
+    from repro.kernels import nekbone_ax as K
+
+    case = NekboneCase(n=4, grid=(2, 2, 4), dtype=jnp.float32,
+                       ax_impl="pallas_fused_cg_v2")
+    rng = np.random.default_rng(3)
+    u = jnp.asarray(rng.normal(size=(3,) + case.mask.shape), jnp.float32)
+    f = jnp.stack([ds_sum_local(u[j], case.grid) for j in range(3)]) \
+        * case.mask
+    kw = dict(D=case.D, g=case.box_fields(), grid=case.grid, tol=1e-5,
+              max_iter=60, precond=case.precond_spec("jacobi"), sz=2,
+              interpret=True)
+
+    def solve():
+        if driver == "jacobi":
+            return cg_fused_tol(f[0], **kw)
+        return cg_block_coupled_tol(f, **kw)
+
+    aliased = solve()
+    jax.clear_caches()
+    monkeypatch.setattr(K, "SLAB_ALIASES", {})
+    monkeypatch.setattr(K, "UPDATE_ALIASES", {})
+    try:
+        plain = solve()
+    finally:
+        jax.clear_caches()
+    assert 0 < int(aliased.iters_taken) < 60
+    for a, b in ((aliased.x, plain.x), (aliased.iters_taken,
+                                          plain.iters_taken),
+                 (aliased.history, plain.history)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -6,13 +6,16 @@ lowers one kernel wrapper at n=10 on the paper grids (the block kernels
 also at n=8 on CEED BP6's 16x16x32) with every block size
 the autotuner admits on a TPU backend (``autotune.candidate_*(tpu=True)``),
 compiles it with Mosaic, and checks that the kernel is in the executable.
-A compile proves nothing about results or times.
+A compile proves nothing about results or times.  The loop cases compile
+whole CG drivers and the autotuner's slab sweep loop, and check that the
+loop carries its fields in place: no field-sized ``copy`` in the program.
 
 The topology is described inside a module fixture (never at import) so
 that every test worker collects the same tests; the persistent compilation
 cache is off around these compiles.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -240,3 +243,81 @@ def test_autotuner_admits_no_16x16_sstep_window():
 def test_sstep_powers_16x16_window_exceeds_vmem(compile_tpu):
     fn, shapes = _build("sstep_powers", GRID_4096, 1)
     assert "tpu_custom_call" in compile_tpu(fn, *shapes)
+
+
+# ---------------------------------------------------------------------------
+# The CG loops carry their fields in place (kernels/nekbone_ax.SLAB_ALIASES,
+# UPDATE_ALIASES): without the aliases XLA copies x, r (or z) and p out of
+# the kernels' buffers into the loop carry on every iteration.
+# ---------------------------------------------------------------------------
+
+def _axes(grid, n):
+    return [(g, n) for g in grid] * 2
+
+
+def _loop_case(kind):
+    """(fn, operand shapes, carried field shape) of one CG loop."""
+    from repro.core import cg_block, cg_fused, precond
+
+    flags = dict(interpret=False, acc_name="float32", x_name="float32")
+    if kind == "cg_fused_v2":
+        n, grid = N, GRID_4096
+        E = grid[0] * grid[1] * grid[2]
+        return (lambda b, D, g, *ax: cg_fused._cg_fused_v2(
+            b, D, D.T, g, *ax, n=n, grid=grid, niter=100, sz=2, **flags),
+            [(E, n, n, n), (n, n), (E, 3, n ** 3)] + _axes(grid, n),
+            (n, n * n, E))
+    n, grid = 8, GRID_BP6
+    E = grid[0] * grid[1] * grid[2]
+    if kind == "pcg_jacobi":
+        return (lambda b, invd, D, g, *ax: precond._pcg_jacobi(
+            b, invd, D, D.T, g, *ax, 1e-12, n=n, grid=grid, max_iter=1000,
+            sz=4, **flags),
+            [(E, n, n, n), (E, n ** 3), (n, n), (E, 3, n ** 3)]
+            + _axes(grid, n), (n, n * n, E))
+    if kind == "cg_block_coupled":
+        return (lambda B, invd, D, g, *ax: cg_block._cg_block_coupled(
+            B, invd, D, D.T, g, *ax, 1e-12, n=n, grid=grid, max_iter=1000,
+            sz=2, **flags),
+            [(3, E, n, n, n), (E, n ** 3), (n, n), (E, 3, n ** 3)]
+            + _axes(grid, n), (3, n, n * n, E))
+    assert kind == "slab_sweep_loop"
+
+    def sweep(p, r, D, g, mx, my, mz, beta):
+        f, _ = autotune._device_loop(
+            lambda *a: K.nekbone_ax_slab_pallas(*a, n=n, grid=grid, sz=4),
+            (p, r, D, D.T, g, mx, my, mz, beta), K.SLAB_ALIASES)
+        return f()
+
+    field = (n, n * n, E)
+    return (sweep, [field, field, (n, n), (3,) + field]
+            + _axes(grid, n)[:3] + [(1, 1)], field)
+
+
+def _loop_field_copies(hlo: str, shape) -> list[str]:
+    """The ``copy`` ops (and copy fusions) of an f32 ``shape`` inside the
+    program's while-loop bodies: the ones paid on every iteration."""
+    from repro.launch.hlo_analysis import parse_computations
+
+    comps, _ = parse_computations(hlo)
+    bodies = {b for lines in comps.values() for line in lines
+              for b in re.findall(r"\bbody=%?([\w.\-]+)", line)}
+    assert bodies, "no while loop in the program"
+    field = "f32[" + ",".join(map(str, shape)) + "]"
+    out = []
+    for line in (ln for b in bodies for ln in comps[b]):
+        m = re.match(r"\s*(ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+        if m and field in m.group(3) and (
+                m.group(4) in ("copy", "copy-start")
+                or m.group(2).startswith("copy")):
+            out.append(line.strip()[:120])
+    return out
+
+
+@pytest.mark.parametrize("kind", ["cg_fused_v2", "pcg_jacobi",
+                                  "cg_block_coupled", "slab_sweep_loop"])
+def test_cg_loop_carries_its_fields_in_place(compile_tpu, kind):
+    fn, shapes, field = _loop_case(kind)
+    hlo = compile_tpu(fn, *shapes)
+    assert "tpu_custom_call" in hlo
+    assert _loop_field_copies(hlo, field) == []
