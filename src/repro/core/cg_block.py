@@ -261,15 +261,9 @@ def _prepare_block(B, D, g, grid, mask, c, sz, grid_order, interpret,
     grid = tuple(grid)
     if interpret is None:
         interpret = kernel_ops.default_interpret()
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_slab_config(
-            grid, n, B.dtype, acc_dtype=policy.accum, precond=precond,
-            nrhs=nrhs)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, B.dtype,
-                                    acc_dtype=policy.accum, precond=precond,
-                                    nrhs=nrhs)
-    grid_order = "parallel" if grid_order is None else grid_order
+    sz, grid_order = _autotune.slab_config(
+        "slab", grid, n, B.dtype, acc_dtype=policy.accum, precond=precond,
+        nrhs=nrhs, sz=sz, grid_order=grid_order)
 
     _check_box_fields(grid, n, mask, c)
     (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(grid, n,

@@ -339,11 +339,8 @@ def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
       mask/c: optional — the kernels rebuild both from per-axis factors;
              when passed (concrete) they are validated against the
              structural fields and otherwise unused.
-      sz:    slabs per block; default: autotuned divisor of EZ
-             (kernels/autotune.pick_slab_sz).
-      grid_order: grid iteration order for the slab kernel (default:
-             autotuned jointly with sz when both are None,
-             kernels/autotune.pick_slab_config).
+      sz, grid_order: slab split and the slab kernel's grid iteration
+             order (default: kernels/autotune.slab_config).
       interpret: force Pallas interpret mode (default: off-TPU detection).
       precision: policy name / policy / ``None`` (infer from ``b.dtype``):
              b and the metric are cast to the storage dtype, both kernels
@@ -362,13 +359,9 @@ def cg_fused_v2_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray,
         grid = tuple(grid)
         if interpret is None:
             interpret = kernel_ops.default_interpret()
-        if sz is None and grid_order is None:
-            sz, grid_order = _autotune.pick_slab_config(
-                grid, n, b.dtype, acc_dtype=policy.accum)
-        elif sz is None:
-            sz = _autotune.pick_slab_sz(grid, n, b.dtype,
-                                        acc_dtype=policy.accum)
-        grid_order = "parallel" if grid_order is None else grid_order
+        sz, grid_order = _autotune.slab_config(
+            "slab", grid, n, b.dtype, acc_dtype=policy.accum, sz=sz,
+            grid_order=grid_order)
 
         _check_box_fields(grid, n, mask, c)
         (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(

@@ -290,11 +290,9 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
       s:     iterations per cycle (s >= 1; s=1 degenerates to the v2
              stream budget, s=4 is the tuned default — DESIGN.md §8).
       mask/c: optional structural fields, validated like the v2 path.
-      sz:    slabs per block (default: joint (sz, s) autotune,
-             `kernels/autotune.pick_slab_sz_sstep`).
-      grid_order: powers-kernel grid iteration order (default:
-             autotuned jointly with sz when both are None,
-             `kernels/autotune.pick_sstep_config`).
+      sz, grid_order: slab split and the powers kernel's grid iteration
+             order (default: `kernels/autotune.slab_config`, kind
+             ``"sstep"``, keyed by s).
       theta: basis scale override (default: power-iteration ||A|| estimate).
       tol:   optional tolerance for early exit (DESIGN.md §9.4): stop, as
              :func:`repro.core.cg.cg` does, *before* the first iteration
@@ -329,13 +327,9 @@ def cg_sstep_fixed_iters(b: jnp.ndarray, *, D: jnp.ndarray, g: jnp.ndarray,
     ex, ey, ez = grid
     if interpret is None:
         interpret = kernel_ops.default_interpret()
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_sstep_config(
-            grid, n, s, b.dtype, acc_dtype=policy.accum)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz_sstep(grid, n, s, b.dtype,
-                                          acc_dtype=policy.accum)
-    grid_order = "parallel" if grid_order is None else grid_order
+    sz, grid_order = _autotune.slab_config(
+        "sstep", grid, n, b.dtype, acc_dtype=policy.accum, depth=s, sz=sz,
+        grid_order=grid_order)
 
     _check_box_fields(grid, n, mask, c)
     (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(grid, n,

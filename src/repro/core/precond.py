@@ -714,19 +714,13 @@ def _prepare(b, D, g, grid, mask, c, sz, interpret, precision, precond,
         interpret = kernel_ops.default_interpret()
     # only Jacobi changes the slab kernels' working set (the update
     # kernel holds the diagonal block); Chebyshev runs the unmodified
-    # v2 kernels — its own apply kernel is tuned by pick_slab_sz_cheb
-    # — so it shares the plain pick rather than re-measuring.
+    # v2 kernels — its own apply kernel has the "cheb" kind — so it
+    # shares the plain pick rather than re-measuring.
     jac = (isinstance(precond, JacobiPrecond)
            or (isinstance(precond, str) and precond == "jacobi"))
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_slab_config(
-            grid, n, b.dtype, acc_dtype=policy.accum,
-            precond="jacobi" if jac else None)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, b.dtype,
-                                    acc_dtype=policy.accum,
-                                    precond="jacobi" if jac else None)
-    grid_order = "parallel" if grid_order is None else grid_order
+    sz, grid_order = _autotune.slab_config(
+        "slab", grid, n, b.dtype, acc_dtype=policy.accum,
+        precond="jacobi" if jac else None, sz=sz, grid_order=grid_order)
     _check_box_fields(grid, n, mask, c)
     (mx, my, mz), (cx, cy, cz) = kernel_ops.slab_axis_factors(grid, n,
                                                               b.dtype)
@@ -748,8 +742,7 @@ def _resolve_precond(precond, *, D, g, grid, mask, c):
 
 def _dispatch(b, precond, tol2, max_iter, *, policy, n, grid, sz, interpret,
               m_factors, c_factors, D_op, g3,
-              cheb_sz: int | None = None,
-              grid_order: str = "parallel") -> CGResult:
+              grid_order: str, cheb_sz: int | None = None) -> CGResult:
     mx, my, mz = m_factors
     cx, cy, cz = c_factors
     common = dict(n=n, grid=grid, max_iter=max_iter, sz=sz,
@@ -765,10 +758,9 @@ def _dispatch(b, precond, tol2, max_iter, *, policy, n, grid, sz, interpret,
         return _pcg_jacobi(b, invd, D_op, D_op.T, g3, mx, my, mz,
                            cx, cy, cz, tol2, **common)
     if isinstance(precond, ChebyshevPrecond):
-        sz_c = cheb_sz
-        if sz_c is None:
-            sz_c = _autotune.pick_slab_sz_cheb(grid, n, precond.k, b.dtype,
-                                               acc_dtype=policy.accum)
+        sz_c, _ = _autotune.slab_config(
+            "cheb", grid, n, b.dtype, acc_dtype=policy.accum,
+            depth=precond.k, sz=cheb_sz, grid_order="parallel")
         coef = jnp.asarray(precond.scalars(), policy.accum_dtype)
         return _pcg_cheb(b, D_op, D_op.T, g3, mx, my, mz, cx, cy, cz,
                          coef, tol2, sz_c=sz_c, k=precond.k, **common)
@@ -786,14 +778,15 @@ def _dispatch(b, precond, tol2, max_iter, *, policy, n, grid, sz, interpret,
         for lev in range(len(ns_t) - 1):
             with _trace.span("pmg.vcycle.level", level=lev, n=ns_t[lev],
                              k=precond.k):
-                szs.append(_autotune.pick_slab_sz(
-                    grid, ns_t[lev], b.dtype, acc_dtype=policy.accum,
-                    precond=f"pmg:{lev}"))
-                cheb_szs.append(
-                    cheb_sz if lev == 0 and cheb_sz is not None else
-                    _autotune.pick_slab_sz_cheb(grid, ns_t[lev],
-                                                precond.k, b.dtype,
-                                                acc_dtype=policy.accum))
+                szs.append(_autotune.slab_config(
+                    "slab", grid, ns_t[lev], b.dtype,
+                    acc_dtype=policy.accum, precond=f"pmg:{lev}",
+                    grid_order="parallel")[0])
+                cheb_szs.append(_autotune.slab_config(
+                    "cheb", grid, ns_t[lev], b.dtype,
+                    acc_dtype=policy.accum, depth=precond.k,
+                    sz=cheb_sz if lev == 0 else None,
+                    grid_order="parallel")[0])
         szs, cheb_szs = tuple(szs), tuple(cheb_szs)
         levels = _pmg.pmg_level_pytree(precond, grid,
                                        policy.op_storage_dtype.name,

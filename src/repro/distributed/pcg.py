@@ -270,11 +270,10 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
         raise ValueError(
             "sharded PCG needs a preconditioner; for unpreconditioned "
             "sharded solves use distributed.sstep or cg_fused_sharded")
-    if sz is None:
-        jac = isinstance(precond, JacobiPrecond)
-        sz = _autotune.pick_slab_sz(grid_local, n, b.dtype,
-                                    acc_dtype=policy.accum,
-                                    precond="jacobi" if jac else None)
+    sz, _ = _autotune.slab_config(
+        "slab", grid_local, n, b.dtype, acc_dtype=policy.accum,
+        precond="jacobi" if isinstance(precond, JacobiPrecond) else None,
+        sz=sz, grid_order="parallel")
     if ez_l % sz:
         raise ValueError(f"local EZ {ez_l} not divisible by sz {sz}")
 
@@ -323,10 +322,9 @@ def _run(b, precond, tol2, max_iter, *, D, g, grid, mask, c, sz, cheb_sz,
         if k > ez_l:
             raise ValueError(
                 f"Chebyshev halo k={k} exceeds local slab count {ez_l}")
-        sz_c = cheb_sz
-        if sz_c is None:
-            sz_c = _autotune.pick_slab_sz_cheb(grid_local, n, k, b.dtype,
-                                               acc_dtype=policy.accum)
+        sz_c, _ = _autotune.slab_config(
+            "cheb", grid_local, n, b.dtype, acc_dtype=policy.accum,
+            depth=k, sz=cheb_sz, grid_order="parallel")
         if ez_l % sz_c:
             raise ValueError(f"local EZ {ez_l} not divisible by "
                              f"cheb sz {sz_c}")

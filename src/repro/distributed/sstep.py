@@ -295,9 +295,9 @@ def cg_sstep_sharded_fixed_iters(
     grid_local = (ex, ey, ez_l)
     if interpret is None:
         interpret = kernel_ops.default_interpret()
-    if sz is None:
-        sz = _autotune.pick_slab_sz_sstep(grid_local, n, s, b.dtype,
-                                          acc_dtype=policy.accum)
+    sz, _ = _autotune.slab_config("sstep", grid_local, n, b.dtype,
+                                  acc_dtype=policy.accum, depth=s, sz=sz,
+                                  grid_order="parallel")
     if ez_l % sz:
         raise ValueError(f"local EZ {ez_l} not divisible by sz {sz}")
     if s > ez_l:

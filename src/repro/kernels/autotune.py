@@ -8,11 +8,15 @@ needs.  Two block modes exist:
 * **Flat blocks** (:func:`pick_block_e`): any power-of-two element count —
   the v1 kernels' mode, where the block never needs to know the element
   grid.
-* **Slab blocks** (:func:`pick_slab_sz`): whole z-slabs of the element box,
+* **Slab blocks** (:func:`slab_config`): whole z-slabs of the element box,
   ``block_e = sz * EX * EY`` with ``sz | EZ`` — the v2 pipeline's mode
   (DESIGN.md §3.4), where the x/y direct-stiffness summation must be
   intra-block, so the block must cover complete slabs of the z-major
-  element order.
+  element order.  Every slab-family kernel (the slab kernel, its
+  multi-RHS twin, the s-step powers and Chebyshev windows, and the
+  update and interpolation kernels that share their split) gets its
+  ``(sz, grid_order)`` from this one function, swept jointly over both;
+  a kernel kind is one row of :data:`_SLAB_KINDS`.
 
 Selection strategy (all modes):
 
@@ -56,11 +60,8 @@ import jax.numpy as jnp
 from repro.kernels import timing as _timing
 
 __all__ = ["vmem_block_e", "pick_block_e", "candidate_blocks",
-           "candidate_slab_sizes", "pick_slab_sz",
-           "candidate_slab_sizes_sstep", "pick_slab_sz_sstep",
-           "candidate_slab_sizes_cheb", "pick_slab_sz_cheb",
-           "candidate_configs", "pick_slab_config", "pick_sstep_config",
-           "pick_cheb_config", "pick_pipeline", "AUTO_V2_MIN_E",
+           "candidate_slab_sizes", "candidate_configs", "slab_config",
+           "pick_pipeline", "AUTO_V2_MIN_E",
            "SWEEP_ROUNDS", "SWEEP_CHAIN_S", "SWEEP_MARGIN",
            "clear_cache", "cache_info", "cache_path", "cache_stats"]
 
@@ -292,10 +293,16 @@ def _sweep(key: tuple, cands: list, timed) -> tuple:
                     sp.set(seconds=t)
                 ts.append(t)
     med = [_timing.median(ts) for ts in rounds]
+    return (_fastest(cands, med),
+            {_config_name(c): t for c, t in zip(cands, med)})
+
+
+def _fastest(cands: list, med: list[float]):
+    """The fastest of ``cands`` by their times ``med``, unless it beats the
+    first by no more than :data:`SWEEP_MARGIN`: then the first."""
     best = min(range(len(cands)), key=med.__getitem__)
-    if not med[best] < med[0] * (1.0 - SWEEP_MARGIN):
-        best = 0
-    return cands[best], {_config_name(c): t for c, t in zip(cands, med)}
+    return cands[best] if med[best] < med[0] * (1.0 - SWEEP_MARGIN) \
+        else cands[0]
 
 
 # kernel calls per dispatch of a sweep's device loop: enough that the
@@ -465,52 +472,44 @@ def pick_block_e(E: int, n: int, dtype=jnp.float32, *,
 
 
 # ---------------------------------------------------------------------------
-# slab blocks (v2 pipeline)
+# the slab-family kernels: one table, one pick (DESIGN.md §11)
 # ---------------------------------------------------------------------------
 
-def candidate_slab_sizes(grid: tuple[int, int, int], n: int,
-                         itemsize: int = 4, nrhs: int = 1, *,
-                         tpu: bool = False) -> list[int]:
-    """Slabs-per-block candidates (descending divisors of EZ).
-
-    A slab block holds ``sz * EX * EY`` elements, so the VMEM limit caps
-    ``sz``; ``sz`` must divide ``EZ`` so every block covers whole slabs with
-    no padding.  ``nrhs > 1`` (the multi-RHS block kernels) scales the
-    per-RHS vector residents while the operator-side share stays constant,
-    so viable sz shrinks as b grows.  ``tpu``: blocks must also be
-    128-lane aligned.  Possibly empty.
-    """
-    ex, ey, ez = grid
-    return [c for c in range(ez, 0, -1) if ez % c == 0 and _fits(
-        "slab", n, c * ex * ey, itemsize, tpu, nrhs=nrhs)]
-
-
-def _default_measure_slab(grid: tuple[int, int, int], n: int, dtype,
-                          acc_dtype=None) -> Callable[[int], float]:
-    """Times the v2 slab kernel on synthetic data for one config
-    (slab count; optionally grid order)."""
+def _slab_operands(grid: tuple[int, int, int], n: int, dtype, nrhs: int = 1):
+    """Synthetic operands of a slab-family measure: ``p`` and ``r`` (with a
+    leading RHS axis when ``nrhs > 1``), the packed metric diagonal, ``D``,
+    and the box's per-axis ``(mask, c)`` factors, in ``dtype``."""
     import numpy as np
 
-    from repro.core.geom import axis_mask_factor
+    from repro.core.geom import box_axis_factors
     from repro.core.sem import derivative_matrix
-    from repro.kernels import nekbone_ax as _ax
 
     ex, ey, ez = grid
-    E = ex * ey * ez
+    field = (n, n * n, ex * ey * ez)
+    lead = (nrhs,) if nrhs != 1 else ()
     rng = np.random.default_rng(0)
-    p = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
-    r = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
+    p = jnp.asarray(rng.normal(size=lead + field), dtype)
+    r = jnp.asarray(rng.normal(size=lead + field), dtype)
+    g3 = jnp.asarray(rng.normal(size=(3,) + field), dtype)
     D = jnp.asarray(derivative_matrix(n), dtype)
-    mx = jnp.asarray(axis_mask_factor(ex, n), dtype)
-    my = jnp.asarray(axis_mask_factor(ey, n), dtype)
-    mz = jnp.asarray(axis_mask_factor(ez, n), dtype)
+    factors = tuple(tuple(jnp.asarray(a, dtype) for a in f)
+                    for f in box_axis_factors(grid, n))
+    return p, r, g3, D, factors
+
+
+def _measure_slab(grid, n, dtype, acc_dtype, nrhs, depth):
+    """Times the v2 slab kernel (the batched one when ``nrhs > 1``)."""
+    from repro.kernels import nekbone_ax as _ax
+
+    p, r, g3, D, ((mx, my, mz), _) = _slab_operands(grid, n, dtype, nrhs)
+    kernel = (_ax.nekbone_ax_slab_pallas if nrhs == 1
+              else _ax.nekbone_ax_slab_block_pallas)
     # beta = 0: each call's p is r, so the threaded p stays bounded
-    beta = jnp.zeros((1, 1), _ax._accum(jnp.dtype(dtype), acc_dtype))
+    beta = jnp.zeros((1, nrhs), _ax._accum(jnp.dtype(dtype), acc_dtype))
     Dt = D.T
 
-    def make(sz: int, grid_order: str = "parallel"):
-        return _device_loop(lambda *a: _ax.nekbone_ax_slab_pallas(
+    def make(sz: int, grid_order: str):
+        return _device_loop(lambda *a: kernel(
             *a, n=n, grid=grid, sz=sz, interpret=False, acc_dtype=acc_dtype,
             grid_order=grid_order), (p, r, D, Dt, g3, mx, my, mz, beta),
             _ax.SLAB_ALIASES)
@@ -518,274 +517,83 @@ def _default_measure_slab(grid: tuple[int, int, int], n: int, dtype,
     return _chained(make)
 
 
-def _default_measure_slab_block(grid: tuple[int, int, int], n: int, dtype,
-                                nrhs: int,
-                                acc_dtype=None) -> Callable[[int], float]:
-    """Times the batched (multi-RHS) v2 slab kernel on synthetic data."""
-    import numpy as np
-
-    from repro.core.geom import axis_mask_factor
-    from repro.core.sem import derivative_matrix
+def _measure_sstep(grid, n, dtype, acc_dtype, nrhs, depth):
+    """Times the v3 matrix-powers kernel at ``s = depth``."""
     from repro.kernels import nekbone_ax as _ax
 
-    ex, ey, ez = grid
-    E = ex * ey * ez
-    rng = np.random.default_rng(0)
-    p3 = jnp.asarray(rng.normal(size=(nrhs, n, n * n, E)), dtype)
-    r3 = jnp.asarray(rng.normal(size=(nrhs, n, n * n, E)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
-    D = jnp.asarray(derivative_matrix(n), dtype)
-    mx = jnp.asarray(axis_mask_factor(ex, n), dtype)
-    my = jnp.asarray(axis_mask_factor(ey, n), dtype)
-    mz = jnp.asarray(axis_mask_factor(ez, n), dtype)
-    beta = jnp.zeros((1, nrhs), _ax._accum(jnp.dtype(dtype), acc_dtype))
+    p, r, g3, D, ((mx, my, mz), (cx, cy, cz)) = _slab_operands(grid, n,
+                                                               dtype)
+    inv_theta = jnp.ones((1, 1), _ax._accum(jnp.dtype(dtype), acc_dtype))
     Dt = D.T
 
-    def make(sz: int, grid_order: str = "parallel"):
-        return _device_loop(lambda *a: _ax.nekbone_ax_slab_block_pallas(
-            *a, n=n, grid=grid, sz=sz, interpret=False, acc_dtype=acc_dtype,
-            grid_order=grid_order), (p3, r3, D, Dt, g3, mx, my, mz, beta),
-            _ax.SLAB_ALIASES)
+    def make(sz: int, grid_order: str):
+        def ext(f):
+            return _ax.sstep_extend_field(f, grid, sz, depth)
 
-    return _chained(make)
-
-
-def pick_slab_sz(grid: tuple[int, int, int], n: int, dtype=jnp.float32, *,
-                 acc_dtype=None, backend: str | None = None,
-                 precond: str | None = None, nrhs: int = 1,
-                 measure: Callable[[int], float] | None = None) -> int:
-    """Best slabs-per-block for the v2 pipeline on ``grid``, memoized.
-
-    Same measure-on-TPU / heuristic-elsewhere policy as
-    :func:`pick_block_e`; cache keys carry the full element grid because
-    the slab layout (and the plane side-output sizes) depend on it, plus
-    the resolved (storage, accum) dtype pair.  ``precond`` adds a cache-key
-    dimension for the PCG update kernels (DESIGN.md §9): the Jacobi update
-    holds one extra block array (the operator diagonal) live, so a
-    measured pick for the plain pipeline must never be reused for the
-    preconditioned one.  ``None`` keeps the pre-precond key shape so
-    existing disk caches stay valid.  ``nrhs > 1`` (the multi-RHS block
-    kernels, DESIGN.md §12) likewise joins the key — as an ``"rhs:<b>"``
-    suffix, so b = 1 keeps the historical key shape — and switches both
-    the VMEM heuristic and the measured sweep to the batched kernel.
-    """
-    dtype = jnp.dtype(dtype)
-    backend = backend or jax.default_backend()
-    ex, ey, ez = grid
-    acc_name = _acc_name(dtype, acc_dtype)
-    key = ("slab", n, ex, ey, ez, dtype.name, acc_name, backend)
-    if precond is not None:
-        key = key + (f"pc:{precond}",)
-    if nrhs != 1:
-        key = key + (f"rhs:{nrhs}",)
-    # as in pick_block_e: VMEM residency is in the accumulation dtype
-    size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-
-    def pick() -> tuple[int, bool]:
-        cands = candidate_slab_sizes(grid, n, itemsize=size_item, nrhs=nrhs,
-                                     tpu=backend == "tpu")
-        if not cands:
-            raise _no_fit("v2 slab", f"grid {grid} (n={n}, b={nrhs})")
-        m = measure
-        if m is None and backend == "tpu":
-            if nrhs != 1:
-                m = _default_measure_slab_block(grid, n, dtype, nrhs,
-                                                acc_dtype)
-            else:
-                m = _default_measure_slab(grid, n, dtype, acc_dtype)
-        if m is None:
-            return cands[0], None
-        return _sweep(key, cands, m)
-
-    return _cached_pick(key, pick)
-
-
-# ---------------------------------------------------------------------------
-# s-step slab blocks (v3 matrix-powers pipeline): joint (sz, s) tuning
-# ---------------------------------------------------------------------------
-
-def candidate_slab_sizes_sstep(grid: tuple[int, int, int], n: int, s: int,
-                               itemsize: int = 4, *,
-                               tpu: bool = False) -> list[int]:
-    """Slabs-per-block candidates for the v3 powers kernel, per ``s``.
-
-    The working set is *s-dependent* twice over — the block marches
-    ``sz + 2s`` slabs (owned + matrix-powers halo) and keeps the whole
-    ``2s+1``-vector basis live alongside the operator temporaries — so the
-    VMEM ceiling on ``sz`` shrinks as ``s`` grows and the two knobs must be
-    tuned jointly.  ``tpu``: owned and halo lanes must also be 128-lane
-    aligned.  Possibly empty.
-    """
-    ex, ey, ez = grid
-    return [c for c in range(ez, 0, -1) if ez % c == 0 and _fits(
-        "sstep", n, c * ex * ey, itemsize, tpu, halo_lanes=s * ex * ey,
-        depth=s)]
-
-
-def _default_measure_sstep(grid: tuple[int, int, int], n: int, s: int,
-                           dtype, acc_dtype=None) -> Callable[[int], float]:
-    """Times the v3 powers kernel on synthetic data for one config."""
-    import numpy as np
-
-    from repro.core.geom import box_axis_factors
-    from repro.core.sem import derivative_matrix
-    from repro.kernels import nekbone_ax as _ax
-
-    ex, ey, ez = grid
-    E = ex * ey * ez
-    rng = np.random.default_rng(0)
-    p2 = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
-    r2 = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
-    D = jnp.asarray(derivative_matrix(n), dtype)
-    (mx, my, mz), (cx, cy, cz) = box_axis_factors(grid, n)
-    mx, my, cx, cy = (jnp.asarray(a, dtype) for a in (mx, my, cx, cy))
-    cz = jnp.asarray(cz, dtype)
-    acc = _ax._accum(jnp.dtype(dtype), acc_dtype)
-    inv_theta = jnp.ones((1, 1), acc)
-    Dt = D.T
-
-    def make(sz: int, grid_order: str = "parallel"):
-        pext = _ax.sstep_extend_field(p2, grid, sz, s)
-        rext = _ax.sstep_extend_field(r2, grid, sz, s)
-        gext = _ax.sstep_extend_field(g3, grid, sz, s)
-        mzext = _ax.sstep_extend_zfactor(jnp.asarray(mz, dtype), sz, s)
         return _device_loop(lambda *a: _ax.nekbone_ax_powers_pallas(
-            *a, n=n, grid=grid, sz=sz, s=s, interpret=False,
+            *a, n=n, grid=grid, sz=sz, s=depth, interpret=False,
             acc_dtype=acc_dtype, grid_order=grid_order),
-            (pext, rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, inv_theta))
+            (ext(p), ext(r), D, Dt, ext(g3), mx, my,
+             _ax.sstep_extend_zfactor(mz, sz, depth), cx, cy, cz, inv_theta))
 
     return _chained(make)
 
 
-def pick_slab_sz_sstep(grid: tuple[int, int, int], n: int, s: int,
-                       dtype=jnp.float32, *, acc_dtype=None,
-                       backend: str | None = None,
-                       measure: Callable[[int], float] | None = None) -> int:
-    """Best slabs-per-block for the v3 powers kernel at a given ``s``.
-
-    Same measure-on-TPU / heuristic-elsewhere policy as
-    :func:`pick_slab_sz`; the cache key gains ``s`` as a dimension — the
-    halo depth and the live basis count both scale with it, so a pick for
-    one ``s`` must never be reused for another.
-    """
-    dtype = jnp.dtype(dtype)
-    backend = backend or jax.default_backend()
-    ex, ey, ez = grid
-    acc_name = _acc_name(dtype, acc_dtype)
-    key = ("sstep", n, ex, ey, ez, s, dtype.name, acc_name, backend)
-    size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-
-    def pick() -> tuple[int, bool]:
-        cands = candidate_slab_sizes_sstep(
-            grid, n, s, itemsize=size_item, tpu=backend == "tpu")
-        if not cands:
-            raise _no_fit("s-step powers", f"grid {grid} (n={n}, s={s})")
-        m = measure
-        if m is None and backend == "tpu":
-            m = _default_measure_sstep(grid, n, s, dtype, acc_dtype)
-        if m is None:
-            return cands[0], None
-        return _sweep(key, cands, m)
-
-    return _cached_pick(key, pick)
-
-
-# ---------------------------------------------------------------------------
-# Chebyshev-apply slab blocks (precond pipeline): halo'd like the v3 powers
-# kernel, but the live set is the recurrence vectors (r, d, res, z) plus the
-# operator temporaries — no 2s+1 basis, so the VMEM ceiling is looser
-# ---------------------------------------------------------------------------
-
-def candidate_slab_sizes_cheb(grid: tuple[int, int, int], n: int, k: int,
-                              itemsize: int = 4, *,
-                              tpu: bool = False) -> list[int]:
-    """Slabs-per-block candidates for the Chebyshev-apply kernel, per ``k``.
-
-    The block marches ``sz + 2k`` slabs (owned + the matrix-powers halo of
-    the k chained applications, DESIGN.md §9.3) and keeps ~12 slab-sized
-    arrays live (r, d, res, z + the operator gradients/temporaries), so
-    the ceiling on ``sz`` shrinks with ``k`` like the v3 kernel's does
-    with ``s``.  ``tpu`` as :func:`candidate_slab_sizes_sstep`.
-    """
-    ex, ey, ez = grid
-    return [c for c in range(ez, 0, -1) if ez % c == 0 and _fits(
-        "cheb", n, c * ex * ey, itemsize, tpu, halo_lanes=k * ex * ey,
-        depth=k)]
-
-
-def _default_measure_cheb(grid: tuple[int, int, int], n: int, k: int,
-                          dtype, acc_dtype=None) -> Callable[[int], float]:
-    """Times the Chebyshev-apply kernel on synthetic data per config."""
-    import numpy as np
-
-    from repro.core.geom import box_axis_factors
-    from repro.core.sem import derivative_matrix
+def _measure_cheb(grid, n, dtype, acc_dtype, nrhs, depth):
+    """Times the Chebyshev-apply kernel at order ``k = depth``."""
     from repro.kernels import nekbone_ax as _ax
 
-    ex, ey, ez = grid
-    E = ex * ey * ez
-    rng = np.random.default_rng(0)
-    r2 = jnp.asarray(rng.normal(size=(n, n * n, E)), dtype)
-    g3 = jnp.asarray(rng.normal(size=(3, n, n * n, E)), dtype)
-    D = jnp.asarray(derivative_matrix(n), dtype)
-    (mx, my, mz), (cx, cy, cz) = box_axis_factors(grid, n)
-    mx, my, cx, cy = (jnp.asarray(a, dtype) for a in (mx, my, cx, cy))
-    cz = jnp.asarray(cz, dtype)
-    acc = _ax._accum(jnp.dtype(dtype), acc_dtype)
-    coef = jnp.ones((k + 1, 2), acc)
+    _, r, g3, D, ((mx, my, mz), (cx, cy, cz)) = _slab_operands(grid, n,
+                                                               dtype)
+    coef = jnp.ones((depth + 1, 2), _ax._accum(jnp.dtype(dtype), acc_dtype))
     Dt = D.T
 
-    def make(sz: int, grid_order: str = "parallel"):
-        rext = _ax.sstep_extend_field(r2, grid, sz, k)
-        gext = _ax.sstep_extend_field(g3, grid, sz, k)
-        mzext = _ax.sstep_extend_zfactor(jnp.asarray(mz, dtype), sz, k)
+    def make(sz: int, grid_order: str):
+        def ext(f):
+            return _ax.sstep_extend_field(f, grid, sz, depth)
+
         return _device_loop(lambda *a: _ax.nekbone_cheb_apply_pallas(
-            *a, n=n, grid=grid, sz=sz, k=k, interpret=False,
+            *a, n=n, grid=grid, sz=sz, k=depth, interpret=False,
             acc_dtype=acc_dtype, grid_order=grid_order),
-            (rext, D, Dt, gext, mx, my, mzext, cx, cy, cz, coef))
+            (ext(r), D, Dt, ext(g3), mx, my,
+             _ax.sstep_extend_zfactor(mz, sz, depth), cx, cy, cz, coef))
 
     return _chained(make)
 
 
-def pick_slab_sz_cheb(grid: tuple[int, int, int], n: int, k: int,
-                      dtype=jnp.float32, *, acc_dtype=None,
-                      backend: str | None = None,
-                      measure: Callable[[int], float] | None = None) -> int:
-    """Best slabs-per-block for the Chebyshev-apply kernel at order ``k``.
+# One row per slab-family kernel kind: ``(halo, measure)``.  The kind names
+# its :func:`vmem_bytes` model and its cache keys.  A ``halo`` kind
+# marches ``depth`` (s or k) extra slabs on each side of its block, so
+# depth joins both its footprint and its key.  ``measure(grid, n, dtype,
+# acc_dtype, nrhs, depth)`` builds the timed sweep's ``timed(sz,
+# grid_order)`` over the real kernel.
+_SLAB_KINDS = {
+    "slab": (False, _measure_slab),     # v2 slab kernel (and its b > 1 twin)
+    "sstep": (True, _measure_sstep),    # v3 matrix-powers kernel, depth = s
+    "cheb": (True, _measure_cheb),      # Chebyshev apply, depth = k
+}
 
-    Same measure-on-TPU / heuristic-elsewhere policy as
-    :func:`pick_slab_sz_sstep`; the cache key carries ``k`` (the precond
-    dimension) — halo depth scales with it, so a pick for one order must
-    never be reused for another.
+
+def candidate_slab_sizes(kind: str, grid: tuple[int, int, int], n: int,
+                         itemsize: int = 4, *, nrhs: int = 1,
+                         depth: int = 0, tpu: bool = False) -> list[int]:
+    """Slabs-per-block candidates (descending divisors of EZ) of ``kind``.
+
+    A block holds ``sz * EX * EY`` elements, so the VMEM limit caps
+    ``sz``; ``sz`` must divide ``EZ`` so every block covers whole slabs
+    with no padding.  ``nrhs > 1`` (the multi-RHS block kernels) scales
+    the per-RHS residents, and a halo kind's window of ``sz + 2*depth``
+    slabs grows with ``depth``, so viable sz shrinks as either grows.
+    ``tpu``: owned and halo blocks must also be 128-lane aligned.
+    Possibly empty.
     """
-    dtype = jnp.dtype(dtype)
-    backend = backend or jax.default_backend()
+    halo, _ = _SLAB_KINDS[kind]
     ex, ey, ez = grid
-    acc_name = _acc_name(dtype, acc_dtype)
-    key = ("cheb", n, ex, ey, ez, k, dtype.name, acc_name, backend)
-    size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
+    halo_lanes = depth * ex * ey if halo else 0
+    return [c for c in range(ez, 0, -1) if ez % c == 0 and _fits(
+        kind, n, c * ex * ey, itemsize, tpu, nrhs=nrhs,
+        halo_lanes=halo_lanes, depth=depth)]
 
-    def pick() -> tuple[int, bool]:
-        cands = candidate_slab_sizes_cheb(
-            grid, n, k, itemsize=size_item, tpu=backend == "tpu")
-        if not cands:
-            raise _no_fit("Chebyshev", f"grid {grid} (n={n}, k={k})")
-        m = measure
-        if m is None and backend == "tpu":
-            m = _default_measure_cheb(grid, n, k, dtype, acc_dtype)
-        if m is None:
-            return cands[0], None
-        return _sweep(key, cands, m)
-
-    return _cached_pick(key, pick)
-
-
-# ---------------------------------------------------------------------------
-# joint (slab sz x grid order) configs — the measured-time sweep
-# (DESIGN.md §11).  One pick per (backend/arch, case key, precision policy,
-# precond), persisted like the sz-only picks above.
-# ---------------------------------------------------------------------------
 
 def candidate_configs(sz_cands: list[int]) -> list[tuple[int, str]]:
     """The joint sweep space: every (sz, grid_order) pair.
@@ -798,95 +606,69 @@ def candidate_configs(sz_cands: list[int]) -> list[tuple[int, str]]:
     return [(sz, go) for sz in sz_cands for go in GRID_ORDERS]
 
 
-def _pick_config(key: tuple, sz_cands: list[int], measure,
-                 default_measure_factory, backend: str):
-    """Shared joint-config selection: measured sweep on TPU (or with an
-    explicit ``measure(sz, grid_order)``), else the heuristic
-    (largest-fitting sz, parallel)."""
-    def pick() -> tuple:
-        if not sz_cands:
-            raise _no_fit(key[1], f"grid {key[3:6]} (n={key[2]})")
-        m = measure
-        if m is None and backend == "tpu":
-            m = default_measure_factory()
-        if m is None:
-            return (sz_cands[0], "parallel"), None
-        return _sweep(key, candidate_configs(sz_cands), m)
+def slab_config(kind: str, grid: tuple[int, int, int], n: int,
+                dtype=jnp.float32, *, acc_dtype=None,
+                precond: str | None = None, nrhs: int = 1, depth: int = 0,
+                sz: int | None = None, grid_order: str | None = None,
+                backend: str | None = None,
+                measure=None) -> tuple[int, str]:
+    """The ``(sz, grid_order)`` a slab-family kernel of ``kind`` runs.
 
-    return _cached_pick(key, pick)
+    An explicit ``sz`` passes through, with ``grid_order`` defaulting to
+    ``"parallel"``.  Otherwise the pick is one memoized sweep per key
+    ``("cfg", kind, n, EX, EY, EZ[, depth], dtype, acc, backend
+    [, "pc:<precond>"][, "rhs:<nrhs>"])``: on a TPU backend (or with an
+    injected ``measure(sz, grid_order) -> seconds``) every
+    :func:`candidate_configs` point is timed by :func:`_sweep`; elsewhere
+    the heuristic takes the largest fitting sz, ``parallel``.  A caller
+    that fixes ``grid_order`` (its kernel takes none, or pins it) gets
+    that order's fastest size from the same sweep by the same
+    :data:`SWEEP_MARGIN` rule, so no second sweep runs.
 
-
-def pick_slab_config(grid: tuple[int, int, int], n: int, dtype=jnp.float32,
-                     *, acc_dtype=None, backend: str | None = None,
-                     precond: str | None = None, nrhs: int = 1,
-                     measure=None) -> tuple[int, str]:
-    """Best ``(sz, grid_order)`` for the v2 slab kernel, memoized.
-
-    The joint analog of :func:`pick_slab_sz`: on a TPU backend (or with an
-    explicit ``measure``) every (slab size x grid iteration order) point
-    is timed and the fastest wins; elsewhere the heuristic keeps the
-    ``parallel`` order at the largest fitting sz.  Keys use a
-    ``("cfg", "slab", ...)`` kind so
-    sz-only picks (and their persisted caches) are never aliased.
-    ``nrhs`` joins the key and the sweep exactly as in
-    :func:`pick_slab_sz` (the RHS batch changes both the VMEM footprint
-    and the measured optimum).
+    ``precond`` keys the PCG update kernels apart (the Jacobi update holds
+    the diagonal block live, DESIGN.md §9); ``nrhs`` switches the
+    footprint and the sweep to the multi-RHS kernels (DESIGN.md §12);
+    ``depth`` is a halo kind's s or k.  Keys carry the resolved (storage,
+    accum) dtype pair, and candidates are sized in the wider of the two.
     """
+    if sz is not None:
+        return sz, grid_order or "parallel"
+    halo, build = _SLAB_KINDS[kind]
     dtype = jnp.dtype(dtype)
     backend = backend or jax.default_backend()
-    ex, ey, ez = grid
     acc_name = _acc_name(dtype, acc_dtype)
-    key = ("cfg", "slab", n, ex, ey, ez, dtype.name, acc_name, backend)
+    key = (("cfg", kind, n) + tuple(grid) + ((depth,) if halo else ())
+           + (dtype.name, acc_name, backend))
     if precond is not None:
         key = key + (f"pc:{precond}",)
     if nrhs != 1:
         key = key + (f"rhs:{nrhs}",)
+    # as in pick_block_e: VMEM residency is in the accumulation dtype
     size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-    sz_cands = candidate_slab_sizes(grid, n, itemsize=size_item, nrhs=nrhs,
-                                    tpu=backend == "tpu")
-    if nrhs != 1:
-        factory = lambda: _default_measure_slab_block(  # noqa: E731
-            grid, n, dtype, nrhs, acc_dtype)
-    else:
-        factory = lambda: _default_measure_slab(  # noqa: E731
-            grid, n, dtype, acc_dtype)
-    return _pick_config(key, sz_cands, measure, factory, backend)
+    configs = candidate_configs(candidate_slab_sizes(
+        kind, grid, n, size_item, nrhs=nrhs, depth=depth,
+        tpu=backend == "tpu"))
 
+    def pick() -> tuple:
+        if not configs:
+            raise _no_fit(kind, f"grid {tuple(grid)} (n={n}, b={nrhs}, "
+                          f"depth={depth})")
+        m = measure
+        if m is None and backend == "tpu":
+            m = build(grid, n, dtype, acc_dtype, nrhs, depth)
+        if m is None:
+            return configs[0], None
+        return _sweep(key, configs, m)
 
-def pick_sstep_config(grid: tuple[int, int, int], n: int, s: int,
-                      dtype=jnp.float32, *, acc_dtype=None,
-                      backend: str | None = None,
-                      measure=None) -> tuple[int, str]:
-    """Best ``(sz, grid_order)`` for the v3 powers kernel at ``s``."""
-    dtype = jnp.dtype(dtype)
-    backend = backend or jax.default_backend()
-    ex, ey, ez = grid
-    acc_name = _acc_name(dtype, acc_dtype)
-    key = ("cfg", "sstep", n, ex, ey, ez, s, dtype.name, acc_name, backend)
-    size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-    sz_cands = candidate_slab_sizes_sstep(
-        grid, n, s, itemsize=size_item, tpu=backend == "tpu")
-    return _pick_config(
-        key, sz_cands, measure,
-        lambda: _default_measure_sstep(grid, n, s, dtype, acc_dtype), backend)
-
-
-def pick_cheb_config(grid: tuple[int, int, int], n: int, k: int,
-                     dtype=jnp.float32, *, acc_dtype=None,
-                     backend: str | None = None,
-                     measure=None) -> tuple[int, str]:
-    """Best ``(sz, grid_order)`` for the Chebyshev-apply kernel."""
-    dtype = jnp.dtype(dtype)
-    backend = backend or jax.default_backend()
-    ex, ey, ez = grid
-    acc_name = _acc_name(dtype, acc_dtype)
-    key = ("cfg", "cheb", n, ex, ey, ez, k, dtype.name, acc_name, backend)
-    size_item = max(dtype.itemsize, jnp.dtype(acc_name).itemsize)
-    sz_cands = candidate_slab_sizes_cheb(
-        grid, n, k, itemsize=size_item, tpu=backend == "tpu")
-    return _pick_config(
-        key, sz_cands, measure,
-        lambda: _default_measure_cheb(grid, n, k, dtype, acc_dtype), backend)
+    best = _cached_pick(key, pick)
+    if grid_order is None:
+        return best
+    with _LOCK:
+        seconds = _SECONDS.get(key)
+    if seconds is None:                # a heuristic pick: largest sz
+        return best[0], grid_order
+    same = [c for c in configs if c[1] == grid_order]
+    return _fastest(same, [seconds[_config_name(c)] for c in same])
 
 
 # ---------------------------------------------------------------------------

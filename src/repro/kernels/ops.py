@@ -214,11 +214,8 @@ def nekbone_ax_dots_slab(p_prev: jnp.ndarray, r: jnp.ndarray,
          full (E, 6, ...) metric of an axis-aligned box (off-diagonals
          validated zero, then dropped — see :func:`diag_metric`).
       grid: (EX, EY, EZ); beta: direction-update scalar.
-      sz: slabs per block (default: autotuned divisor of EZ).
-      grid_order: grid iteration order (default: autotuned jointly with
-        sz when both are None, see
-        :func:`repro.kernels.autotune.pick_slab_config`; otherwise
-        ``"parallel"``).
+      sz, grid_order: slab split and grid iteration order (default:
+        :func:`repro.kernels.autotune.slab_config`).
       acc_dtype: explicit in-kernel accumulation dtype (precision policy).
 
     Returns ``(p, w, pap)`` with ``pap == p·c·(mask gs w_local)`` tree-
@@ -228,13 +225,9 @@ def nekbone_ax_dots_slab(p_prev: jnp.ndarray, r: jnp.ndarray,
     E = p_prev.shape[0]
     n = p_prev.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_slab_config(
-            grid, n, p_prev.dtype, acc_dtype=acc_dtype)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, p_prev.dtype,
-                                    acc_dtype=acc_dtype)
-    grid_order = "parallel" if grid_order is None else grid_order
+    sz, grid_order = _autotune.slab_config(
+        "slab", grid, n, p_prev.dtype, acc_dtype=acc_dtype, sz=sz,
+        grid_order=grid_order)
     (mx, my, mz), _ = slab_axis_factors(grid, n, p_prev.dtype)
     D = jnp.asarray(D, p_prev.dtype)
     g3 = diag_metric(jnp.asarray(g3, p_prev.dtype), E, n)
@@ -267,10 +260,9 @@ def nekbone_ax_powers(p: jnp.ndarray, r: jnp.ndarray, D: jnp.ndarray,
       p, r: (E, n, n, n), z-major over ``grid``; both continuous+masked.
       D: (n, n); g3: diagonal (E, 3, ...) or verifiably-diagonal 6-component
          metric; theta: basis scale (``A' = A/theta``).
-      s: powers per cycle (>= 1); sz: slabs per block (default: autotuned).
-      grid_order: grid iteration order (default: autotuned jointly with
-        sz when both are None, see
-        :func:`repro.kernels.autotune.pick_sstep_config`).
+      s: powers per cycle (>= 1).
+      sz, grid_order: slab split and grid iteration order (default:
+        :func:`repro.kernels.autotune.slab_config`, kind ``"sstep"``).
 
     Returns ``(basis, gram)``: basis ``(E, 2s-1, n, n, n)`` holding
     ``[A'p..A'^s p, A'r..A'^{s-1} r]`` and the summed ``(2s+1, 2s+1)``
@@ -280,13 +272,9 @@ def nekbone_ax_powers(p: jnp.ndarray, r: jnp.ndarray, D: jnp.ndarray,
     E = p.shape[0]
     n = p.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_sstep_config(
-            grid, n, s, p.dtype, acc_dtype=acc_dtype)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz_sstep(grid, n, s, p.dtype,
-                                          acc_dtype=acc_dtype)
-    grid_order = "parallel" if grid_order is None else grid_order
+    sz, grid_order = _autotune.slab_config(
+        "sstep", grid, n, p.dtype, acc_dtype=acc_dtype, depth=s, sz=sz,
+        grid_order=grid_order)
     (mx, my, mz), (cx, cy, cz) = slab_axis_factors(grid, n, p.dtype)
     D = jnp.asarray(D, p.dtype)
     g3 = _ax.metric_lanes(diag_metric(jnp.asarray(g3, p.dtype), E, n), n)
@@ -326,9 +314,9 @@ def nekbone_sstep_update(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
     grid = tuple(grid)
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None:
-        sz = _autotune.pick_slab_sz_sstep(grid, n, s, p.dtype,
-                                          acc_dtype=acc_dtype)
+    sz, _ = _autotune.slab_config("sstep", grid, n, p.dtype,
+                                  acc_dtype=acc_dtype, depth=s, sz=sz,
+                                  grid_order="parallel")
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
     x2, r2, p2, rcr_b = _ax.nekbone_sstep_update_pallas(
@@ -363,8 +351,9 @@ def nekbone_cg_update(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
     ex, ey, ez = grid = tuple(grid)
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, x.dtype, acc_dtype=acc_dtype)
+    sz, _ = _autotune.slab_config("slab", grid, n, x.dtype,
+                                  acc_dtype=acc_dtype, sz=sz,
+                                  grid_order="parallel")
     nblk = ez // sz
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
@@ -400,13 +389,9 @@ def nekbone_ax_dots_slab_block(p_prev: jnp.ndarray, r: jnp.ndarray,
     nrhs, E = p_prev.shape[0], p_prev.shape[1]
     n = p_prev.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_slab_config(
-            grid, n, p_prev.dtype, acc_dtype=acc_dtype, nrhs=nrhs)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, p_prev.dtype,
-                                    acc_dtype=acc_dtype, nrhs=nrhs)
-    grid_order = "parallel" if grid_order is None else grid_order
+    sz, grid_order = _autotune.slab_config(
+        "slab", grid, n, p_prev.dtype, acc_dtype=acc_dtype, nrhs=nrhs,
+        sz=sz, grid_order=grid_order)
     (mx, my, mz), _ = slab_axis_factors(grid, n, p_prev.dtype)
     D = jnp.asarray(D, p_prev.dtype)
     g3 = diag_metric(jnp.asarray(g3, p_prev.dtype), E, n)
@@ -443,9 +428,9 @@ def nekbone_cg_update_block(x: jnp.ndarray, p: jnp.ndarray, r: jnp.ndarray,
     nrhs = x.shape[0]
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, x.dtype, acc_dtype=acc_dtype,
-                                    nrhs=nrhs)
+    sz, _ = _autotune.slab_config("slab", grid, n, x.dtype,
+                                  acc_dtype=acc_dtype, nrhs=nrhs, sz=sz,
+                                  grid_order="parallel")
     nblk = ez // sz
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
@@ -488,9 +473,9 @@ def nekbone_pcg_update(x: jnp.ndarray, p: jnp.ndarray, z: jnp.ndarray,
     ex, ey, ez = grid = tuple(grid)
     n = x.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None:
-        sz = _autotune.pick_slab_sz(grid, n, x.dtype, acc_dtype=acc_dtype,
-                                    precond="jacobi")
+    sz, _ = _autotune.slab_config("slab", grid, n, x.dtype,
+                                  acc_dtype=acc_dtype, precond="jacobi",
+                                  sz=sz, grid_order="parallel")
     nblk = ez // sz
     _, (cx, cy, cz) = slab_axis_factors(grid, n, x.dtype)
     acc = _ax._accum(x.dtype, acc_dtype)
@@ -524,11 +509,9 @@ def nekbone_cheb_precond(r: jnp.ndarray, D: jnp.ndarray, g3: jnp.ndarray,
       D: (n, n); g3: diagonal (E, 3, ...) or verifiably-diagonal
          6-component metric; coef: (k+1, 2) recurrence scalars
          (:func:`repro.core.precond.cheb_scalars`).
-      k: polynomial degree (>= 1); sz: slabs per block (default:
-         autotuned, :func:`repro.kernels.autotune.pick_slab_sz_cheb`).
-      grid_order: grid iteration order (default: autotuned jointly with
-        sz when both are None, see
-        :func:`repro.kernels.autotune.pick_cheb_config`).
+      k: polynomial degree (>= 1).
+      sz, grid_order: slab split and grid iteration order (default:
+        :func:`repro.kernels.autotune.slab_config`, kind ``"cheb"``).
 
     Returns ``(z, rtz)``.
     """
@@ -536,13 +519,9 @@ def nekbone_cheb_precond(r: jnp.ndarray, D: jnp.ndarray, g3: jnp.ndarray,
     E = r.shape[0]
     n = r.shape[-1]
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None and grid_order is None:
-        sz, grid_order = _autotune.pick_cheb_config(
-            grid, n, k, r.dtype, acc_dtype=acc_dtype)
-    elif sz is None:
-        sz = _autotune.pick_slab_sz_cheb(grid, n, k, r.dtype,
-                                         acc_dtype=acc_dtype)
-    grid_order = "parallel" if grid_order is None else grid_order
+    sz, grid_order = _autotune.slab_config(
+        "cheb", grid, n, r.dtype, acc_dtype=acc_dtype, depth=k, sz=sz,
+        grid_order=grid_order)
     (mx, my, mz), (cx, cy, cz) = slab_axis_factors(grid, n, r.dtype)
     D = jnp.asarray(D, r.dtype)
     g3 = _ax.metric_lanes(diag_metric(jnp.asarray(g3, r.dtype), E, n), n)
@@ -579,10 +558,9 @@ def nekbone_interp(u: jnp.ndarray, M: jnp.ndarray,
     nout = M.shape[0]
     assert M.shape == (nout, nin), (M.shape, (nout, nin))
     interpret = default_interpret() if interpret is None else interpret
-    if sz is None:
-        sz = _autotune.pick_slab_sz(grid, max(nin, nout), u.dtype,
-                                    acc_dtype=acc_dtype,
-                                    precond="pmg:interp")
+    sz, _ = _autotune.slab_config("slab", grid, max(nin, nout), u.dtype,
+                                  acc_dtype=acc_dtype, precond="pmg:interp",
+                                  sz=sz, grid_order="parallel")
     v = _ax.nekbone_interp_pallas(
         _lanes(u), M.T, nin=nin, nout=nout, grid=grid, sz=sz,
         interpret=interpret, acc_dtype=acc_dtype)

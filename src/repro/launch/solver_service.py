@@ -17,11 +17,12 @@ Rules (pinned by tests/test_solver_service.py):
   * ``drain()`` on an empty queue returns ``[]`` and dispatches nothing;
   * results come back in submission order, each carrying its request id.
 
-Warm start: :meth:`SolverService.warm_start` pre-populates the autotune
-cache (``$REPRO_CACHE_DIR`` — the JSON layer persists across processes,
-so a deploy can ship a pre-baked cache) and compiles the solver for each
-expected (bucket, batch) shape, taking the measuring sweep and the XLA
-compile off the first request's latency.
+Warm start: :meth:`SolverService.warm_start` runs one solve of each
+expected (bucket, batch) shape through its route, so the route's driver
+populates the autotune cache with the key it reads (``$REPRO_CACHE_DIR`` —
+the JSON layer persists across processes, so a deploy can ship a
+pre-baked cache) and the solver is compiled, taking the measuring sweep
+and the XLA compile off the first request's latency.
 
 Bench: ``python -m repro.launch.solver_service --requests 32 --max-b 8``
 emits latency/throughput rows (consumed by benchmarks/run.py, schema v7).
@@ -244,26 +245,20 @@ class SolverService:
     def warm_start(self, configs, *, batches=None, niter: int = 1) -> int:
         """Pre-tune and pre-compile the expected (case, batch) shapes.
 
-        For every config × batch size: runs the autotune pick at that RHS
-        count (populating the in-memory + ``$REPRO_CACHE_DIR`` JSON cache
-        — ship that file to skip the measuring sweep entirely) and traces
-        one ``niter``-iteration batched solve so the XLA executable is
-        resident before the first real request.  Returns the number of
+        For every config × batch size: traces one ``niter``-iteration
+        batched solve through the route a request of that batch takes, so
+        the XLA executable is resident before the first real request and
+        the route's driver has made the autotune pick it reads (in memory
+        and in the ``$REPRO_CACHE_DIR`` JSON cache — ship that file to
+        skip the measuring sweep entirely).  Returns the number of
         (case, b) combinations warmed.
         """
         from repro.core import solvers as solvers_mod
-        from repro.kernels import autotune as _autotune
 
         batches = sorted(set(batches or (1, self.max_b)))
         warmed = 0
         for cfg in configs:
             case = self._case_for(cfg)
-            if case.ax_impl in ("pallas_fused_cg", "pallas_fused_cg_v2",
-                                "pallas_sstep_v3"):
-                for b in batches:
-                    _autotune.pick_slab_config(
-                        tuple(case.grid), case.n, case.dtype,
-                        precond=case.precond, nrhs=b)
             _, f1 = case.manufactured()
             for b in batches:
                 f = f1[None] if b == 1 else jnp.stack([f1] * b)

@@ -130,24 +130,6 @@ def test_block_keys_distinct_per_dtype_pair():
                           backend="tpu", measure=boom)
 
 
-def test_slab_keys_distinct_per_dtype_pair():
-    seen = []
-
-    def measure(sz):
-        seen.append(sz)
-        return float(sz)
-
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.bfloat16, backend="tpu",
-                          measure=measure)
-    n1 = len(seen)
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.bfloat16, acc_dtype="float64",
-                          backend="tpu", measure=measure)
-    assert len(seen) > n1              # distinct key -> re-measured
-    keys = set(autotune.cache_info())
-    assert ("slab", 4, 8, 8, 8, "bfloat16", "float32", "tpu") in keys
-    assert ("slab", 4, 8, 8, 8, "bfloat16", "float64", "tpu") in keys
-
-
 # ---------------------------------------------------------------------------
 # slab mode (v2 pipeline)
 # ---------------------------------------------------------------------------
@@ -156,7 +138,8 @@ def test_slab_candidates_divide_ez_and_fit_budget():
     for grid in ((2, 2, 8), (4, 8, 16), (1, 3, 5), (16, 16, 14)):
         for n in (4, 10):
             for tpu in (False, True):
-                cands = autotune.candidate_slab_sizes(grid, n, tpu=tpu)
+                cands = autotune.candidate_slab_sizes("slab", grid, n,
+                                                      tpu=tpu)
                 assert all(grid[2] % sz == 0 for sz in cands)
                 assert cands == sorted(cands, reverse=True)
                 ex, ey, _ = grid
@@ -166,36 +149,46 @@ def test_slab_candidates_divide_ez_and_fit_budget():
                     assert (autotune.vmem_bytes("slab", n, sz * ex * ey)
                             <= autotune.VMEM_LIMIT_BYTES), (grid, n, sz)
                     assert not tpu or (sz * ex * ey) % 128 == 0
-            assert autotune.candidate_slab_sizes(grid, n), (grid, n)
+            assert autotune.candidate_slab_sizes("slab", grid, n), \
+                (grid, n)
 
 
 def test_pick_slab_sz_cached_per_grid():
     calls = []
 
-    def measure(sz):
-        calls.append(sz)
+    def measure(sz, grid_order):
+        calls.append((sz, grid_order))
         return float(sz)               # smallest "fastest": picks 1
 
-    sz1 = autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
-                                measure=measure)
+    sz1, _ = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                                  backend="tpu", measure=measure)
     assert sz1 == 2                    # the smallest 128-lane block
     n_calls = len(calls)
-    assert n_calls == autotune.SWEEP_ROUNDS * len(
-        autotune.candidate_slab_sizes(TPU_GRID, 4, tpu=True))
+    assert n_calls == autotune.SWEEP_ROUNDS * len(autotune.candidate_configs(
+        autotune.candidate_slab_sizes("slab", TPU_GRID, 4, tpu=True)))
     # same key: cached; different grid: distinct key
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
-                          measure=measure)
+    autotune.slab_config("slab", TPU_GRID, 4, jnp.float32, backend="tpu",
+                         measure=measure)
     assert len(calls) == n_calls
-    autotune.pick_slab_sz((8, 8, 4), 4, jnp.float32, backend="tpu",
-                          measure=measure)
+    autotune.slab_config("slab", (8, 8, 4), 4, jnp.float32, backend="tpu",
+                         measure=measure)
     assert len(calls) > n_calls
-    assert (("slab", 4, 8, 8, 8, "float32", "float32", "tpu")
+    assert (("cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu")
             in autotune.cache_info())
 
 
-def test_slab_heuristic_on_cpu_prefers_largest():
-    sz = autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="cpu")
-    assert sz == autotune.candidate_slab_sizes(TPU_GRID, 4)[0]
+@pytest.mark.parametrize("grid_order", [None, "parallel", "arbitrary"])
+def test_slab_heuristic_on_cpu_prefers_largest(grid_order):
+    """Off-TPU the pick is the largest fitting sz (``parallel`` unless the
+    caller fixes the order), without measuring and without a disk entry."""
+    def boom(sz, grid_order):
+        raise AssertionError("must not measure on cpu")
+
+    cfg = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                               backend="cpu", grid_order=grid_order)
+    assert cfg == (autotune.candidate_slab_sizes("slab", TPU_GRID, 4)[0],
+                   grid_order or "parallel")
+    assert not autotune.cache_path().exists()
 
 
 # ---------------------------------------------------------------------------
@@ -247,44 +240,14 @@ def test_sstep_candidates_shrink_with_s():
         for n in (4, 10):
             prev_max = None
             for s in (1, 2, 4, 8):
-                cands = autotune.candidate_slab_sizes_sstep(grid, n, s)
+                cands = autotune.candidate_slab_sizes("sstep", grid, n,
+                                                      depth=s)
                 assert cands, (grid, n, s)
                 assert all(grid[2] % sz == 0 for sz in cands)
                 assert cands[-1] == 1
                 if prev_max is not None:
                     assert cands[0] <= prev_max, (grid, n, s)
                 prev_max = cands[0]
-
-
-def test_pick_slab_sz_sstep_keys_carry_s():
-    """A pick for one s must never be reused for another — s changes the
-    halo depth and the live basis count."""
-    calls = []
-
-    def measure(sz):
-        calls.append(sz)
-        return float(sz)
-
-    sz_a = autotune.pick_slab_sz_sstep(TPU_GRID, 4, 2, jnp.float32,
-                                       backend="tpu", measure=measure)
-    assert sz_a == 2
-    n_calls = len(calls)
-    # same (grid, s): cached
-    autotune.pick_slab_sz_sstep(TPU_GRID, 4, 2, jnp.float32,
-                                backend="tpu", measure=measure)
-    assert len(calls) == n_calls
-    # different s: distinct key, fresh sweep
-    autotune.pick_slab_sz_sstep(TPU_GRID, 4, 4, jnp.float32,
-                                backend="tpu", measure=measure)
-    assert len(calls) > n_calls
-    info = autotune.cache_info()
-    assert ("sstep", 4, 8, 8, 8, 2, "float32", "float32", "tpu") in info
-    assert ("sstep", 4, 8, 8, 8, 4, "float32", "float32", "tpu") in info
-    # and the sstep keys never collide with the plain slab keys
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
-                          measure=measure)
-    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu") \
-        in autotune.cache_info()
 
 
 def test_cheb_candidates_shrink_with_k():
@@ -294,7 +257,8 @@ def test_cheb_candidates_shrink_with_k():
         for n in (4, 10):
             prev_max = None
             for k in (1, 2, 4, 8):
-                cands = autotune.candidate_slab_sizes_cheb(grid, n, k)
+                cands = autotune.candidate_slab_sizes("cheb", grid, n,
+                                                      depth=k)
                 assert cands, (grid, n, k)
                 assert all(grid[2] % sz == 0 for sz in cands)
                 assert cands[-1] == 1
@@ -303,49 +267,50 @@ def test_cheb_candidates_shrink_with_k():
                 prev_max = cands[0]
 
 
-def test_pick_slab_sz_cheb_keys_carry_k():
-    """A pick for one Chebyshev order must never serve another — k sets
-    the halo depth (the precond cache-key dimension)."""
+# Each case: the keyword arguments of two picks that differ in one key
+# dimension, and the two keys they must write.  A pick for one value must
+# never serve another: the dtype pair sets the resident bytes, s and k the
+# halo depth, the Jacobi update holds one more live block, and the RHS
+# batch scales the per-RHS residents.
+_F32 = ("float32", "float32", "tpu")
+_KEY_CASES = {
+    "dtype_pair": (
+        dict(kind="slab", dtype=jnp.bfloat16), dict(acc_dtype="float64"),
+        ("cfg", "slab", 4, 8, 8, 8, "bfloat16", "float32", "tpu"),
+        ("cfg", "slab", 4, 8, 8, 8, "bfloat16", "float64", "tpu")),
+    "s": (dict(kind="sstep", depth=2), dict(depth=4),
+          ("cfg", "sstep", 4, 8, 8, 8, 2) + _F32,
+          ("cfg", "sstep", 4, 8, 8, 8, 4) + _F32),
+    "k": (dict(kind="cheb", depth=2), dict(depth=4),
+          ("cfg", "cheb", 4, 8, 8, 8, 2) + _F32,
+          ("cfg", "cheb", 4, 8, 8, 8, 4) + _F32),
+    "precond": (dict(kind="slab"), dict(precond="jacobi"),
+                ("cfg", "slab", 4, 8, 8, 8) + _F32,
+                ("cfg", "slab", 4, 8, 8, 8) + _F32 + ("pc:jacobi",)),
+    "nrhs": (dict(kind="slab", precond="jacobi"), dict(nrhs=3),
+             ("cfg", "slab", 4, 8, 8, 8) + _F32 + ("pc:jacobi",),
+             ("cfg", "slab", 4, 8, 8, 8) + _F32 + ("pc:jacobi", "rhs:3")),
+}
+
+
+@pytest.mark.parametrize("case", list(_KEY_CASES))
+def test_slab_keys_carry_each_dimension(case):
+    first, change, key_a, key_b = _KEY_CASES[case]
     calls = []
 
-    def measure(sz):
-        calls.append(sz)
+    def measure(sz, grid_order):
+        calls.append((sz, grid_order))
         return float(sz)
 
-    sz_a = autotune.pick_slab_sz_cheb(TPU_GRID, 4, 2, jnp.float32,
-                                      backend="tpu", measure=measure)
-    assert sz_a == 2
+    kw = dict(grid=TPU_GRID, n=4, dtype=jnp.float32, backend="tpu",
+              measure=measure) | first
+    assert autotune.slab_config(**kw) == (2, "parallel")
     n_calls = len(calls)
-    autotune.pick_slab_sz_cheb(TPU_GRID, 4, 2, jnp.float32,
-                               backend="tpu", measure=measure)
-    assert len(calls) == n_calls       # same (grid, k): cached
-    autotune.pick_slab_sz_cheb(TPU_GRID, 4, 4, jnp.float32,
-                               backend="tpu", measure=measure)
-    assert len(calls) > n_calls        # different k: fresh sweep
-    info = autotune.cache_info()
-    assert ("cheb", 4, 8, 8, 8, 2, "float32", "float32", "tpu") in info
-    assert ("cheb", 4, 8, 8, 8, 4, "float32", "float32", "tpu") in info
-
-
-def test_pick_slab_sz_precond_key_dimension():
-    """The PCG update kernel's pick is keyed apart from the plain v2 one
-    (one extra live block array), and None keeps the pre-precond key."""
-    calls = []
-
-    def measure(sz):
-        calls.append(sz)
-        return float(sz)
-
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
-                          measure=measure)
-    n_plain = len(calls)
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
-                          precond="jacobi", measure=measure)
-    assert len(calls) > n_plain        # distinct key -> re-measured
-    info = autotune.cache_info()
-    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu") in info
-    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu",
-            "pc:jacobi") in info
+    autotune.slab_config(**kw)
+    assert len(calls) == n_calls       # same key: cached
+    autotune.slab_config(**kw | change)
+    assert len(calls) > n_calls        # distinct key: a fresh sweep
+    assert set(autotune.cache_info()) == {key_a, key_b}
 
 
 def test_corrupt_cache_file_is_tolerated():
@@ -395,24 +360,13 @@ def test_candidate_configs_cover_the_sweep_space():
     assert cands[len(GRID_ORDERS)] == (2, "parallel")
 
 
-def test_pick_slab_config_heuristic_is_pre_sweep_point():
-    def boom(sz, grid_order):
-        raise AssertionError("must not measure on cpu")
-
-    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="cpu")
-    assert cfg == (autotune.candidate_slab_sizes(TPU_GRID, 4)[0],
-                   "parallel")
-    # heuristic picks stay memory-only (like the sz-only picks)
-    assert not autotune.cache_path().exists()
-
-
 def test_pick_slab_config_measured_winner_and_persistence():
     def measure(sz, grid_order):
         # a non-default point must win: (2, arbitrary)
         return 0.0 if (sz, grid_order) == (2, "arbitrary") else 1.0 + sz
 
-    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32,
-                                    backend="tpu", measure=measure)
+    cfg = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                               backend="tpu", measure=measure)
     assert cfg == (2, "arbitrary")
     assert autotune.cache_path().exists()
 
@@ -423,49 +377,129 @@ def test_pick_slab_config_measured_winner_and_persistence():
     def boom(sz, grid_order):
         raise AssertionError("disk-cached pick must not re-measure")
 
-    cfg2 = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32,
-                                     backend="tpu", measure=boom)
+    cfg2 = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                                backend="tpu", measure=boom)
     assert cfg2 == cfg
     assert isinstance(cfg2, tuple)
 
 
-def test_cfg_keys_never_alias_sz_only_keys():
-    """The joint picks live under a ("cfg", kind, ...) namespace: a
-    measured sz-only pick and a joint pick for the same case must coexist
-    under distinct keys."""
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
-                          measure=lambda sz: float(sz))
-    autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
-                              measure=lambda sz, go: float(sz))
-    info = autotune.cache_info()
-    assert ("slab", 4, 8, 8, 8, "float32", "float32", "tpu") in info
-    assert ("cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu") in info
+def test_keys_never_alias_across_kinds_pipeline_and_flat_blocks():
+    """Each slab kind, the pipeline pick and the flat-block pick of one
+    case keep their own key and their own measured value."""
+    autotune.pick_block_e(TPU_E, 4, jnp.float32, backend="tpu",
+                          measure=lambda be: float(be))
+    autotune.pick_pipeline(TPU_GRID, 4, jnp.float32, backend="tpu",
+                           measure=lambda p: float(p.endswith("v2")))
+    for kind, depth in (("slab", 0), ("sstep", 2), ("cheb", 2)):
+        autotune.slab_config(kind, TPU_GRID, 4, jnp.float32, depth=depth,
+                             backend="tpu", measure=lambda sz, go: float(sz))
+    assert autotune.cache_info() == {
+        (4, TPU_E) + _F32: 128,
+        ("pipeline", 4, 8, 8, 8) + _F32: "pallas_fused_cg",
+        ("cfg", "slab", 4, 8, 8, 8) + _F32: (2, "parallel"),
+        ("cfg", "sstep", 4, 8, 8, 8, 2) + _F32: (2, "parallel"),
+        ("cfg", "cheb", 4, 8, 8, 8, 2) + _F32: (2, "parallel")}
 
 
-def test_cfg_keys_carry_s_k_and_precond_dimensions():
-    calls = []
+def test_fixed_order_caller_reads_the_joint_sweep():
+    """A caller that fixes the grid order (a kernel that takes none) gets
+    that order's fastest size from the one joint sweep, by the sweep's
+    margin rule: one ``autotune.sweep`` span for both callers."""
+    from repro.obs import trace
+
+    times = {(8, "parallel"): 1.0, (8, "arbitrary"): 0.9,
+             (4, "parallel"): 0.97, (4, "arbitrary"): 0.5,
+             (2, "parallel"): 0.99, (2, "arbitrary"): 0.6}
 
     def measure(sz, grid_order):
-        calls.append((sz, grid_order))
-        return float(sz)
+        return times[sz, grid_order]
 
-    autotune.pick_sstep_config(TPU_GRID, 4, 2, jnp.float32,
-                               backend="tpu", measure=measure)
-    autotune.pick_sstep_config(TPU_GRID, 4, 4, jnp.float32,
-                               backend="tpu", measure=measure)
-    autotune.pick_cheb_config(TPU_GRID, 4, 2, jnp.float32,
-                              backend="tpu", measure=measure)
-    autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
-                              precond="jacobi", measure=measure)
-    info = autotune.cache_info()
-    assert ("cfg", "sstep", 4, 8, 8, 8, 2, "float32", "float32", "tpu") \
-        in info
-    assert ("cfg", "sstep", 4, 8, 8, 8, 4, "float32", "float32", "tpu") \
-        in info
-    assert ("cfg", "cheb", 4, 8, 8, 8, 2, "float32", "float32", "tpu") \
-        in info
-    assert ("cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu",
-            "pc:jacobi") in info
+    with trace.recording() as rec:
+        par = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                                   grid_order="parallel", backend="tpu",
+                                   measure=measure)
+        joint = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                                     backend="tpu", measure=measure)
+        arb = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                                   grid_order="arbitrary", backend="tpu",
+                                   measure=measure)
+    assert joint == (4, "arbitrary")
+    assert par == (4, "parallel")      # 3% faster than 8/parallel
+    # the arbitrary points' first, 8, is beaten by 4 by more than 2%
+    assert arb == (4, "arbitrary")
+    assert sum(r["name"] == "autotune.sweep" for r in rec.records
+               if r["type"] == "span") == 1
+    # within the margin the order's first point stays
+    autotune.clear_cache()
+    times[4, "parallel"] = 0.99
+    assert autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                                grid_order="parallel", backend="tpu",
+                                measure=measure) == (8, "parallel")
+
+
+# The three benchmark cells' shapes and routes, and the key and candidate
+# configs each driver asked the autotuner for before its pick went
+# behind one function, written out.
+_CELL_PICKS = {
+    "nekbone_p9_e4096.cg100": (
+        "v2", 10, (16, 16, 16),
+        ("cfg", "slab", 10, 16, 16, 16) + _F32,
+        [(2, "parallel"), (2, "arbitrary"), (1, "parallel"),
+         (1, "arbitrary")]),
+    "ceed_bp5_p7_e8192.jacobi_rtol": (
+        "v2_tol", 8, (16, 16, 32),
+        ("cfg", "slab", 8, 16, 16, 32) + _F32 + ("pc:jacobi",),
+        [(4, "parallel"), (4, "arbitrary"), (2, "parallel"),
+         (2, "arbitrary"), (1, "parallel"), (1, "arbitrary")]),
+    "ceed_bp6_p7_e8192.jacobi_rtol": (
+        "vector", 8, (16, 16, 32),
+        ("cfg", "slab", 8, 16, 16, 32) + _F32 + ("pc:jacobi", "rhs:3"),
+        [(2, "parallel"), (2, "arbitrary"), (1, "parallel"),
+         (1, "arbitrary")]),
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_PICKS))
+def test_cell_drivers_request_the_established_keys(cell, monkeypatch):
+    """With a TPU backend and an injected timer, each cell's driver asks
+    for the key and sweeps the candidates its cached pick was made under,
+    in order, so a disk cache from before keeps answering it.  The
+    driver is stopped right after its pick: nothing runs at cell size."""
+    import numpy as np
+
+    from repro.core import cg_block, cg_fused, precond
+
+    route, n, grid, key, configs = _CELL_PICKS[cell]
+
+    class Picked(Exception):
+        pass
+
+    seen = []
+    pick = autotune.slab_config
+
+    def tpu_pick(*a, **kw):
+        kw.update(backend="tpu", measure=lambda sz, go: seen.append(
+            (sz, go)) or float(sz))
+        pick(*a, **kw)
+        raise Picked
+
+    monkeypatch.setattr(autotune, "slab_config", tpu_pick)
+    E = grid[0] * grid[1] * grid[2]
+    lead = (3,) if route == "vector" else ()
+    rhs = np.zeros(lead + (E, n, n, n), np.float32)
+    args = dict(D=np.zeros((n, n), np.float32), g=None, grid=grid)
+    drive = {
+        "v2": lambda: cg_fused.cg_fused_v2_fixed_iters(rhs, niter=100,
+                                                       **args),
+        "v2_tol": lambda: precond.cg_fused_tol(
+            rhs, tol=1e-6, max_iter=10, precond="jacobi", **args),
+        "vector": lambda: cg_block.cg_block_coupled_tol(
+            rhs, tol=1e-6, max_iter=10, precond="jacobi", **args),
+    }[route]
+    with pytest.raises(Picked):
+        drive()
+    assert list(autotune.cache_info()) == [key]
+    assert seen == configs * autotune.SWEEP_ROUNDS
 
 
 def test_pick_pipeline_heuristic_threshold():
@@ -551,22 +585,22 @@ def test_tpu_pick_sweeps_only_compiled_candidates():
     filter depends on the backend alone, never on who measures."""
     seen = []
 
-    def measure(sz):
-        seen.append(sz)
+    def measure(sz, grid_order):
+        seen.append((sz, grid_order))
         return float(sz)
 
-    autotune.pick_slab_sz(TPU_GRID, 4, jnp.float32, backend="tpu",
-                          measure=measure)
+    autotune.slab_config("slab", TPU_GRID, 4, jnp.float32, backend="tpu",
+                         measure=measure)
     # the rounds interleave the candidates
-    assert seen == (autotune.candidate_slab_sizes(TPU_GRID, 4, tpu=True)
-                    * autotune.SWEEP_ROUNDS)
-    assert 1 not in seen               # 64 lanes: not a compiled block
+    assert seen == (autotune.candidate_configs(autotune.candidate_slab_sizes(
+        "slab", TPU_GRID, 4, tpu=True)) * autotune.SWEEP_ROUNDS)
+    assert 1 not in {sz for sz, _ in seen}   # 64 lanes: not a compiled block
 
 
 def test_tpu_pick_without_admissible_block_raises_even_when_measured():
     with pytest.raises(ValueError, match="VMEM"):
-        autotune.pick_slab_sz((2, 2, 8), 4, jnp.float32, backend="tpu",
-                              measure=lambda sz: float(sz))
+        autotune.slab_config("slab", (2, 2, 8), 4, jnp.float32,
+                             backend="tpu", measure=lambda sz, go: float(sz))
     with pytest.raises(ValueError, match="VMEM"):
         autotune.pick_block_e(8, 4, jnp.float32, backend="tpu",
                               measure=lambda be: float(be))
@@ -580,8 +614,8 @@ def test_stale_disk_schema_is_ignored():
     key = ["cfg", "slab", 4, 8, 8, 8, "float32", "float32", "tpu"]
     path.write_text(json.dumps({"version": 1, "entries": [
         {"key": key, "value": [2, "fold", "parallel"]}]}))
-    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
-                                    measure=lambda sz, go: float(sz))
+    cfg = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                               backend="tpu", measure=lambda sz, go: float(sz))
     assert cfg == (2, "parallel")
     assert json.loads(path.read_text())["version"] == autotune._DISK_VERSION
 
@@ -694,8 +728,8 @@ def test_disk_cache_round_trips_candidate_seconds():
                                  measure=boom) == 256
     assert autotune._SECONDS == {key: want}
     # ... and keeps them when a later measured pick rewrites the file
-    autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
-                              measure=lambda sz, go: float(sz))
+    autotune.slab_config("slab", TPU_GRID, 4, jnp.float32, backend="tpu",
+                         measure=lambda sz, go: float(sz))
     data = json.loads(autotune.cache_path().read_text())
     assert data["version"] == autotune._DISK_VERSION == 3
     seconds = {tuple(e["key"]): e["seconds"] for e in data["entries"]}
@@ -720,8 +754,8 @@ def test_schema_2_cache_file_loads_as_a_miss():
         calls.append((sz, grid_order))
         return float(sz)
 
-    cfg = autotune.pick_slab_config(TPU_GRID, 4, jnp.float32, backend="tpu",
-                                    measure=measure)
+    cfg = autotune.slab_config("slab", TPU_GRID, 4, jnp.float32,
+                               backend="tpu", measure=measure)
     assert calls and cfg == (2, "parallel")
     (entry,) = json.loads(path.read_text())["entries"]
     assert entry["key"] == key and entry["value"] == [2, "parallel"]

@@ -106,11 +106,26 @@ def test_warm_start_populates_caches(setup, tmp_path, monkeypatch):
     from repro.kernels import autotune
 
     autotune.clear_cache()
+    cfg_pc = _cfg(precond="jacobi")
     svc = SolverService(max_b=2)
-    warmed = svc.warm_start([cfg], batches=[1, 2], niter=1)
-    assert warmed == 2
-    # the case is cached for subsequent dispatches
-    assert len(svc._cases) == 1
+    warmed = svc.warm_start([cfg, cfg_pc], batches=[1, 2], niter=1)
+    assert warmed == 4
+    # the cases are cached for subsequent dispatches
+    assert len(svc._cases) == 2
+    # the warm-up holds exactly the keys the routes' drivers read: a
+    # preconditioned batch goes per RHS, so it reads no "rhs:2" key
+    plain = ("cfg", "slab", 4, 2, 2, 2, "float32", "float32",
+             autotune.jax.default_backend())
+    assert set(autotune.cache_info()) == {
+        plain, plain + ("rhs:2",), plain + ("pc:jacobi",)}
+    # and a solve of each warmed batch misses the autotune cache nowhere
+    for c in (cfg, cfg_pc):
+        for b in (1, 2):
+            misses = autotune.cache_stats()["misses"]
+            for _ in range(b):
+                svc.submit(SolveRequest(f=f, config=c, niter=2))
+            svc.drain()
+            assert autotune.cache_stats()["misses"] == misses, (c, b)
     autotune.clear_cache(disk=False)
 
 

@@ -103,31 +103,35 @@ for E in (1024, 4096):
     for be in autotune.candidate_blocks(E, N, tpu=True):
         CASES.append(("v1_operator", E, be))
 for grid in (GRID_1024, GRID_4096):
-    for sz in autotune.candidate_slab_sizes(grid, N, tpu=True):
+    for sz in autotune.candidate_slab_sizes("slab", grid, N, tpu=True):
         CASES.append(("v2_slab", grid, sz))
         CASES.append(("v2_cg_update", grid, sz))
-for sz in autotune.candidate_slab_sizes(GRID_1024, N, nrhs=8, tpu=True):
+for sz in autotune.candidate_slab_sizes("slab", GRID_1024, N, nrhs=8,
+                                       tpu=True):
     CASES.append(("v2_slab_block_b8", GRID_1024, sz))
     CASES.append(("v2_cg_update_block_b8", GRID_1024, sz))
 # the multi-RHS block kernels at the benchmark's grids: the coupled BP6
 # solve (n=8, b=3, block slab + batched Jacobi update) and a service drain
 # of b=2-4 at the paper's n=10 on a 16x16 cross-section
 for b in (2, 3, 4):
-    for sz in autotune.candidate_slab_sizes(GRID_4096, N, nrhs=b, tpu=True):
+    for sz in autotune.candidate_slab_sizes("slab", GRID_4096, N, nrhs=b,
+                                           tpu=True):
         CASES.append(("slab_block", (GRID_4096, N, b), sz))
-for sz in autotune.candidate_slab_sizes(GRID_BP6, 8, nrhs=3, tpu=True):
+for sz in autotune.candidate_slab_sizes("slab", GRID_BP6, 8, nrhs=3, tpu=True):
     CASES.append(("slab_block", (GRID_BP6, 8, 3), sz))
     CASES.append(("pcg_update_block", (GRID_BP6, 8, 3), sz))
 for grid in (GRID_1024, GRID_4096_SLIM):
-    for sz in autotune.candidate_slab_sizes_sstep(grid, N, S, tpu=True):
+    for sz in autotune.candidate_slab_sizes("sstep", grid, N, depth=S,
+                                           tpu=True):
         CASES.append(("sstep_powers", grid, sz))
         CASES.append(("sstep_update", grid, sz))
-for sz in autotune.candidate_slab_sizes(GRID_1024, N, tpu=True):
+for sz in autotune.candidate_slab_sizes("slab", GRID_1024, N, tpu=True):
     CASES.append(("fused_v1_dots", GRID_1024, sz))
     CASES.append(("fused_v1_pap", GRID_1024, sz))
     CASES.append(("pcg_update", GRID_1024, sz))
     CASES.append(("interp_10to5", GRID_1024, sz))
-for sz in autotune.candidate_slab_sizes_cheb(GRID_1024, N, S, tpu=True):
+for sz in autotune.candidate_slab_sizes("cheb", GRID_1024, N, depth=S,
+                                       tpu=True):
     CASES.append(("cheb_apply", GRID_1024, sz))
 
 
@@ -229,11 +233,11 @@ def test_kernel_compiles_for_v5e(compile_tpu, kind, where, size):
 def test_autotuner_admits_no_16x16_sstep_window():
     """At a 16x16 cross-section the s=4 window's modelled footprint exceeds
     the VMEM limit, so the TPU pick raises instead of overshooting."""
-    assert autotune.candidate_slab_sizes_sstep(GRID_4096, N, S,
-                                               tpu=True) == []
+    assert autotune.candidate_slab_sizes("sstep", GRID_4096, N, depth=S,
+                                         tpu=True) == []
     with pytest.raises(ValueError, match="VMEM"):
-        autotune.pick_slab_sz_sstep(GRID_4096, N, S, jnp.float32,
-                                    backend="tpu")
+        autotune.slab_config("sstep", GRID_4096, N, jnp.float32, depth=S,
+                             backend="tpu")
 
 
 @pytest.mark.xfail(strict=True, reason=(
